@@ -169,7 +169,7 @@ def int8_encode_pallas(x: jax.Array, block_size: int = 16,
     assert n % (block_size * LANES) == 0, (n, block_size * LANES)
     x2 = x.astype(jnp.float32).reshape(-1, LANES)
     n_tiles = x2.shape[0] // block_size
-    t, steps = _bfp_pl._grid(n_tiles, block_size, tiles_per_step)
+    t, steps = _bfp_pl._grid(n_tiles, tiles_per_step)
     q, scale = pl.pallas_call(
         functools.partial(_encode_kernel, block_size=block_size,
                           rounding=rounding, seed=seed),
@@ -205,7 +205,7 @@ def int8_decode_pallas(q: jax.Array, scale: jax.Array, block_size: int = 16,
     n = q.shape[0]
     q2 = q.reshape(-1, LANES)
     s2 = scale.reshape(-1, LANES)
-    t, steps = _bfp_pl._grid(s2.shape[0], block_size, tiles_per_step)
+    t, steps = _bfp_pl._grid(s2.shape[0], tiles_per_step)
     out = pl.pallas_call(
         functools.partial(_decode_kernel, block_size=block_size),
         grid=(steps,),

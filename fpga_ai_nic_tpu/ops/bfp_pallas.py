@@ -81,11 +81,23 @@ def _decode_kernel(mant_ref, scale_ref, out_ref, *, block_size,
     out_ref[:] = m * _bcast_blocks(scale, block_size, broadcast)
 
 
-def _grid(n_tiles: int, block_size: int, tiles_per_step: int):
-    t = min(tiles_per_step, n_tiles)
-    while n_tiles % t:
-        t -= 1
-    return t, n_tiles // t
+# the scale block of one grid step is (t, 128) int8 (bf16 in compress.int8):
+# Mosaic takes it only when t is a whole number of native sublane tiles —
+# (32, 128) for int8, which also covers bf16's (16, 128) — or the whole array
+_SCALE_SUBLANES = 32
+
+
+def _grid(n_tiles: int, tiles_per_step: int):
+    """(tiles per grid step, steps) for any tile count: one whole-array
+    step when it fits, else tiles_per_step rounded down to the sublane
+    tile with a ragged last step (Pallas drops its out-of-bounds rows).
+    Tiles are independent — a BFP block never leaves its tile — so the
+    grid is a schedule choice and never changes the bits."""
+    if n_tiles <= tiles_per_step:
+        return n_tiles, 1
+    t = max(tiles_per_step - tiles_per_step % _SCALE_SUBLANES,
+            _SCALE_SUBLANES)
+    return t, pl.cdiv(n_tiles, t)
 
 
 def bfp_encode_inline(x: jax.Array, block_size: int = 16,
@@ -107,7 +119,7 @@ def bfp_encode_inline(x: jax.Array, block_size: int = 16,
     assert n % (block_size * LANES) == 0, (n, block_size * LANES)
     x2 = x.astype(jnp.float32).reshape(-1, LANES)       # (tiles*B, 128)
     n_tiles = x2.shape[0] // block_size
-    t, steps = _grid(n_tiles, block_size, tiles_per_step)
+    t, steps = _grid(n_tiles, tiles_per_step)
     kern = functools.partial(_encode_kernel, block_size=block_size,
                              mantissa_bits=mantissa_bits, rounding=rounding,
                              broadcast=broadcast)
@@ -147,7 +159,7 @@ def bfp_decode_inline(mant: jax.Array, scale: jax.Array,
     n = mant.shape[0]
     m2 = mant.reshape(-1, LANES)
     s2 = scale.reshape(-1, LANES)
-    t, steps = _grid(s2.shape[0], block_size, tiles_per_step)
+    t, steps = _grid(s2.shape[0], tiles_per_step)
     out = pl.pallas_call(
         functools.partial(_decode_kernel, block_size=block_size,
                           broadcast=broadcast),

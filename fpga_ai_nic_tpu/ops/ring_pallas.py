@@ -1548,11 +1548,57 @@ def _ag_stream_call(own2, axis_name: Optional[str], block_size: int,
 # Frame VMEM for the streaming gather is ~2 * (S+2)/S * (FR/(R*4)) bytes
 # per chunk f32 element (send + recv windows), where FR = _frame_rows(R, B)
 # includes the 8-row tile padding — 72/68 of the live 17/16 rate at the
-# default R=64 plan, but up to 24/17 (~1.4x) at R=16; the binding
-# constraint is the CHUNK size.  Larger chunks are gathered in sequential
-# segments of at most this many elements (each segment is an independent
-# all-gather — BFP blocks never straddle a segment boundary).
+# default R=64 plan, but up to 24/17 (~1.4x) at R=16.  Larger chunks are
+# gathered in sequential segments of at most this many elements (each
+# segment is an independent all-gather — BFP blocks never straddle a
+# segment boundary).
 _AG_STREAM_MAX_CHUNK_ELEMS = 2 << 20      # ~4.5 MiB frame VMEM per segment
+
+
+def ag_stream_segments(C: int, slice_elems: int,
+                       block_size: int) -> list:
+    """[(offset, size, slice_elems)] — how the streaming gather cuts an
+    owned chunk of C elements into independent sequential gathers.  Two
+    budgets bound a segment: its frames must fit VMEM
+    (_AG_STREAM_MAX_CHUNK_ELEMS) and its slot window must fit the chip's
+    semaphore memory (`opstream.AG_MAX_SLICES` — at the default 8192-element
+    slice that is the binding one).  Every cut falls on a (block, 128)
+    tile, so the bytes equal the whole-chunk gather's.
+
+    Body segments use the full slice; what is left past the last whole
+    slice (fewer than slice_elems/tile tiles) is one short segment with
+    its own slice plan, so no chunk length can force a long plan of tiny
+    slices."""
+    tile = block_size * LANES
+    assert C % tile == 0, (C, tile)
+    slice_e = max(tile, slice_elems - slice_elems % tile)
+    seg = slice_e * max(1, min(_opstream.AG_MAX_SLICES,
+                               _AG_STREAM_MAX_CHUNK_ELEMS // slice_e))
+    body = C - C % slice_e
+    segs = [(off, min(seg, body - off), slice_e)
+            for off in range(0, body, seg)]
+    if C > body:
+        segs.append((body, C - body,
+                     pick_slice_elems(C - body, slice_e, block_size)))
+    return segs
+
+
+def _ag_stream_segmented(owned: jax.Array, axis_name: Optional[str],
+                         cfg: BFPConfig, slice_elems: int, interpret,
+                         collective_id: int,
+                         loopback_n: Optional[int] = None) -> jax.Array:
+    """Streaming gather of an owned chunk [C] -> [n*C], one
+    `_ag_stream_call` per `ag_stream_segments` entry."""
+    C = owned.shape[0]
+    x = owned.astype(jnp.float32)
+    outs = [_ag_stream_call(x[off:off + sz].reshape(-1, LANES), axis_name,
+                            cfg.block_size, cfg.mantissa_bits, cfg.rounding,
+                            slice_e, interpret, collective_id,
+                            loopback_n=loopback_n).reshape(-1, sz)
+            for off, sz, slice_e in ag_stream_segments(
+                C, slice_elems, cfg.block_size)]
+    out = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
+    return out.reshape(-1)
 
 
 def ring_all_gather_fused(owned: jax.Array, axis_name: str, *,
@@ -1570,8 +1616,9 @@ def ring_all_gather_fused(owned: jax.Array, axis_name: str, *,
     (~4 MiB) use the whole-chunk resident kernel; larger payloads default
     to the HBM-streaming interleaved-emission kernel (slot window S + 2,
     deadlock-free for arbitrary slice plans — _ag_schedule P1/P2), gathered
-    in sequential segments past the frame-VMEM budget.  streaming=False
-    opts out to the separate-op XLA ring with the identical codec."""
+    in sequential segments past the frame-VMEM or semaphore budget
+    (`ag_stream_segments`).  streaming=False opts out to the separate-op
+    XLA ring with the identical codec."""
     cfg = compression or BFPConfig()
     n = lax.axis_size(axis_name)
     C = owned.shape[0]
@@ -1611,27 +1658,8 @@ def ring_all_gather_fused(owned: jax.Array, axis_name: str, *,
                        cfg.rounding, interpret, collective_id)
         return out.reshape(n * C)
 
-    # streaming kernel; frame VMEM scales with the chunk (not the slice
-    # plan), so chunks beyond the budget gather in independent sequential
-    # segments — blocks never straddle a segment boundary, so the bytes
-    # match the whole-chunk gather exactly
-    tile = cfg.block_size * LANES
-    cap = _AG_STREAM_MAX_CHUNK_ELEMS - (_AG_STREAM_MAX_CHUNK_ELEMS % tile)
-
-    def gather_seg(seg: jax.Array) -> jax.Array:
-        sz = seg.shape[0]
-        x2 = seg.astype(jnp.float32).reshape(-1, LANES)
-        slice_e = pick_slice_elems(sz, slice_elems, cfg.block_size)
-        out = _ag_stream_call(x2, axis_name, cfg.block_size,
-                              cfg.mantissa_bits, cfg.rounding, slice_e,
-                              interpret, collective_id)
-        return out.reshape(n, sz)
-
-    if C <= cap:
-        return gather_seg(owned).reshape(n * C)
-    outs = [gather_seg(owned[off:min(off + cap, C)])
-            for off in range(0, C, cap)]
-    return jnp.concatenate(outs, axis=1).reshape(n * C)
+    return _ag_stream_segmented(owned, axis_name, cfg, slice_elems,
+                                interpret, collective_id)
 
 
 def ring_reduce_scatter_update_fused(
@@ -1953,16 +1981,14 @@ def loopback_gather_microbench(owned: jax.Array, virtual_n: int = 4, *,
     C = owned.shape[0]
     if C % slice_elems or slice_elems % (cfg.block_size * LANES):
         raise ValueError((C, slice_elems, cfg.block_size * LANES))
-    x2 = owned.astype(jnp.float32).reshape(-1, LANES)
     if streaming:
-        out = _loopback_shmap(
-            lambda v: _ag_stream_call(v, None, cfg.block_size,
-                                      cfg.mantissa_bits, cfg.rounding,
-                                      slice_elems, interpret, 8,
-                                      loopback_n=virtual_n), x2)
-    else:
-        out = _loopback_shmap(
-            lambda v: _ag_call(v, None, cfg.block_size, cfg.mantissa_bits,
-                               cfg.rounding, interpret, 8,
-                               loopback_n=virtual_n), x2)
+        return _loopback_shmap(
+            lambda v: _ag_stream_segmented(v, None, cfg, slice_elems,
+                                           interpret, 8,
+                                           loopback_n=virtual_n), owned)
+    x2 = owned.astype(jnp.float32).reshape(-1, LANES)
+    out = _loopback_shmap(
+        lambda v: _ag_call(v, None, cfg.block_size, cfg.mantissa_bits,
+                           cfg.rounding, interpret, 8,
+                           loopback_n=virtual_n), x2)
     return out.reshape(virtual_n * C)

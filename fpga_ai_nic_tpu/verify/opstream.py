@@ -1157,11 +1157,39 @@ def ag_schedule(n: int, S: int, n_slots: int) -> Tuple[
     return content, fwd_j, own_at, own_j, own_js, tail_own_js
 
 
+# One TPU core has 2 KiB of semaphore memory — 512 words — and the
+# streaming gather spends one DMA semaphore per wire slot on each of its
+# send and recv windows.  What else the compiled program keeps there,
+# measured with jaxlib 0.9.0 / libtpu 0.0.34 for a described v5e: 29 words
+# in the kernel beside its windows (its seven own semaphores, the barrier,
+# Mosaic's internals), and in the whole DPTrainer step another 51 the
+# program reserves plus 15 of XLA's temporaries — 95 in all, so a
+# 208-slot window is the last that compiles there.  128 are reserved: the
+# margin is for a program with a few more temporaries.  The interpreter
+# never counts semaphores, so this bound is pinned by
+# tests/test_tpu_compile.py, not by the kernel tests.
+AG_SEM_WORDS = 512
+AG_SEM_RESERVED = 128
+AG_MAX_SLOTS = (AG_SEM_WORDS - AG_SEM_RESERVED) // 2
+# the longest slice plan whose window (S + 2) fits: `ring_pallas` cuts a
+# larger chunk into independent sequential gathers of at most this many
+# slices — the window rule below is untouched, so the credit argument of
+# `ag_schedule` (n_slots >= S + 1 per gather) holds per segment
+AG_MAX_SLICES = AG_MAX_SLOTS - 2
+
+
 def ag_n_slots(n: int, S: int) -> int:
     """THE slot-window rule of the streaming gather: covers the own
     phase's maximum emission lead (== S, P2) with one slot of margin
-    (`_ag_stream_call` consumes this)."""
-    return min((n - 1) * S, S + 2)
+    (`_ag_stream_call` consumes this).  A plan past the chip's semaphore
+    budget is refused here, by name, instead of by the compiler."""
+    n_slots = min((n - 1) * S, S + 2)
+    if n_slots > AG_MAX_SLOTS:
+        raise ValueError(
+            f"streaming gather of S={S} slices needs {n_slots} wire slots, "
+            f"over the {AG_MAX_SLOTS} the semaphore budget allows — "
+            f"segment the chunk to at most {AG_MAX_SLICES} slices")
+    return n_slots
 
 
 class AgSchedule:
