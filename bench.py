@@ -82,7 +82,7 @@ def child_main(layers: int, batch: int, iters: int) -> None:
 
     # persistent compile cache: repeat runs (and the degraded retry) skip
     # XLA compilation entirely
-    enable_compile_cache(jax)
+    enable_compile_cache()
 
     phase("devices")
     n_dev = jax.device_count()

@@ -89,7 +89,7 @@ def child_main() -> None:
 
     phase("import")
     import jax
-    enable_compile_cache(jax)
+    enable_compile_cache()
     phase("devices")
     n_dev = jax.device_count()
     platform = jax.default_backend()
@@ -481,7 +481,7 @@ def codec_matrix_child() -> None:
 
     phase("import")
     import jax
-    enable_compile_cache(jax)
+    enable_compile_cache()
     import jax.numpy as jnp
     from jax import lax
 
@@ -656,7 +656,7 @@ def autotune_child() -> None:
 
     phase("import")
     import jax
-    enable_compile_cache(jax)
+    enable_compile_cache()
     import jax.numpy as jnp
     from jax import lax
     from jax.sharding import Mesh, PartitionSpec as P
@@ -870,7 +870,7 @@ def fused_opt_child() -> None:
 
     phase("import")
     import jax
-    enable_compile_cache(jax)
+    enable_compile_cache()
     import jax.numpy as jnp
     from jax import lax
     from jax.sharding import Mesh, PartitionSpec as P

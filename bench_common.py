@@ -275,11 +275,26 @@ def cpu_env(n_devices: int = 8) -> dict:
                 XLA_FLAGS=flags)
 
 
-def enable_compile_cache(jax) -> None:
-    cache_dir = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                             ".jax_cache")
-    try:
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-    except Exception as e:  # noqa: BLE001 — cache is best-effort
-        log(f"compile cache unavailable: {e}")
+def enable_compile_cache() -> dict:
+    """JAX's persistent compile cache, placed from outside: where
+    JAX_COMPILATION_CACHE_DIR is set jax reads it itself and nothing is set
+    here; otherwise the cache is `<checkout>/.jax_cache` — a fixed path,
+    because the path is part of the cache key and a directory that moves
+    never hits.  Returns a live {"hits", "misses"} count of this process's
+    cache reads and writes."""
+    import jax
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update(
+            "jax_compilation_cache_dir",
+            os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                         ".jax_cache"))
+    counts = {"hits": 0, "misses": 0}
+
+    def on_event(event: str, **_) -> None:
+        if event == "/jax/compilation_cache/cache_hits":
+            counts["hits"] += 1
+        elif event == "/jax/compilation_cache/cache_misses":
+            counts["misses"] += 1
+
+    jax.monitoring.register_event_listener(on_event)
+    return counts

@@ -110,3 +110,27 @@ def fused_allreduce_sgd(grad_shards: np.ndarray, weights: np.ndarray,
     w_new_owned = np.stack([w_chunks[i] - np.float32(lr) * g_owned[i]
                             for i in range(n)])
     return ring_all_gather(w_new_owned, compression)
+
+
+def loopback_reduce_scatter(x: np.ndarray, n: int,
+                            compression: Optional[BFPConfig] = None,
+                            layout: str = "sublane") -> np.ndarray:
+    """What ``ops.ring_pallas.loopback_microbench`` computes on one chip:
+    the fused kernel's ring with every RDMA addressed to the sender, at
+    ring index 0 — hop s adds the roundtrip of chunk (-s-1) mod n into
+    chunk (-s-2) mod n, and chunk 0 is what comes out.  x: [n*C] -> [C]."""
+    chunks = x.reshape(n, -1).astype(np.float32).copy()
+    for s in range(n - 1):
+        chunks[(-s - 2) % n] += _roundtrip(chunks[(-s - 1) % n],
+                                           compression, layout)
+    return chunks[0]
+
+
+def loopback_all_gather(owned: np.ndarray, n: int,
+                        compression: Optional[BFPConfig] = None,
+                        layout: str = "sublane") -> np.ndarray:
+    """What ``ops.ring_pallas.loopback_gather_microbench`` computes: a
+    node whose arrivals are its own emissions stores the roundtrip of its
+    own chunk in every one of the n chunk slots.  owned: [C] -> [n*C]."""
+    return np.tile(_roundtrip(owned.astype(np.float32), compression,
+                              layout), n)

@@ -1,0 +1,250 @@
+"""The chip's compiler as a test: the main path's kernels compiled for a
+described (not attached) v5e:2x2 at the sizes the canonical trainer produces.
+
+The Pallas interpreter checks bits; it counts no semaphores and aligns no
+blocks.  Both faults PR 22 repaired — the codec grid whose scale block was
+not a legal TPU block, and the streaming gather whose slot window outgrew
+the chip's semaphore memory — passed every interpreter test and were
+refused here.  Nothing runs: these are compiles, never timings
+(/opt/skills/guides/on-chip-measurement, section 2).
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")   # else libtpu logs to /tmp
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from fpga_ai_nic_tpu import optim
+from fpga_ai_nic_tpu.compress import int8
+from fpga_ai_nic_tpu.ops import bfp_pallas, ring_pallas
+from fpga_ai_nic_tpu.utils.config import (BFPConfig, OptimizerConfig,
+                                          OptimizerSpec)
+from fpga_ai_nic_tpu.verify import opstream
+
+try:
+    from jax.experimental import topologies
+    TOPO = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+except Exception as e:  # noqa: BLE001 — no TPU compiler here: nothing to test
+    pytest.skip(f"v5e:2x2 cannot be described here: {e!r}",
+                allow_module_level=True)
+
+TILE = 16 * 128
+GRAD = 10 * 2048 * 2048            # the 10x2048^2 gradient, no biases
+DP4_PADDED = 41_967_616            # the dp=4 trainer's padded flat length
+DP1_PADDED = 41_963_520            # dp=1: 20,490 tiles, with the biases
+MESH4 = Mesh(np.array(TOPO.devices), ("dp",))
+MESH1 = Mesh(np.array(TOPO.devices[:1]), ("lb",))
+DP = NamedSharding(MESH4, P("dp"))
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _compile_cache_off():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without the chip: the next one would warn."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def kernels_in(fn, *args) -> int:
+    """Compile for the described chip; the number of Pallas calls in it."""
+    return jax.jit(fn).lower(*args).compile().as_text().count(
+        "tpu_custom_call")
+
+
+def on_mesh4(fn, n_in=1, n_out=1):
+    return jax.shard_map(fn, mesh=MESH4, in_specs=(P("dp"),) * n_in,
+                         out_specs=(P("dp"),) * n_out if n_out > 1
+                         else P("dp"), check_vma=False)
+
+
+def on_one_chip(fn):
+    return jax.shard_map(fn, mesh=MESH1, in_specs=P(), out_specs=P(),
+                         check_vma=False)
+
+
+ONE = NamedSharding(MESH1, P())
+
+
+# -- the codec grid (`bfp_pallas._grid`) -------------------------------------
+
+def _bfp(n):
+    enc = kernels_in(lambda x: bfp_pallas.bfp_encode_inline(
+        x, interpret=False), sds((n,), jnp.float32, ONE))
+    dec = kernels_in(lambda m, s: bfp_pallas.bfp_decode_inline(
+        m, s, interpret=False), sds((n,), jnp.int8, ONE),
+        sds((n // 16,), jnp.int8, ONE))
+    return enc, dec
+
+
+def _int8(n):
+    enc = kernels_in(lambda x: int8.int8_encode_pallas(
+        x, rounding="nearest", interpret=False), sds((n,), jnp.float32, ONE))
+    dec = kernels_in(lambda q, s: int8.int8_decode_pallas(
+        q, s, interpret=False), sds((n,), jnp.int8, ONE),
+        sds((n // 16,), jnp.bfloat16, ONE))
+    return enc, dec
+
+
+def test_bfp_codec_at_the_gradient_size():
+    assert _bfp(GRAD) == (1, 1)
+
+
+@pytest.mark.parametrize("tiles", [20_490, 20_492, 20_483])
+@pytest.mark.parametrize("codec", [_bfp, _int8], ids=["bfp", "int8"])
+def test_codec_grid_is_legal_at_awkward_tile_counts(codec, tiles):
+    """20,490 is the dp=1 trainer's own count (the MLP with its biases):
+    the old largest-divisor grid made its scale block (30, 128).  20,492
+    gave 47, and the prime 20,483 a grid of one-tile steps."""
+    assert codec(tiles * TILE) == (1, 1)
+    t, steps = bfp_pallas._grid(tiles, bfp_pallas._DEF_TILES)
+    assert t % 32 == 0 and (steps - 1) * t < tiles <= steps * t
+
+
+# -- the ring kernels on the four-chip mesh ----------------------------------
+
+def test_reduce_scatter_resident():
+    assert kernels_in(on_mesh4(lambda x: ring_pallas.ring_reduce_scatter_fused(
+        x, "dp", streaming=False, interpret=False)),
+        sds((4 * 131_072,), jnp.float32, DP)) == 1
+
+
+def test_all_gather_resident():
+    assert kernels_in(on_mesh4(lambda x: ring_pallas.ring_all_gather_fused(
+        x, "dp", streaming=False, interpret=False)),
+        sds((4 * 32_768,), jnp.float32, DP)) == 1
+
+
+def test_reduce_scatter_streaming_at_the_gradient_size():
+    assert kernels_in(on_mesh4(lambda x: ring_pallas.ring_reduce_scatter_fused(
+        x, "dp", streaming=True, interpret=False)),
+        sds((4 * GRAD,), jnp.float32, DP)) == 1
+
+
+@pytest.mark.parametrize("owned", [GRAD // 4, DP4_PADDED // 4])
+def test_all_gather_streaming_at_the_gradient_size(owned):
+    """The repaired one: S = 256 slices per 2 Mi-element segment asked for
+    2 x 258 DMA semaphores and was refused (`sflag`)."""
+    n = kernels_in(on_mesh4(lambda x: ring_pallas.ring_all_gather_fused(
+        x, "dp", streaming=True, interpret=False)),
+        sds((4 * owned,), jnp.float32, DP))
+    assert n == len(ring_pallas.ag_stream_segments(owned, 8192, 16)) > 1
+
+
+@pytest.mark.parametrize("kind", ["sgd", "adamw"])
+def test_reduce_scatter_update_streaming_at_the_gradient_size(kind):
+    keys = OptimizerSpec(kind=kind).state_keys
+    hyper = optim.fused_hyperparams(
+        OptimizerConfig(kind=kind, learning_rate=1e-3),
+        jnp.zeros((), jnp.int32))
+
+    def fn(x, w, *st):
+        g, w2, st2 = ring_pallas.ring_reduce_scatter_update_fused(
+            x, w, dict(zip(keys, st)), hyper, "dp", opt_kind=kind,
+            streaming=True, interpret=False)
+        return (g, w2) + tuple(st2[k] for k in keys)
+
+    shards = [sds((GRAD,), jnp.float32, DP)] * (1 + len(keys))
+    assert kernels_in(on_mesh4(fn, 2 + len(keys), 2 + len(keys)),
+                      sds((4 * GRAD,), jnp.float32, DP), *shards) == 1
+
+
+# -- the one-chip loopback chip_smoke.py runs --------------------------------
+
+def test_loopback_reduce_scatter_32mib():
+    assert kernels_in(on_one_chip(lambda x: ring_pallas._rs_stream_call(
+        x.reshape(-1, 128), None, 16, 8, "nearest", 8192, False, 7,
+        loopback_n=4)), sds((8 << 20,), jnp.float32, ONE)) == 1
+
+
+def test_loopback_all_gather_32mib():
+    n = kernels_in(on_one_chip(lambda x: ring_pallas._ag_stream_segmented(
+        x, None, BFPConfig(), 8192, False, 8, loopback_n=4)),
+        sds((2 << 20,), jnp.float32, ONE))
+    assert n == len(ring_pallas.ag_stream_segments(2 << 20, 8192, 16)) == 2
+
+
+# -- the semaphore bound, in plain Python ------------------------------------
+
+@pytest.mark.parametrize("mib", [1, 32, 160])
+@pytest.mark.parametrize("n", [2, 4, 8])
+def test_gather_slot_window_fits_the_semaphore_budget(n, mib):
+    """Every segment of every payload: two windows of `ag_n_slots` DMA
+    semaphores plus what the compiled program keeps beside them
+    (`opstream.AG_SEM_RESERVED`, measured at 95 words) fit the core's 512
+    words; the segments tile the chunk on tile boundaries."""
+    owned = (mib << 20) // 4 // n
+    owned -= owned % TILE
+    segs = ring_pallas.ag_stream_segments(owned, 8192, 16)
+    at = 0
+    for off, size, slice_e in segs:
+        assert off == at and size % slice_e == 0 and slice_e % TILE == 0
+        slices = size // slice_e
+        assert slices <= opstream.AG_MAX_SLICES
+        assert (2 * opstream.ag_n_slots(n, slices)
+                + opstream.AG_SEM_RESERVED <= opstream.AG_SEM_WORDS)
+        at += size
+    assert at == owned
+
+
+def test_gather_slot_window_past_the_budget_is_refused_by_name():
+    assert opstream.AG_MAX_SLICES < 239      # the v5e compiler's own limit
+    with pytest.raises(ValueError, match="semaphore budget"):
+        opstream.ag_n_slots(4, 256)
+
+
+# -- the whole step (about a minute each: not tier-1) ------------------------
+
+@pytest.mark.slow
+@pytest.mark.parametrize("dp,padded", [(1, DP1_PADDED), (4, DP4_PADDED)])
+def test_whole_fused_ring_step_compiles(monkeypatch, dp, padded):
+    """DPTrainer's step with the fused-ring config, from shapes alone, at
+    the trainer's own padded length.  `_is_tpu` still sees the CPU here, so
+    the test steers it — not an option of the program."""
+    from fpga_ai_nic_tpu.models import mlp
+    from fpga_ai_nic_tpu.parallel import DPTrainer, make_mesh
+    from fpga_ai_nic_tpu.parallel.train import TrainState
+    from fpga_ai_nic_tpu.utils.config import (
+        CollectiveConfig, MeshConfig, MLPConfig, TrainConfig)
+
+    monkeypatch.setattr(bfp_pallas, "_is_tpu", lambda: True)
+    monkeypatch.setattr(ring_pallas, "_is_tpu", lambda: True)
+    mcfg = MLPConfig(layer_sizes=(2048,) * 11, dtype="bfloat16")
+    cfg = TrainConfig(
+        global_batch=4096 * dp, mesh=MeshConfig(dp=dp),
+        collective=CollectiveConfig(
+            impl="ring", compression=BFPConfig(), fused_kernel=True,
+            fused_optimizer=True),
+        optimizer=OptimizerConfig(kind="sgd", learning_rate=0.1))
+    mesh = make_mesh(cfg.mesh, devices=TOPO.devices)
+    tr = DPTrainer(lambda p, b: mlp.loss_fn(p, b, mcfg), mesh, cfg)
+    like = jax.eval_shape(lambda: mlp.init(jax.random.PRNGKey(0), mcfg))
+    tr._ensure_meta(like)
+    assert tr._meta.padded_len == padded
+    rep, shd = NamedSharding(mesh, P()), NamedSharding(mesh, P("dp"))
+    state = TrainState(
+        params=jax.tree_util.tree_map(
+            lambda l: sds(l.shape, l.dtype, rep), like),
+        w_own=sds((padded,), jnp.float32, shd), opt_state={},
+        step=sds((), jnp.int32, rep))
+    batch = (sds((cfg.global_batch, 2048), jnp.bfloat16, shd),
+             sds((cfg.global_batch,), jnp.int32, shd))
+    hlo = tr.step_fn.lower(state, batch).compile().as_text()
+    want = 2 if dp == 1 else 1 + len(ring_pallas.ag_stream_segments(
+        padded // dp, 8192, 16))
+    assert hlo.count("tpu_custom_call") == want

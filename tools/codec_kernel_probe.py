@@ -32,7 +32,7 @@ def main():
     import jax
     import jax.numpy as jnp
     from jax import lax
-    enable_compile_cache(jax)
+    enable_compile_cache()
     from fpga_ai_nic_tpu.ops import bfp_pallas as bp
 
     platform = jax.default_backend()

@@ -28,7 +28,7 @@ def main():
     from jax import lax
 
     from bench_common import enable_compile_cache
-    enable_compile_cache(jax)
+    enable_compile_cache()
     from fpga_ai_nic_tpu.ops import ring as ring_ops
     from fpga_ai_nic_tpu.utils.config import BFPConfig
 

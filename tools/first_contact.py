@@ -133,7 +133,7 @@ platform = d[0].platform
 print("[bench] phase=devices t=%.1fs platform=%s" % (time.time()-t0, platform),
       flush=True)
 from bench_common import chain_kernel_calls, enable_compile_cache, slope_timeit
-enable_compile_cache(jax)
+enable_compile_cache()
 from fpga_ai_nic_tpu.ops import ring_pallas as rp
 
 _scalar = jax.jit(lambda t: jnp.sum(t.astype(jnp.float32)))

@@ -84,7 +84,7 @@ def _child_common():
     import jax
     import jax.numpy as jnp
     from bench_common import enable_compile_cache
-    enable_compile_cache(jax)
+    enable_compile_cache()
     print(f"[bench] phase=devices t={time.time() - t0:.1f}s", flush=True)
     n = jax.device_count()
     platform = jax.default_backend()

@@ -59,7 +59,7 @@ def child_main(name: str, validate: bool = False) -> None:
     import jax
     import jax.numpy as jnp
     from bench_common import enable_compile_cache
-    enable_compile_cache(jax)
+    enable_compile_cache()
     print(f"[bench] phase=devices t={time.time()-t0:.1f}s", flush=True)
     if not validate:
         assert is_tpu_platform(jax.devices()[0].platform), jax.devices()
