@@ -115,8 +115,8 @@ def lint_source(path: str, text: str,
                             suppressed=True, suppress_reason=reason)
             out.append(f)
     if _depth == 0:
-        # child-script templates (first_contact/multichip bank headline
-        # artifacts from `python -c <SRC>` strings) are shipped code too:
+        # child-script templates (tools that bank headline artifacts
+        # from `python -c <SRC>` strings) are shipped code too:
         # lint any module-level string that parses as a Python script
         for name, start, src in _embedded_sources(tree):
             for f in lint_source(path, src, rules, _depth=1):

@@ -74,8 +74,7 @@ def initialize(coordinator_address: Optional[str] = None,
 
 def _on_multihost_tpu() -> bool:
     """Detect a multi-worker TPU environment from env alone (never probes
-    jax — backend queries can hang on a wedged transport; same rule as
-    tests/conftest.py)."""
+    jax — a process that has touched jax holds the chip)."""
     hostnames = os.environ.get("TPU_WORKER_HOSTNAMES", "")
     if len([h for h in hostnames.split(",") if h.strip()]) > 1:
         return True

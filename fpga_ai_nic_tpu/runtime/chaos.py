@@ -31,7 +31,7 @@ Fault classes (``FAULT_KINDS``):
               poll-forever, provoked on purpose.
   slowdown    sleep below the limit — a straggler hop/host; must be
               survived WITHOUT recovery.
-  exception   raise InjectedFault — a transient driver/tunnel error.
+  exception   raise InjectedFault — a transient driver error.
   corruption  silently damage the payload (NaN / high-bit flip / scale) —
               the failure a compressed wire adds and checksums must catch.
   preemption  raise InjectedPreemption — the process lost its device slice

@@ -8,14 +8,14 @@ sw/mlp_mpi_example_f32.cpp:54-57).  SURVEY.md §5 calls this out as a gap to
 fill, not replicate.  Here:
 
 - ``Watchdog.run`` bounds any device-touching call with a wall-clock
-  timeout; a wedged dispatch/tunnel raises ``DeviceHangError`` instead of
+  timeout; a wedged dispatch raises ``DeviceHangError`` instead of
   spinning forever the way the reference's ``wait()`` poll loop does
   (sw/mlp_mpi_example_f32.cpp:157-180).
 - ``Heartbeat`` is the training-loop liveness probe: steps beat it, a
   monitor (or the loop itself) checks staleness.
 - ``run_with_recovery`` retries a step from the last known-good state with
   exponential backoff — elastic recovery for transient failures
-  (preempted chip, flaky tunnel), composing with utils.checkpoint for
+  (preempted chip, flaky host link), composing with utils.checkpoint for
   cross-process restarts.
 
 A hung XLA dispatch cannot be cancelled from Python (the thread leaks until
@@ -68,7 +68,7 @@ class Watchdog:
         if not done.wait(limit):
             raise DeviceHangError(
                 f"{getattr(fn, '__name__', fn)!r} exceeded "
-                f"{limit:.1f}s — device or tunnel "
+                f"{limit:.1f}s — device "
                 "presumed hung (reference analogue: hw/README:3 hang with "
                 "no kill path)")
         if "error" in result:

@@ -169,11 +169,11 @@ class CollectiveConfig:
     # Validation status: bit-exactness and the full flow-control protocol
     # (neighbor barrier + credit window) are exercised on every CI run —
     # the discharge-interpreter sweep and the threaded-interpreter
-    # TestFlowControl battery in tests/test_ring_pallas.py — but the
-    # kernels have NOT yet run on multi-chip ICI hardware.  Before first
-    # production use on a real multi-chip mesh, run the hardware canary
-    # (tools/first_contact.py stage 'canary', or loopback_microbench /
-    # loopback_gather_microbench directly) on one chip of that platform.
+    # TestFlowControl battery in tests/test_ring_pallas.py — and
+    # chip_smoke.py runs them on hardware: in loopback at 32 MiB on one
+    # chip against the numpy goldens, and with --chips 4 under the trainer
+    # over a real v5e ring against impl="xla" (first passed in PR 22).
+    # Before first use on another platform, run it there.
     fused_kernel: bool = False
     # fuse the optimizer update into the gradient reduce-scatter (the
     # reference's weight_update.sv trick + ZeRO-1 weight-update sharding):

@@ -202,7 +202,7 @@ class TestFlowControl:
     everything the protocol has: multi-hop forwards, credit waits
     (j >= n_slots), wire-slot reuse (total > n_slots), and the barrier;
     n=8 stays covered by the fast discharge-interpreter sweep above and
-    the hardware canary (tools/first_contact.py)."""
+    the hardware run (chip_smoke.py)."""
 
     @pytest.mark.parametrize("n,slices_per_chunk", [(4, 2), (3, 1), (2, 2)])
     def test_rs_resident(self, rng, n, slices_per_chunk):

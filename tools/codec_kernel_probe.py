@@ -8,7 +8,7 @@ of broadcast strategy ("repeat" = jnp.repeat on sublanes vs "reshape" =
 settings become bfp_pallas defaults; the whole table is banked as an
 artifact so the choice is evidenced, not asserted.
 
-Targets (VERDICT r4 item 2): >= 25 GB/s per direction is the minimum
+Targets: >= 25 GB/s per direction is the minimum
 ticket for the wire path to win a 12.5 GB/s link; >= 90 GB/s covers
 v5p-class links; the HBM roofline at ~820 GB/s and 5.06 traffic bytes
 per payload f32 byte allows ~650 GB/s.
