@@ -27,8 +27,4 @@ Nothing here is a translation: the compute path is JAX/XLA/Pallas over a
 ``jax.sharding.Mesh``; collectives ride ICI via ``psum_scatter``/``ppermute``.
 """
 
-from . import compat as _compat
-
-_compat.install()
-
 __version__ = "0.1.0"

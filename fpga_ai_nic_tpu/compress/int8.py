@@ -162,7 +162,6 @@ def int8_encode_pallas(x: jax.Array, block_size: int = 16,
     — same contract as bfp_pallas.bfp_encode_inline."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
-    from .. import compat
     if interpret is None:
         interpret = not _bfp_pl._is_tpu()
     n = x.shape[0]
@@ -183,9 +182,9 @@ def int8_encode_pallas(x: jax.Array, block_size: int = 16,
                          memory_space=pltpu.VMEM),
         ],
         out_shape=[
-            compat.shape_dtype_struct(x2.shape, jnp.int8,
+            jax.ShapeDtypeStruct(x2.shape, jnp.int8,
                                       vma=jax.typeof(x2).vma),
-            compat.shape_dtype_struct((n_tiles, LANES), jnp.bfloat16,
+            jax.ShapeDtypeStruct((n_tiles, LANES), jnp.bfloat16,
                                       vma=jax.typeof(x2).vma),
         ],
         interpret=interpret,
@@ -199,7 +198,6 @@ def int8_decode_pallas(q: jax.Array, scale: jax.Array, block_size: int = 16,
                        tiles_per_step: int = _bfp_pl._DEF_TILES) -> jax.Array:
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
-    from .. import compat
     if interpret is None:
         interpret = not _bfp_pl._is_tpu()
     n = q.shape[0]
@@ -217,7 +215,7 @@ def int8_decode_pallas(q: jax.Array, scale: jax.Array, block_size: int = 16,
         ],
         out_specs=pl.BlockSpec((t * block_size, LANES), lambda i: (i, 0),
                                memory_space=pltpu.VMEM),
-        out_shape=compat.shape_dtype_struct(
+        out_shape=jax.ShapeDtypeStruct(
             q2.shape, jnp.float32,
             vma=jax.typeof(q2).vma | jax.typeof(s2).vma),
         interpret=interpret,

@@ -26,8 +26,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .. import compat
-
 LANES = 128
 _DEF_TILES = 64  # (64, 16, 128) f32 tiles = 512 KiB per grid step in VMEM
 
@@ -138,8 +136,8 @@ def bfp_encode_inline(x: jax.Array, block_size: int = 16,
                          memory_space=pltpu.VMEM),
         ],
         out_shape=[
-            compat.shape_dtype_struct(x2.shape, jnp.int8, vma=jax.typeof(x2).vma),
-            compat.shape_dtype_struct((n_tiles, LANES), jnp.int8,
+            jax.ShapeDtypeStruct(x2.shape, jnp.int8, vma=jax.typeof(x2).vma),
+            jax.ShapeDtypeStruct((n_tiles, LANES), jnp.int8,
                                  vma=jax.typeof(x2).vma),
         ],
         interpret=interpret,
@@ -175,7 +173,7 @@ def bfp_decode_inline(mant: jax.Array, scale: jax.Array,
         ],
         out_specs=pl.BlockSpec((t * block_size, LANES), lambda i: (i, 0),
                                memory_space=pltpu.VMEM),
-        out_shape=compat.shape_dtype_struct(
+        out_shape=jax.ShapeDtypeStruct(
             m2.shape, jnp.float32,
             vma=jax.typeof(m2).vma | jax.typeof(s2).vma),
         interpret=interpret,

@@ -46,7 +46,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .. import compat
 from .bfp_pallas import _is_tpu
 
 LANES = 128
@@ -197,15 +196,15 @@ def _fwd(q3, k3, v3, off, bias, n_heads, sm_scale, causal, block_q,
             pl.BlockSpec((1, block_q), lambda b, i, j: (b, i)),
         ],
         out_shape=[
-            compat.shape_dtype_struct((BH, Sq, dh), q3.dtype, vma=vma),
-            compat.shape_dtype_struct((BH, Sq), jnp.float32, vma=vma),
+            jax.ShapeDtypeStruct((BH, Sq, dh), q3.dtype, vma=vma),
+            jax.ShapeDtypeStruct((BH, Sq), jnp.float32, vma=vma),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, LANES), jnp.float32),   # running max
             pltpu.VMEM((block_q, LANES), jnp.float32),   # normalizer
             pltpu.VMEM((block_q, dh), jnp.float32),      # output acc
         ],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(*args)
@@ -358,9 +357,9 @@ def _bwd(q3, k3, v3, off, bias, n_heads, out, lse, do, d_lse, sm_scale,
         grid=(BH, nq, nk),
         in_specs=dq_specs,
         out_specs=pl.BlockSpec((1, block_q, dh), lambda b, i, j: (b, i, 0)),
-        out_shape=compat.shape_dtype_struct((BH, Sq, dh), q3.dtype, vma=vma),
+        out_shape=jax.ShapeDtypeStruct((BH, Sq, dh), q3.dtype, vma=vma),
         scratch_shapes=[pltpu.VMEM((block_q, dh), jnp.float32)],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(*dq_args)
@@ -401,12 +400,12 @@ def _bwd(q3, k3, v3, off, bias, n_heads, out, lse, do, d_lse, sm_scale,
             pl.BlockSpec((1, block_k, dh), lambda b, j, g: (b, j, 0)),
         ],
         out_shape=[
-            compat.shape_dtype_struct((BHkv, Sk, dh), k3.dtype, vma=vma),
-            compat.shape_dtype_struct((BHkv, Sk, dh), v3.dtype, vma=vma),
+            jax.ShapeDtypeStruct((BHkv, Sk, dh), k3.dtype, vma=vma),
+            jax.ShapeDtypeStruct((BHkv, Sk, dh), v3.dtype, vma=vma),
         ],
         scratch_shapes=[pltpu.VMEM((block_k, dh), jnp.float32),
                         pltpu.VMEM((block_k, dh), jnp.float32)],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(*dkv_args)

@@ -61,7 +61,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .. import compat
 from ..verify import opstream as _opstream
 from .bfp_pallas import _is_tpu
 
@@ -287,7 +286,7 @@ def paged_gather_attend(q, pool_k, pool_v, page_table, pos, *,
                   hbm, hbm],
         out_specs=pl.BlockSpec((1, 1, G * T, hd),
                                lambda r, k: (r, k, 0, 0)),
-        out_shape=compat.shape_dtype_struct((R, n_kv, G * T, hd),
+        out_shape=jax.ShapeDtypeStruct((R, n_kv, G * T, hd),
                                             jnp.float32, vma=vma),
         scratch_shapes=[
             pltpu.VMEM((P * page_size, hd), pool_k.dtype),   # K tiles
@@ -295,7 +294,7 @@ def paged_gather_attend(q, pool_k, pool_v, page_table, pos, *,
             pltpu.VMEM((G * T, P * page_size), jnp.float32),  # scores
             pltpu.SemaphoreType.DMA((max(depth, 1), 2)),
         ],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
             has_side_effects=True),
         interpret=bool(interpret),

@@ -58,8 +58,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .. import compat
-
 from .bfp_pallas import LANES, _is_tpu
 from .. import optim as _optim
 from ..utils.config import BFPConfig, OptimizerSpec
@@ -94,15 +92,6 @@ def _decode_rows(mant, scale, block_size: int):
     s = pltpu.bitcast(((se + 127) << 23).astype(jnp.uint32), jnp.float32)
     return mant.astype(jnp.float32) * jnp.repeat(s, block_size, axis=0)
 
-
-# the threaded per-device TPU interpreter (blocking semaphores, race
-# detection) arrived after this container's jaxlib — under its original
-# TPUInterpretParams name on older releases that do ship it; the
-# flow-control battery skips without it (the discharge interpreter
-# still runs)
-_InterpretParams = getattr(pltpu, "InterpretParams",
-                           getattr(pltpu, "TPUInterpretParams", None))
-HAS_THREADED_INTERPRET = _InterpretParams is not None
 
 _FRAME_ALIGN = 8     # int8 VMEM sublane tile: DMA slice row extents align
 
@@ -151,14 +140,7 @@ def _interp_args(interpret):
                 admits (tests/test_ring_pallas.py::TestFlowControl).
     """
     if interpret == "threaded":
-        if not HAS_THREADED_INTERPRET:
-            raise NotImplementedError(
-                "interpret='threaded' needs pltpu.InterpretParams (or the "
-                "older TPUInterpretParams — the threaded TPU interpreter), "
-                "which this jaxlib does not ship — run the flow-control "
-                "battery on a newer JAX, or use interpret=True for the "
-                "discharge interpreter")
-        return _InterpretParams(detect_races=True), True, True
+        return pltpu.InterpretParams(detect_races=True), True, True
     return bool(interpret), not interpret, bool(interpret)
 
 
@@ -594,7 +576,7 @@ def _ring_ids(axis_name: Optional[str]) -> jax.Array:
     time: a silent mismatch would RDMA to the wrong chip."""
     if axis_name is None:
         return jnp.zeros((3,), jnp.int32)
-    sizes = compat.mesh_axis_sizes()
+    sizes = dict(jax.sharding.get_abstract_mesh().shape)
     other = {a: s for a, s in sizes.items()
              if a != axis_name and s != 1}
     if other:
@@ -645,10 +627,10 @@ def _rs_call(x2, axis_name: Optional[str], block_size: int,
     vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
 
     def sds(shape):
-        return compat.shape_dtype_struct(shape, jnp.float32, vma=vma)
+        return jax.ShapeDtypeStruct(shape, jnp.float32, vma=vma)
 
     def chk_sds():
-        return compat.shape_dtype_struct((2,), jnp.uint32, vma=vma)
+        return jax.ShapeDtypeStruct((2,), jnp.uint32, vma=vma)
 
     if opt_kind is None:
         out_shape = [sds((chunk_rows, LANES))]
@@ -687,7 +669,7 @@ def _rs_call(x2, axis_name: Optional[str], block_size: int,
             pltpu.SemaphoreType.DMA((n_slots,)),
             pltpu.SemaphoreType.REGULAR,
         ],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             has_side_effects=True, collective_id=collective_id),
         interpret=_interp,
     )(*args)
@@ -1074,7 +1056,7 @@ def _rs_stream_call(x2, axis_name: Optional[str], block_size: int,
     hbm = pl.BlockSpec(memory_space=pl.ANY)
 
     def sds(shape):
-        return compat.shape_dtype_struct(shape, jnp.float32, vma=vma)
+        return jax.ShapeDtypeStruct(shape, jnp.float32, vma=vma)
 
     ns = 0 if opt_kind is None else OptimizerSpec(kind=opt_kind).n_state
     n_t = 1 + ns
@@ -1093,7 +1075,7 @@ def _rs_stream_call(x2, axis_name: Optional[str], block_size: int,
         io_alias = {3: 0, **{4 + i: 1 + i for i in range(n_t)}}
     if integrity:
         out_shape = out_shape \
-            + [compat.shape_dtype_struct((2,), jnp.uint32, vma=vma)]
+            + [jax.ShapeDtypeStruct((2,), jnp.uint32, vma=vma)]
         out_specs = out_specs + [smem]
     res = pl.pallas_call(
         kern,
@@ -1120,7 +1102,7 @@ def _rs_stream_call(x2, axis_name: Optional[str], block_size: int,
             pltpu.SemaphoreType.DMA((n_slots,)),           # rdma recv
             pltpu.SemaphoreType.REGULAR,
         ],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             has_side_effects=True, collective_id=collective_id),
         interpret=_interp,
     )(*args)
@@ -1245,7 +1227,7 @@ def _ag_call(own2, axis_name: Optional[str], block_size: int,
     vma = jax.typeof(own2).vma | jax.typeof(ids).vma
     return pl.pallas_call(
         kern,
-        out_shape=compat.shape_dtype_struct((n * R, LANES), jnp.float32,
+        out_shape=jax.ShapeDtypeStruct((n * R, LANES), jnp.float32,
                                        vma=vma),
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
                   pl.BlockSpec(memory_space=pltpu.VMEM)],
@@ -1257,7 +1239,7 @@ def _ag_call(own2, axis_name: Optional[str], block_size: int,
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.REGULAR,
         ],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             has_side_effects=True, collective_id=collective_id),
         interpret=_interp,
     )(ids, own2)
@@ -1520,7 +1502,7 @@ def _ag_stream_call(own2, axis_name: Optional[str], block_size: int,
     vma = jax.typeof(own2).vma | jax.typeof(ids).vma
     return pl.pallas_call(
         kern,
-        out_shape=compat.shape_dtype_struct((n * C_rows, LANES), jnp.float32,
+        out_shape=jax.ShapeDtypeStruct((n * C_rows, LANES), jnp.float32,
                                        vma=vma),
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
                   pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -1539,7 +1521,7 @@ def _ag_stream_call(own2, axis_name: Optional[str], block_size: int,
             pltpu.SemaphoreType.DMA((n_slots,)),           # rdma recv
             pltpu.SemaphoreType.REGULAR,
         ],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             has_side_effects=True, collective_id=collective_id),
         interpret=_interp,
     )(ids, sched, own2)
@@ -1836,7 +1818,7 @@ def flow_control_selftest(n: int = 8, *, streaming: bool = False,
     encode/decode compiled out the accumulator is untouched, so the
     result is exact: each device returns its own input chunk.  Raises on
     deadlock (test timeout), data race (interpreter detector), or
-    mismatch.  Needs pltpu.InterpretParams (see _interp_args)."""
+    mismatch."""
     import numpy as np
     from jax.sharding import Mesh, PartitionSpec as P
     cfg = BFPConfig()

@@ -32,7 +32,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .. import compat
 
 
 def _pcast_to(x: jax.Array, vma) -> jax.Array:
@@ -162,30 +161,16 @@ def pipeline_apply_aux(stage_fn: Callable, stage_params, x: jax.Array,
     return outputs.reshape(x.shape), aux
 
 
-def _widen(tree, vma, polyfill_vma=()):
+def _widen(tree, vma):
     """Widen every leaf to the full varying set, RECORDING the widened
     axes per leaf — the 1F1B schedulers' entry pcast whose manual
     transpose is the exit psum in ``_unwiden_grads`` (the reason is
     documented in pipeline_train_1f1b: a vjp-inserted psum inside a
-    stage-divergent cond deadlocks the mesh).
-
-    ``polyfill_vma``: the tree's CONTRACT varying axes, used when the
-    jaxlib has no vma typing (compat.HAS_VMA False: ``jax.typeof`` is
-    polyfilled to an EMPTY vma for every leaf).  Without it the recorded
-    widened axes claimed every leaf was invariant, and the exit transpose
-    psum'd STAGE-SHARDED gradients across the pp ring — elementwise
-    summing different layers' gradients, the collective-transpose /
-    gradient-scale class of docs/KNOWN_FAILURES.md #5-16 (frozen as
-    graftlint rule J7).  On vma-typed jaxlibs the leaf types carry the
-    exact answer (including extra axes like dp) and the contract default
-    is ignored."""
+    stage-divergent cond deadlocks the mesh).  The leaf types carry the
+    exact varying set (including extra axes like dp)."""
     tmap = jax.tree_util.tree_map
-
-    def leaf_vma(v):
-        return (set(jax.typeof(v).vma) if compat.HAS_VMA
-                else set(polyfill_vma))
-
-    axes = tmap(lambda v: tuple(sorted(set(vma) - leaf_vma(v))), tree)
+    axes = tmap(lambda v: tuple(sorted(set(vma) - set(jax.typeof(v).vma))),
+                tree)
     return tmap(lambda v: _pcast_to(v, vma), tree), axes
 
 
@@ -337,12 +322,9 @@ def pipeline_train_1f1b(stage_fn: Callable, loss_head_fn: Callable,
     # invariantization happens exactly once after the scan — each
     # gradient leaf psum'd over precisely its recorded widened axes (the
     # manual transpose of the entry pcast).
-    # contract vma defaults (polyfill jaxlibs — see _widen): stage params
-    # are pp-sharded, head params and x replicated over pp
-    sp_v, sp_axes = _widen(stage_params, vma, polyfill_vma=(pp_axis,))
+    sp_v, sp_axes = _widen(stage_params, vma)
     hp_v, hp_axes = _widen(head_params, vma)
-    x_axes = tuple(sorted(set(vma) - (set(jax.typeof(x).vma)
-                                      if compat.HAS_VMA else set())))
+    x_axes = tuple(sorted(set(vma) - set(jax.typeof(x).vma)))
     x_mb = _pcast_to(x_mb, vma)
     ctx_mb = tmap(lambda v: _pcast_to(v, vma), ctx_mb)
 
@@ -548,14 +530,13 @@ def from_last_stage(val: jax.Array, pp_axis: str) -> jax.Array:
     Cheap for scalars (per-microbatch losses); use sparingly on big tensors.
 
     The psum sits on the gradient path, so differentiating through this
-    inherits the jaxlib's psum-transpose convention.  That is the correct
-    pairing when the grad is taken OUTSIDE shard_map (the polyfill
-    boundary hands each replica ct/n for a replicated output, and the
-    psum transpose restores the factor); losses differentiated INSIDE
-    shard_map must use ``from_last_stage_local_grad`` instead — with the
-    psum on their gradient path, this container's psum-as-transpose
-    scaled every pipeline gradient by n_pp (docs/KNOWN_FAILURES.md #5-16
-    family, frozen as graftlint rule J7)."""
+    inherits the psum-transpose convention.  That is the correct pairing
+    when the grad is taken OUTSIDE shard_map (the boundary hands each
+    replica ct/n for a replicated output, and the psum transpose restores
+    the factor); losses differentiated INSIDE shard_map must use
+    ``from_last_stage_local_grad`` instead — with the psum on their
+    gradient path every pipeline gradient came out scaled by n_pp
+    (docs/KNOWN_FAILURES.md #5-16 family, frozen as graftlint rule J7)."""
     n = lax.axis_size(pp_axis)
     is_last = (lax.axis_index(pp_axis) == n - 1).astype(val.dtype)
     return lax.psum(val * is_last, pp_axis)
@@ -564,12 +545,11 @@ def from_last_stage(val: jax.Array, pp_axis: str) -> jax.Array:
 def from_last_stage_local_grad(val: jax.Array, pp_axis: str) -> jax.Array:
     """``from_last_stage`` for losses differentiated INSIDE shard_map: the
     psum carries the VALUE only, the gradient path rides the local masked
-    value — so the cotangent reaching ``val`` is exactly ct * is_last on
-    every jaxlib, independent of its psum-transpose convention (the J7
-    gradient-scale class).  Per-stage gradients of pp-replicated leaves
-    then come out as clean per-stage PARTIALS; the trainer supplies the
-    cross-stage psum (ShardedTrainer's manual pvary-transpose stand-in on
-    polyfill jaxlibs; vma autodiff inserts it on typed ones)."""
+    value — so the cotangent reaching ``val`` is exactly ct * is_last,
+    independent of the psum-transpose convention (the J7 gradient-scale
+    class).  Per-stage gradients of pp-replicated leaves then come out
+    as clean per-stage PARTIALS; vma autodiff inserts the cross-stage
+    psum (the transpose of its pvary)."""
     n = lax.axis_size(pp_axis)
     is_last = (lax.axis_index(pp_axis) == n - 1).astype(val.dtype)
     masked = val * is_last
@@ -812,11 +792,9 @@ def pipeline_train_1f1b_interleaved(stage_fn: Callable,
     act_shape = (mb,) + x.shape[1:]
     vma = _tree_vma(x, ctx, stage_params, head_params) | {pp_axis}
 
-    # same contract vma defaults as pipeline_train_1f1b (see _widen)
-    sp_v, sp_axes = _widen(stage_params, vma, polyfill_vma=(pp_axis,))
+    sp_v, sp_axes = _widen(stage_params, vma)
     hp_v, hp_axes = _widen(head_params, vma)
-    x_axes = tuple(sorted(set(vma) - (set(jax.typeof(x).vma)
-                                      if compat.HAS_VMA else set())))
+    x_axes = tuple(sorted(set(vma) - set(jax.typeof(x).vma)))
     x_mb = _pcast_to(x_mb, vma)
     ctx_mb = tmap(lambda val: _pcast_to(val, vma), ctx_mb)
 

@@ -29,7 +29,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from . import accum
 from . import mesh as mesh_lib
-from .. import compat
 from .. import optim
 from ..ops import fused_update
 from ..utils.config import TrainConfig
@@ -220,29 +219,6 @@ class ShardedTrainer:
             else:
                 loss, grads = accum.accumulated_value_and_grad(
                     self.loss_fn, self.cfg.accum_steps)(params_v, batch)
-            if not compat.HAS_VMA and pp is not None \
-                    and self.mesh.shape[pp] > 1 \
-                    and self.loss_and_grads_fn is None:
-                # Manual stand-in for the vma pvary transposes this
-                # polyfill jaxlib lacks: a pp-REPLICATED leaf (spec omits
-                # pp — embeddings on stage 0, the head on stage pp-1) gets
-                # per-stage PARTIAL gradients from autodiff (the pipeline
-                # loss keeps collectives off the gradient path —
-                # from_last_stage), so the stages' master copies would
-                # silently diverge without this psum.  pp-SHARDED leaves
-                # keep their per-stage gradients.  (The 1F1B
-                # loss_and_grads_fn contract already delivers psum'd
-                # replicated leaves — _unwiden_grads.)
-                def _pp_rep_sum(g, spec):
-                    used = set()
-                    for entry in tuple(spec):
-                        if entry is not None:
-                            used.update(entry if isinstance(entry, tuple)
-                                        else (entry,))
-                    return g if pp in used else lax.psum(g, pp)
-                grads = jax.tree_util.tree_map(
-                    _pp_rep_sum, grads, self.param_specs,
-                    is_leaf=lambda x: isinstance(x, P))
             flat_g, _ = fused_update.flatten_tree(grads, coll, self.n_dp)
             g_own = fused_update.reduce_scatter(flat_g, dp, coll) / self.n_dp
             if opt_cfg.clip_norm is not None:

@@ -107,12 +107,12 @@ def find_xplane(trace_dir: str) -> str:
 
 def _load_trace(trace_dir: str, data=None):
     """(xplane path, parsed profile data), reusing an already-parsed
-    ``data`` when the caller has one — the tsl-proto shim walks every
-    event in pure python, so re-parsing a multi-MB xplane per analysis
-    pass dominates CLI runtime."""
-    from ..compat import load_profile_data
+    ``data`` when the caller has one (a multi-MB xplane is worth parsing
+    once per CLI run, not once per analysis pass)."""
+    from jax.profiler import ProfileData
     path = find_xplane(trace_dir)
-    return path, (data if data is not None else load_profile_data(path))
+    return path, (data if data is not None
+                  else ProfileData.from_file(path))
 
 
 def _is_collective(name: str) -> bool:
@@ -390,14 +390,12 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     analyze = {"auto": analyze_any, "device": analyze_trace,
                "cpu": analyze_cpu_thunk_trace}[args.mode]
     try:
-        # one parse serves the report AND the interval dump (the shim
-        # loader walks the whole xplane in python — parse it once)
+        # one parse serves the report AND the interval dump
         _, data = _load_trace(args.trace_dir)
         report = analyze(args.trace_dir, data=data)
-    except (FileNotFoundError, ValueError, ImportError) as e:
-        # ImportError: no ProfileData loader on this jaxlib/container
-        # (compat.load_profile_data) — same JSON error contract as a
-        # missing xplane, never a raw traceback
+    except (FileNotFoundError, ValueError) as e:
+        # the JSON error contract of a missing xplane, never a raw
+        # traceback
         print(json.dumps({"error": str(e)}))
         return 1
     if args.intervals:

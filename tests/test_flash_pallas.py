@@ -188,18 +188,7 @@ class TestRingFlash:
             fn, mesh=mesh, in_specs=P(None, None, "sp", None),
             out_specs=P(None, None, "sp", None), check_vma=False))
 
-    @pytest.mark.parametrize("causal", [
-        True,
-        # causal=False lowers a bare PartitionId through the non-causal
-        # hop-count path, which this container's XLA:CPU SPMD partitioner
-        # rejects (UNIMPLEMENTED) — a seed-era backend limitation, not a
-        # kernel bug; works on TPU and on jaxlibs whose CPU partitioner
-        # accepts PartitionId.  docs/KNOWN_FAILURES.md #2.
-        pytest.param(False, marks=pytest.mark.xfail(
-            strict=False,
-            reason="jaxlib drift: XLA:CPU SPMD rejects PartitionId "
-                   "(UNIMPLEMENTED) on the non-causal ring-flash path")),
-    ])
+    @pytest.mark.parametrize("causal", [True, False])
     def test_fwd_matches_ring_and_full(self, rng, causal):
         from fpga_ai_nic_tpu.ops.ring_attention import ring_attention
         n, Sl, dh = 4, 128, 64

@@ -171,9 +171,6 @@ def test_fused_all_reduce_matches_xla_op_ring_bitexact(rng):
 
 
 @pytest.mark.slow
-@pytest.mark.skipif(not rp.HAS_THREADED_INTERPRET,
-                    reason="this jaxlib ships no threaded TPU interpreter "
-                           "(pltpu.InterpretParams)")
 class TestFlowControl:
     """The REAL flow-control protocol — neighbor barrier, credit-window
     semaphores, blocking waits — executed end-to-end under the threaded
@@ -554,9 +551,6 @@ def test_rs_protocol_simulation_catches_bad_window(monkeypatch):
 
 
 @pytest.mark.slow
-@pytest.mark.skipif(not rp.HAS_THREADED_INTERPRET,
-                    reason="this jaxlib ships no threaded TPU interpreter "
-                           "(pltpu.InterpretParams)")
 @pytest.mark.parametrize("streaming", [False, True])
 def test_flow_control_selftest_n8(streaming):
     """The REAL credit protocol at n=8 under the threaded interpreter —
