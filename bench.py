@@ -28,7 +28,8 @@ import sys
 import tempfile
 import time
 
-from bench_common import bf16_peak, enable_compile_cache, log as _log
+from bench_common import (bf16_peak, enable_compile_cache, log as _log,
+                          require_tpu)
 
 BASELINE_SAMPLES_PER_SEC_PER_NODE = 14_000.0
 METRIC = "mlp_train_samples_per_sec_per_chip"
@@ -46,12 +47,7 @@ def main() -> int:
     import jax.numpy as jnp
 
     phase("devices")
-    dev = jax.devices()
-    if dev[0].platform != "tpu":
-        print(f"bench: jax found no TPU (platform {dev[0].platform!r}); "
-              f"{METRIC} is a device metric and was not measured",
-              file=sys.stderr)
-        return 1
+    dev = require_tpu("bench")
     n_dev, kind = len(dev), dev[0].device_kind
     _log(f"device_kind={kind!r} n_dev={n_dev}")
     enable_compile_cache()

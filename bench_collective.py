@@ -36,7 +36,8 @@ import sys
 import time
 
 from bench_common import (enable_compile_cache, is_tpu_platform, log,
-                          run_attempt, save_artifact, slope_timeit)
+                          require_tpu, run_attempt, save_artifact,
+                          slope_timeit)
 
 SWEEP_MB = (16, 64, 256)          # flat f32 vector sizes to sweep
 CODEC_MB = 64                     # standalone codec payload
@@ -58,16 +59,6 @@ def _timeit(fn, sync, iters=TIMED_ITERS):
         out = fn()
     sync(out)
     return (time.perf_counter() - t0) / iters
-
-
-def _require_tpu() -> None:
-    """Every child's first act after importing jax: these are device
-    metrics, and a child that found no chip measures nothing."""
-    import jax
-    platform = jax.devices()[0].platform
-    if not is_tpu_platform(platform):
-        raise SystemExit(f"bench_collective: jax found no TPU (platform "
-                         f"{platform!r}); nothing was measured")
 
 
 def child_main() -> None:
@@ -92,7 +83,7 @@ def child_main() -> None:
 
     phase("import")
     import jax
-    _require_tpu()
+    require_tpu("bench_collective")
     enable_compile_cache()
     phase("devices")
     n_dev = jax.device_count()
@@ -485,7 +476,7 @@ def codec_matrix_child() -> None:
 
     phase("import")
     import jax
-    _require_tpu()
+    require_tpu("bench_collective")
     enable_compile_cache()
     import jax.numpy as jnp
     from jax import lax
@@ -629,7 +620,7 @@ def autotune_child() -> None:
 
     phase("import")
     import jax
-    _require_tpu()
+    require_tpu("bench_collective")
     enable_compile_cache()
     import jax.numpy as jnp
     from jax import lax
@@ -812,7 +803,7 @@ def fused_opt_child() -> None:
 
     phase("import")
     import jax
-    _require_tpu()
+    require_tpu("bench_collective")
     enable_compile_cache()
     import jax.numpy as jnp
     from jax import lax

@@ -24,6 +24,19 @@ def is_tpu_platform(platform: str) -> bool:
     return platform == "tpu"
 
 
+def require_tpu(who: str):
+    """`jax.devices()` where jax found a TPU; elsewhere the process ends
+    with code 1 and a line on stderr, before anything is run or printed —
+    a number from another platform is not a device metric.  For the one
+    process that is meant to hold the chip."""
+    import jax
+    dev = jax.devices()
+    if not is_tpu_platform(dev[0].platform):
+        raise SystemExit(f"{who}: jax found no TPU (platform "
+                         f"{dev[0].platform!r}); nothing was run")
+    return dev
+
+
 def run_attempt(name: str, cmd, *, env=None, budget_s: float,
                 silence_s: float, cwd=None) -> dict:
     """Run one child attempt; returns its parsed result JSON (the last line

@@ -361,12 +361,9 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     import jax
-    dev = jax.devices()
-    if dev[0].platform != "tpu":
-        print(f"chip_smoke: jax found no TPU (platform {dev[0].platform!r});"
-              " nothing was run", file=sys.stderr)
-        return 1
-    from bench_common import enable_compile_cache
+
+    from bench_common import enable_compile_cache, require_tpu
+    dev = require_tpu("chip_smoke")
     cache = enable_compile_cache()
     log(f"device_kind {dev[0].device_kind!r}, {len(dev)} device(s); compile "
         f"cache at {jax.config.jax_compilation_cache_dir}")

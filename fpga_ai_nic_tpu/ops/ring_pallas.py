@@ -1579,8 +1579,7 @@ def _ag_stream_segmented(owned: jax.Array, axis_name: Optional[str],
                             loopback_n=loopback_n).reshape(-1, sz)
             for off, sz, slice_e in ag_stream_segments(
                 C, slice_elems, cfg.block_size)]
-    out = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
-    return out.reshape(-1)
+    return jnp.concatenate(outs, axis=1).reshape(-1)
 
 
 def ring_all_gather_fused(owned: jax.Array, axis_name: str, *,

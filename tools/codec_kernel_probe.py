@@ -27,19 +27,14 @@ sys.path.insert(0, REPO)
 
 
 def main():
-    from bench_common import (enable_compile_cache, is_tpu_platform, log,
+    from bench_common import (enable_compile_cache, log, require_tpu,
                               save_artifact, slope_timeit)
     import jax
     import jax.numpy as jnp
     from jax import lax
+    platform = require_tpu("codec_kernel_probe")[0].platform
     enable_compile_cache()
     from fpga_ai_nic_tpu.ops import bfp_pallas as bp
-
-    platform = jax.default_backend()
-    if not is_tpu_platform(platform):
-        log(f"platform={platform}: interpret-mode rates are meaningless; "
-            "run on the TPU")
-        return 1
 
     mb = int(sys.argv[1]) if len(sys.argv) > 1 else 64
     K = int(sys.argv[2]) if len(sys.argv) > 2 else 32

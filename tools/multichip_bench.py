@@ -89,9 +89,9 @@ def _child_common():
     n = jax.device_count()
     platform = jax.default_backend()
     dryrun = os.environ.get("MULTICHIP_DRYRUN") == "1"
-    if not dryrun and platform != "tpu":
-        raise SystemExit(f"multichip_bench: jax found no TPU (platform "
-                         f"{platform!r}); --dryrun is the CPU-mesh mode")
+    if not dryrun:                  # --dryrun is the CPU-mesh mode
+        from bench_common import require_tpu
+        require_tpu("multichip_bench")
     if not dryrun and n < 2:
         print(json.dumps({"ok": False, "skipped": True, "n_devices": n,
                           "reason": "needs >= 2 real chips; this surface "

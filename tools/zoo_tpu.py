@@ -28,7 +28,7 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 REPO = os.path.dirname(HERE)
 sys.path.insert(0, REPO)
 
-from bench_common import (bf16_peak, is_tpu_platform, log,  # noqa: E402
+from bench_common import (bf16_peak, log, require_tpu,  # noqa: E402
                           run_attempt, save_artifact)
 
 # the ~16 GB config runs FIRST: the terminal's HBM reclaim between child
@@ -61,7 +61,7 @@ def child_main(name: str, validate: bool = False) -> None:
     enable_compile_cache()
     print(f"[bench] phase=devices t={time.time()-t0:.1f}s", flush=True)
     if not validate:
-        assert is_tpu_platform(jax.devices()[0].platform), jax.devices()
+        require_tpu("zoo_tpu")
     from fpga_ai_nic_tpu.parallel import DPTrainer, make_mesh
     from fpga_ai_nic_tpu.utils.config import (CollectiveConfig, MeshConfig,
                                               OptimizerConfig, TrainConfig)
