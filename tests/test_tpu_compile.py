@@ -10,8 +10,7 @@ refused here.  Nothing runs: these are compiles, never timings
 """
 
 import os
-
-os.environ.setdefault("TPU_LOG_DIR", "disabled")   # else libtpu logs to /tmp
+import types
 
 import numpy as np
 import pytest
@@ -27,21 +26,33 @@ from fpga_ai_nic_tpu.utils.config import (BFPConfig, OptimizerConfig,
                                           OptimizerSpec)
 from fpga_ai_nic_tpu.verify import opstream
 
-try:
-    from jax.experimental import topologies
-    TOPO = topologies.get_topology_desc(platform="tpu",
-                                        topology_name="v5e:2x2")
-except Exception as e:  # noqa: BLE001 — no TPU compiler here: nothing to test
-    pytest.skip(f"v5e:2x2 cannot be described here: {e!r}",
-                allow_module_level=True)
-
 TILE = 16 * 128
 GRAD = 10 * 2048 * 2048            # the 10x2048^2 gradient, no biases
 DP4_PADDED = 41_967_616            # the dp=4 trainer's padded flat length
 DP1_PADDED = 41_963_520            # dp=1: 20,490 tiles, with the biases
-MESH4 = Mesh(np.array(TOPO.devices), ("dp",))
-MESH1 = Mesh(np.array(TOPO.devices[:1]), ("lb",))
-DP = NamedSharding(MESH4, P("dp"))
+
+
+@pytest.fixture(scope="module")
+def chip():
+    """The described v5e:2x2 and the shardings the cases use — made when a
+    case first runs, never at collection, so every worker of a parallel
+    run collects the same cases whether or not it can describe the chip."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else logs to /tmp
+    # several test processes may load the TPU compiler at once; none of
+    # them touches a device, so libtpu's one-process lock has nothing to
+    # guard here
+    os.environ.setdefault("ALLOW_MULTIPLE_LIBTPU_LOAD", "1")
+    try:
+        from jax.experimental import topologies
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler: nothing to test
+        pytest.skip(f"v5e:2x2 cannot be described here: {e!r}")
+    mesh4 = Mesh(np.array(topo.devices), ("dp",))
+    mesh1 = Mesh(np.array(topo.devices[:1]), ("lb",))
+    return types.SimpleNamespace(
+        devices=topo.devices, mesh4=mesh4, mesh1=mesh1,
+        dp=NamedSharding(mesh4, P("dp")), one=NamedSharding(mesh1, P()))
 
 
 @pytest.fixture(scope="module", autouse=True)
@@ -67,87 +78,89 @@ def kernels_in(fn, *args) -> int:
         "tpu_custom_call")
 
 
-def on_mesh4(fn, n_in=1, n_out=1):
-    return jax.shard_map(fn, mesh=MESH4, in_specs=(P("dp"),) * n_in,
+def on_mesh4(chip, fn, n_in=1, n_out=1):
+    return jax.shard_map(fn, mesh=chip.mesh4, in_specs=(P("dp"),) * n_in,
                          out_specs=(P("dp"),) * n_out if n_out > 1
                          else P("dp"), check_vma=False)
 
 
-def on_one_chip(fn):
-    return jax.shard_map(fn, mesh=MESH1, in_specs=P(), out_specs=P(),
+def on_one_chip(chip, fn):
+    return jax.shard_map(fn, mesh=chip.mesh1, in_specs=P(), out_specs=P(),
                          check_vma=False)
-
-
-ONE = NamedSharding(MESH1, P())
 
 
 # -- the codec grid (`bfp_pallas._grid`) -------------------------------------
 
-def _bfp(n):
+def _bfp(chip, n):
     enc = kernels_in(lambda x: bfp_pallas.bfp_encode_inline(
-        x, interpret=False), sds((n,), jnp.float32, ONE))
+        x, interpret=False), sds((n,), jnp.float32, chip.one))
     dec = kernels_in(lambda m, s: bfp_pallas.bfp_decode_inline(
-        m, s, interpret=False), sds((n,), jnp.int8, ONE),
-        sds((n // 16,), jnp.int8, ONE))
+        m, s, interpret=False), sds((n,), jnp.int8, chip.one),
+        sds((n // 16,), jnp.int8, chip.one))
     return enc, dec
 
 
-def _int8(n):
+def _int8(chip, n):
     enc = kernels_in(lambda x: int8.int8_encode_pallas(
-        x, rounding="nearest", interpret=False), sds((n,), jnp.float32, ONE))
+        x, rounding="nearest", interpret=False),
+        sds((n,), jnp.float32, chip.one))
     dec = kernels_in(lambda q, s: int8.int8_decode_pallas(
-        q, s, interpret=False), sds((n,), jnp.int8, ONE),
-        sds((n // 16,), jnp.bfloat16, ONE))
+        q, s, interpret=False), sds((n,), jnp.int8, chip.one),
+        sds((n // 16,), jnp.bfloat16, chip.one))
     return enc, dec
 
 
-def test_bfp_codec_at_the_gradient_size():
-    assert _bfp(GRAD) == (1, 1)
+def test_bfp_codec_at_the_gradient_size(chip):
+    assert _bfp(chip, GRAD) == (1, 1)
 
 
 @pytest.mark.parametrize("tiles", [20_490, 20_492, 20_483])
 @pytest.mark.parametrize("codec", [_bfp, _int8], ids=["bfp", "int8"])
-def test_codec_grid_is_legal_at_awkward_tile_counts(codec, tiles):
+def test_codec_grid_is_legal_at_awkward_tile_counts(chip, codec, tiles):
     """20,490 is the dp=1 trainer's own count (the MLP with its biases):
     the old largest-divisor grid made its scale block (30, 128).  20,492
     gave 47, and the prime 20,483 a grid of one-tile steps."""
-    assert codec(tiles * TILE) == (1, 1)
+    assert codec(chip, tiles * TILE) == (1, 1)
     t, steps = bfp_pallas._grid(tiles, bfp_pallas._DEF_TILES)
     assert t % 32 == 0 and (steps - 1) * t < tiles <= steps * t
 
 
 # -- the ring kernels on the four-chip mesh ----------------------------------
 
-def test_reduce_scatter_resident():
-    assert kernels_in(on_mesh4(lambda x: ring_pallas.ring_reduce_scatter_fused(
-        x, "dp", streaming=False, interpret=False)),
-        sds((4 * 131_072,), jnp.float32, DP)) == 1
+def test_reduce_scatter_resident(chip):
+    assert kernels_in(on_mesh4(
+        chip, lambda x: ring_pallas.ring_reduce_scatter_fused(
+            x, "dp", streaming=False, interpret=False)),
+        sds((4 * 131_072,), jnp.float32, chip.dp)) == 1
 
 
-def test_all_gather_resident():
-    assert kernels_in(on_mesh4(lambda x: ring_pallas.ring_all_gather_fused(
-        x, "dp", streaming=False, interpret=False)),
-        sds((4 * 32_768,), jnp.float32, DP)) == 1
+def test_all_gather_resident(chip):
+    assert kernels_in(on_mesh4(
+        chip, lambda x: ring_pallas.ring_all_gather_fused(
+            x, "dp", streaming=False, interpret=False)),
+        sds((4 * 32_768,), jnp.float32, chip.dp)) == 1
 
 
-def test_reduce_scatter_streaming_at_the_gradient_size():
-    assert kernels_in(on_mesh4(lambda x: ring_pallas.ring_reduce_scatter_fused(
-        x, "dp", streaming=True, interpret=False)),
-        sds((4 * GRAD,), jnp.float32, DP)) == 1
+def test_reduce_scatter_streaming_at_the_gradient_size(chip):
+    assert kernels_in(on_mesh4(
+        chip, lambda x: ring_pallas.ring_reduce_scatter_fused(
+            x, "dp", streaming=True, interpret=False)),
+        sds((4 * GRAD,), jnp.float32, chip.dp)) == 1
 
 
 @pytest.mark.parametrize("owned", [GRAD // 4, DP4_PADDED // 4])
-def test_all_gather_streaming_at_the_gradient_size(owned):
+def test_all_gather_streaming_at_the_gradient_size(chip, owned):
     """The repaired one: S = 256 slices per 2 Mi-element segment asked for
     2 x 258 DMA semaphores and was refused (`sflag`)."""
-    n = kernels_in(on_mesh4(lambda x: ring_pallas.ring_all_gather_fused(
-        x, "dp", streaming=True, interpret=False)),
-        sds((4 * owned,), jnp.float32, DP))
+    n = kernels_in(on_mesh4(
+        chip, lambda x: ring_pallas.ring_all_gather_fused(
+            x, "dp", streaming=True, interpret=False)),
+        sds((4 * owned,), jnp.float32, chip.dp))
     assert n == len(ring_pallas.ag_stream_segments(owned, 8192, 16)) > 1
 
 
 @pytest.mark.parametrize("kind", ["sgd", "adamw"])
-def test_reduce_scatter_update_streaming_at_the_gradient_size(kind):
+def test_reduce_scatter_update_streaming_at_the_gradient_size(chip, kind):
     keys = OptimizerSpec(kind=kind).state_keys
     hyper = optim.fused_hyperparams(
         OptimizerConfig(kind=kind, learning_rate=1e-3),
@@ -159,23 +172,25 @@ def test_reduce_scatter_update_streaming_at_the_gradient_size(kind):
             streaming=True, interpret=False)
         return (g, w2) + tuple(st2[k] for k in keys)
 
-    shards = [sds((GRAD,), jnp.float32, DP)] * (1 + len(keys))
-    assert kernels_in(on_mesh4(fn, 2 + len(keys), 2 + len(keys)),
-                      sds((4 * GRAD,), jnp.float32, DP), *shards) == 1
+    shards = [sds((GRAD,), jnp.float32, chip.dp)] * (1 + len(keys))
+    assert kernels_in(on_mesh4(chip, fn, 2 + len(keys), 2 + len(keys)),
+                      sds((4 * GRAD,), jnp.float32, chip.dp), *shards) == 1
 
 
 # -- the one-chip loopback chip_smoke.py runs --------------------------------
 
-def test_loopback_reduce_scatter_32mib():
-    assert kernels_in(on_one_chip(lambda x: ring_pallas._rs_stream_call(
-        x.reshape(-1, 128), None, 16, 8, "nearest", 8192, False, 7,
-        loopback_n=4)), sds((8 << 20,), jnp.float32, ONE)) == 1
+def test_loopback_reduce_scatter_32mib(chip):
+    assert kernels_in(on_one_chip(
+        chip, lambda x: ring_pallas._rs_stream_call(
+            x.reshape(-1, 128), None, 16, 8, "nearest", 8192, False, 7,
+            loopback_n=4)), sds((8 << 20,), jnp.float32, chip.one)) == 1
 
 
-def test_loopback_all_gather_32mib():
-    n = kernels_in(on_one_chip(lambda x: ring_pallas._ag_stream_segmented(
-        x, None, BFPConfig(), 8192, False, 8, loopback_n=4)),
-        sds((2 << 20,), jnp.float32, ONE))
+def test_loopback_all_gather_32mib(chip):
+    n = kernels_in(on_one_chip(
+        chip, lambda x: ring_pallas._ag_stream_segmented(
+            x, None, BFPConfig(), 8192, False, 8, loopback_n=4)),
+        sds((2 << 20,), jnp.float32, chip.one))
     assert n == len(ring_pallas.ag_stream_segments(2 << 20, 8192, 16)) == 2
 
 
@@ -212,7 +227,7 @@ def test_gather_slot_window_past_the_budget_is_refused_by_name():
 
 @pytest.mark.slow
 @pytest.mark.parametrize("dp,padded", [(1, DP1_PADDED), (4, DP4_PADDED)])
-def test_whole_fused_ring_step_compiles(monkeypatch, dp, padded):
+def test_whole_fused_ring_step_compiles(monkeypatch, chip, dp, padded):
     """DPTrainer's step with the fused-ring config, from shapes alone, at
     the trainer's own padded length.  `_is_tpu` still sees the CPU here, so
     the test steers it — not an option of the program."""
@@ -231,7 +246,7 @@ def test_whole_fused_ring_step_compiles(monkeypatch, dp, padded):
             impl="ring", compression=BFPConfig(), fused_kernel=True,
             fused_optimizer=True),
         optimizer=OptimizerConfig(kind="sgd", learning_rate=0.1))
-    mesh = make_mesh(cfg.mesh, devices=TOPO.devices)
+    mesh = make_mesh(cfg.mesh, devices=chip.devices)
     tr = DPTrainer(lambda p, b: mlp.loss_fn(p, b, mcfg), mesh, cfg)
     like = jax.eval_shape(lambda: mlp.init(jax.random.PRNGKey(0), mcfg))
     tr._ensure_meta(like)
