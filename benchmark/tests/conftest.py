@@ -1,0 +1,10 @@
+"""The benchmark's own tests: `python -m pytest benchmark/tests` from the
+root of the checkout.  They import no part of the program."""
+
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
