@@ -63,6 +63,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from .base import Codec, DTypeLike, register
+from ..obs.names import kernel
 from ..ops import bfp_pallas as _bfp_pl
 from ..ops.bfp_pallas import LANES
 
@@ -188,6 +189,7 @@ def int8_encode_pallas(x: jax.Array, block_size: int = 16,
                                       vma=jax.typeof(x2).vma),
         ],
         interpret=interpret,
+        **kernel("codec.int8_encode"),
     )(x2)
     return q.reshape(n), scale.reshape(n // block_size)
 
@@ -219,6 +221,7 @@ def int8_decode_pallas(q: jax.Array, scale: jax.Array, block_size: int = 16,
             q2.shape, jnp.float32,
             vma=jax.typeof(q2).vma | jax.typeof(s2).vma),
         interpret=interpret,
+        **kernel("codec.int8_decode"),
     )(q2, s2)
     return out.reshape(n).astype(dtype)
 

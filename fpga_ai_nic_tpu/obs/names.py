@@ -1,0 +1,80 @@
+"""The one table of names the program's Pallas kernels and step phases carry
+into the compiled step and the device trace.
+
+Every ``pl.pallas_call`` in the package is written
+``pl.pallas_call(kern, ..., **kernel("ring.rs_update", opt="sgd"))``:
+``name=`` (the table's name with ``.`` as ``_``) names the Mosaic kernel and
+the HLO instruction, and ``metadata=`` rides into the instruction's
+``frontend_attributes={kernel_metadata={...}}``, which the TPU's profiler
+keeps in the event's name.  The benchmark's rules
+(``benchmark/op_classes/05-named-kernels.json``) and PERF.md section 3 agree
+with this table and with nothing else.  Names are compile-time only: nothing
+is added to a step.
+"""
+
+from typing import Dict, Tuple
+
+import jax
+
+METADATA_KEY = "ainic_kernel"
+
+# name -> (layer, what it is).  The layer is the part before the dot.
+KERNELS: Dict[str, Tuple[str, str]] = {
+    "ring.rs": ("ring", "resident reduce-scatter (ops/ring_pallas._rs_call)"),
+    "ring.rs_update": (
+        "ring", "resident reduce-scatter with the optimizer in its last hop"),
+    "ring.rs_stream": (
+        "ring", "streaming reduce-scatter (ops/ring_pallas._rs_stream_call)"),
+    "ring.rs_update_stream": (
+        "ring", "streaming reduce-scatter with the optimizer in its last hop"),
+    "ring.ag": ("ring", "resident all-gather (ops/ring_pallas._ag_call)"),
+    "ring.ag_stream": (
+        "ring", "streaming all-gather, one launch per segment "
+                "(ops/ring_pallas._ag_stream_call)"),
+    "codec.bfp_encode": (
+        "codec", "f32 -> BFP mantissas and scales (ops/bfp_pallas.py)"),
+    "codec.bfp_decode": (
+        "codec", "BFP mantissas and scales -> f32 (ops/bfp_pallas.py)"),
+    "codec.int8_encode": (
+        "codec", "f32 -> int8 and bf16 block scales (compress/int8.py)"),
+    "codec.int8_decode": (
+        "codec", "int8 and bf16 block scales -> f32 (compress/int8.py)"),
+    "attention.flash_fwd": (
+        "attention", "flash attention forward (ops/flash_pallas.py)"),
+    "attention.flash_dq": (
+        "attention", "flash attention backward, dQ (ops/flash_pallas.py)"),
+    "attention.flash_dkv": (
+        "attention", "flash attention backward, dK and dV "
+                     "(ops/flash_pallas.py)"),
+    "attention.paged": (
+        "attention", "paged decode attention (ops/paged_attend_pallas.py)"),
+}
+
+# the phases of DPTrainer's step, as jax.named_scope: metadata on the
+# instructions (op_name), the same program
+SCOPES: Dict[str, str] = {
+    "ainic.fwd_bwd": "loss forward and backward over the micro-batches",
+    "ainic.flatten": "gradient tree -> flat vector (+ error-feedback encode)",
+    "ainic.collective_update": "reduce-scatter, with the update where fused",
+    "ainic.optimizer": "the optimizer's formula where XLA runs it",
+    "ainic.gather": "owned shard -> replicated parameters",
+}
+
+
+def kernel(name: str, **extra: object) -> dict:
+    """``name=`` and ``metadata=`` for ``pl.pallas_call``, splatted into the
+    call.  ``extra`` (``opt="sgd"``, ``ablate="rdma"``) joins the
+    metadata; a value of None is left out.  An unknown name raises."""
+    if name not in KERNELS:
+        raise KeyError(f"{name!r} is not in obs.names.KERNELS: a kernel gets "
+                       f"its name there before a pallas_call carries it")
+    metadata = {METADATA_KEY: name}
+    metadata.update((k, str(v)) for k, v in extra.items() if v is not None)
+    return {"name": name.replace(".", "_"), "metadata": metadata}
+
+
+def scope(name: str):
+    """``jax.named_scope`` for a phase of the step; an unknown name raises."""
+    if name not in SCOPES:
+        raise KeyError(f"{name!r} is not in obs.names.SCOPES")
+    return jax.named_scope(name)

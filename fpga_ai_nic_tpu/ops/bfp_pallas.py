@@ -26,6 +26,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..obs.names import kernel
+
 LANES = 128
 _DEF_TILES = 64  # (64, 16, 128) f32 tiles = 512 KiB per grid step in VMEM
 
@@ -141,6 +143,7 @@ def bfp_encode_inline(x: jax.Array, block_size: int = 16,
                                  vma=jax.typeof(x2).vma),
         ],
         interpret=interpret,
+        **kernel("codec.bfp_encode"),
     )(x2)
     return mant.reshape(n), scale.reshape(n // block_size)
 
@@ -177,6 +180,7 @@ def bfp_decode_inline(mant: jax.Array, scale: jax.Array,
             m2.shape, jnp.float32,
             vma=jax.typeof(m2).vma | jax.typeof(s2).vma),
         interpret=interpret,
+        **kernel("codec.bfp_decode"),
     )(m2, s2)
     return out.reshape(n).astype(dtype)
 

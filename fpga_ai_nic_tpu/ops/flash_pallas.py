@@ -47,6 +47,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .bfp_pallas import _is_tpu
+from ..obs.names import kernel
 
 LANES = 128
 _NEG = -1e30
@@ -207,6 +208,7 @@ def _fwd(q3, k3, v3, off, bias, n_heads, sm_scale, causal, block_q,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        **kernel("attention.flash_fwd"),
     )(*args)
     return out, lse
 
@@ -362,6 +364,7 @@ def _bwd(q3, k3, v3, off, bias, n_heads, out, lse, do, d_lse, sm_scale,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        **kernel("attention.flash_dq"),
     )(*dq_args)
 
     # dkv grid: leading dim is the KV head; the sequential axis g
@@ -408,6 +411,7 @@ def _bwd(q3, k3, v3, off, bias, n_heads, out, lse, do, d_lse, sm_scale,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        **kernel("attention.flash_dkv"),
     )(*dkv_args)
     return dq, dk, dv
 

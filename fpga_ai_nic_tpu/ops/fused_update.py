@@ -34,6 +34,7 @@ from jax import lax
 
 from . import ring as ring_ops
 from .. import optim
+from ..obs.names import scope
 from ..utils.config import CollectiveConfig, OptimizerConfig
 
 
@@ -335,8 +336,9 @@ def reduce_scatter_update(flat_g: jax.Array, w_own: jax.Array, opt_state,
         # separate-op ring; the update below stays the shared formula
     res = reduce_scatter(flat_g, axis_name, coll, integrity=integrity)
     g_own, wire_ok = res if integrity else (res, None)
-    w_new, st2 = optim.fused_apply_flat(spec, w_own, g_own, opt_state,
-                                        hyper, n)
+    with scope("ainic.optimizer"):
+        w_new, st2 = optim.fused_apply_flat(spec, w_own, g_own, opt_state,
+                                            hyper, n)
     if integrity:
         return g_own, w_new, st2, wire_ok
     return g_own, w_new, st2

@@ -61,6 +61,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..obs.names import kernel
 from ..verify import opstream as _opstream
 from .bfp_pallas import _is_tpu
 
@@ -298,5 +299,6 @@ def paged_gather_attend(q, pool_k, pool_v, page_table, pos, *,
             dimension_semantics=("parallel", "parallel"),
             has_side_effects=True),
         interpret=bool(interpret),
+        **kernel("attention.paged"),
     )(page_table, pos, qg, pool_k, pool_v)
     return out.reshape(R, H, T, hd)

@@ -134,6 +134,21 @@ def _as_codec(compression):
     return as_codec(compression)
 
 
+def _varying(x: jax.Array, axis_name: str) -> jax.Array:
+    """x as a ``fori_loop`` over the ring's hops must enter with it: what a
+    hop returns is varying over the ring's axis, so what goes in must be
+    too, or the loop's carry types differ."""
+    if axis_name in jax.typeof(x).vma:
+        return x
+    return lax.pcast(x, axis_name, to="varying")
+
+
+def _zero_carry(axis_name: str):
+    """The integrity checksums' (send, recv) carry, varying like the sums
+    every hop adds to it."""
+    return tuple(_varying(z, axis_name) for z in _integrity.zero_carry())
+
+
 def _send_n_messages(codec, length: int,
                      slice_elems: Optional[int]) -> int:
     """How many distinct wire messages one ``_send`` call emits — the
@@ -291,9 +306,10 @@ def ring_reduce_scatter(x: jax.Array, axis_name: str, *,
                           chk=chk, msg_base=s * stride)
         return ch.at[(idx - s - 2) % n].add(recv), chk
 
-    chunks, (sa, ra) = lax.fori_loop(0, n - 1, hop_i,
-                                     (chunks, _integrity.zero_carry()),
-                                     unroll=unroll)
+    chunks, (sa, ra) = lax.fori_loop(
+        0, n - 1, hop_i,
+        (_varying(chunks, axis_name), _zero_carry(axis_name)),
+        unroll=unroll)
     ok = _integrity.conservation_ok(sa, ra, axis_name)
     return jnp.take(chunks, idx[None], axis=0)[0], ok
 
@@ -375,7 +391,7 @@ def ring_all_gather(owned: jax.Array, axis_name: str, *,
         return out_.at[(idx - s - 1) % n].set(_landed(p)), p, (sa, ra)
 
     out, _, (sa, ra) = lax.fori_loop(
-        0, n - 1, hop_i, (out, pay, _integrity.zero_carry()),
+        0, n - 1, hop_i, (out, pay, _zero_carry(axis_name)),
         unroll=unroll)
     ok = _integrity.conservation_ok(sa, ra, axis_name)
     return out.reshape(n * C), ok

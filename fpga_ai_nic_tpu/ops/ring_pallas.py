@@ -60,6 +60,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .bfp_pallas import LANES, _is_tpu
 from .. import optim as _optim
+from ..obs.names import kernel
 from ..utils.config import BFPConfig, OptimizerSpec
 # the shared protocol IR: the kernels below CONSUME its emitters — the
 # schedule they execute and the stream graftmc explores are one
@@ -672,6 +673,8 @@ def _rs_call(x2, axis_name: Optional[str], block_size: int,
         compiler_params=pltpu.CompilerParams(
             has_side_effects=True, collective_id=collective_id),
         interpret=_interp,
+        **kernel("ring.rs" if opt_kind is None else "ring.rs_update",
+                 opt=opt_kind, ablate=ablate),
     )(*args)
     if opt_kind is None:
         if integrity:
@@ -1105,6 +1108,8 @@ def _rs_stream_call(x2, axis_name: Optional[str], block_size: int,
         compiler_params=pltpu.CompilerParams(
             has_side_effects=True, collective_id=collective_id),
         interpret=_interp,
+        **kernel("ring.rs_stream" if opt_kind is None
+                 else "ring.rs_update_stream", opt=opt_kind, ablate=ablate),
     )(*args)
     chk = None
     if integrity:
@@ -1242,6 +1247,7 @@ def _ag_call(own2, axis_name: Optional[str], block_size: int,
         compiler_params=pltpu.CompilerParams(
             has_side_effects=True, collective_id=collective_id),
         interpret=_interp,
+        **kernel("ring.ag"),
     )(ids, own2)
 
 
@@ -1524,6 +1530,7 @@ def _ag_stream_call(own2, axis_name: Optional[str], block_size: int,
         compiler_params=pltpu.CompilerParams(
             has_side_effects=True, collective_id=collective_id),
         interpret=_interp,
+        **kernel("ring.ag_stream"),
     )(ids, sched, own2)
 
 

@@ -37,6 +37,7 @@ from . import accum
 from . import mesh as mesh_lib
 from .. import optim
 from ..obs import metrics as obs_metrics
+from ..obs.names import scope
 from ..ops import fused_update
 from ..runtime import chaos
 from ..utils.config import TrainConfig
@@ -216,9 +217,11 @@ class DPTrainer:
             # reduce-scatter and forfeits the fused-ring/BFP wire path.
             params_v = jax.tree_util.tree_map(
                 lambda x: lax.pcast(x, ax, to="varying"), params)
-            loss, grads = accum.accumulated_value_and_grad(
-                self.loss_fn, self.cfg.accum_steps)(params_v, batch)
-            flat_g, _ = fused_update.flatten_tree(grads, coll, self.n)
+            with scope("ainic.fwd_bwd"):
+                loss, grads = accum.accumulated_value_and_grad(
+                    self.loss_fn, self.cfg.accum_steps)(params_v, batch)
+            with scope("ainic.flatten"):
+                flat_g, _ = fused_update.flatten_tree(grads, coll, self.n)
             m = {}      # in-graph metrics (obs_on only; else stays empty)
             if ef:
                 # compensate-then-compress: the wire sees the locally
@@ -226,8 +229,9 @@ class DPTrainer:
                 # step (TrainState.codec_state)
                 resid = maybe_resid[0]
                 flat_raw = flat_g
-                flat_g, new_resid = fused_update.error_feedback_encode(
-                    codec, flat_g, resid)
+                with scope("ainic.flatten"):
+                    flat_g, new_resid = fused_update.error_feedback_encode(
+                        codec, flat_g, resid)
                 if obs_on:
                     # flat_g IS roundtrip(flat_raw + resid) here, so the
                     # declared-vs-observed check costs no extra roundtrip
@@ -254,9 +258,10 @@ class DPTrainer:
                 # update): the optimizer runs on zero exposed time, and
                 # the EF residual carry above is untouched by the fusion
                 # (it compensates the LOCAL encode, before the wire)
-                res = fused_update.reduce_scatter_update(
-                    flat_g, w_own, opt_state, step, ax, coll, opt_cfg,
-                    integrity=icheck)
+                with scope("ainic.collective_update"):
+                    res = fused_update.reduce_scatter_update(
+                        flat_g, w_own, opt_state, step, ax, coll, opt_cfg,
+                        integrity=icheck)
                 if icheck:
                     g_sum, w_new, opt_state2, wire_ok = res
                     # BOTH tiers ride the fused path since PR 12: the
@@ -298,8 +303,9 @@ class DPTrainer:
                 return out + ((new_resid,) if ef else ()) + (
                     (m,) if obs_on else ())
             if icheck:
-                g_red, wire_ok = fused_update.reduce_scatter(
-                    flat_g, ax, coll, integrity=True)
+                with scope("ainic.collective_update"):
+                    g_red, wire_ok = fused_update.reduce_scatter(
+                        flat_g, ax, coll, integrity=True)
                 diag = chaos.collective_integrity(expect, l1, g_red, ax,
                                                   self.n, tol)
                 # the EXACT tier (ops.integrity): bit-conservation of the
@@ -307,7 +313,8 @@ class DPTrainer:
                 # band above is provably blind to
                 diag["wire_ok"] = wire_ok
             else:
-                g_red = fused_update.reduce_scatter(flat_g, ax, coll)
+                with scope("ainic.collective_update"):
+                    g_red = fused_update.reduce_scatter(flat_g, ax, coll)
             g_own = g_red / self.n
             if icheck:
                 diag["grad_norm"] = jnp.sqrt(
@@ -318,9 +325,10 @@ class DPTrainer:
                 m["grad_norm"] = diag["grad_norm"] if "grad_norm" in diag \
                     else jnp.sqrt(lax.psum(
                         jnp.sum(g_own.astype(jnp.float32) ** 2), ax))
-            g_own = optim.clip_by_global_norm(opt_cfg, g_own, (ax,))
-            w_new, opt_state2 = optim.apply(opt_cfg, w_own, g_own,
-                                            opt_state, step)
+            with scope("ainic.optimizer"):
+                g_own = optim.clip_by_global_norm(opt_cfg, g_own, (ax,))
+                w_new, opt_state2 = optim.apply(opt_cfg, w_own, g_own,
+                                                opt_state, step)
             if icheck:
                 # gate the update: a corrupted reduce-scatter must not
                 # reach the master weights — the step becomes a no-op and
@@ -351,6 +359,7 @@ class DPTrainer:
         # REPLICATED params (the masters are safe), so the verdict is
         # surfaced for check_step_diag — the elastic ladder rebuilds the
         # params from the still-clean masters.
+        @scope("ainic.gather")
         def shard_gather(w_new):
             if coll.integrity_check:
                 flat_w, ag_ok = fused_update.all_gather_flat(

@@ -9,7 +9,9 @@ refused here.  Nothing runs: these are compiles, never timings
 (/opt/skills/guides/on-chip-measurement, section 2).
 """
 
+import json
 import os
+import re
 import types
 
 import numpy as np
@@ -21,7 +23,9 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from fpga_ai_nic_tpu import optim
 from fpga_ai_nic_tpu.compress import int8
-from fpga_ai_nic_tpu.ops import bfp_pallas, ring_pallas
+from fpga_ai_nic_tpu.obs import names as obs_names
+from fpga_ai_nic_tpu.ops import (bfp_pallas, flash_pallas,
+                                 paged_attend_pallas, ring_pallas)
 from fpga_ai_nic_tpu.utils.config import (BFPConfig, OptimizerConfig,
                                           OptimizerSpec)
 from fpga_ai_nic_tpu.verify import opstream
@@ -72,10 +76,14 @@ def sds(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
+def compiled_text(fn, *args) -> str:
+    """Compile for the described chip; the compiler's own text."""
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
 def kernels_in(fn, *args) -> int:
-    """Compile for the described chip; the number of Pallas calls in it."""
-    return jax.jit(fn).lower(*args).compile().as_text().count(
-        "tpu_custom_call")
+    """The number of Pallas calls in fn, compiled for the described chip."""
+    return compiled_text(fn, *args).count("tpu_custom_call")
 
 
 def on_mesh4(chip, fn, n_in=1, n_out=1):
@@ -159,8 +167,9 @@ def test_all_gather_streaming_at_the_gradient_size(chip, owned):
     assert n == len(ring_pallas.ag_stream_segments(owned, 8192, 16)) > 1
 
 
-@pytest.mark.parametrize("kind", ["sgd", "adamw"])
-def test_reduce_scatter_update_streaming_at_the_gradient_size(chip, kind):
+def _rs_update(chip, streaming, kind="sgd", n=4 * 131_072):
+    """(fn, shapes) of the reduce-scatter+update on the four-chip mesh,
+    n elements owned per chip."""
     keys = OptimizerSpec(kind=kind).state_keys
     hyper = optim.fused_hyperparams(
         OptimizerConfig(kind=kind, learning_rate=1e-3),
@@ -169,12 +178,17 @@ def test_reduce_scatter_update_streaming_at_the_gradient_size(chip, kind):
     def fn(x, w, *st):
         g, w2, st2 = ring_pallas.ring_reduce_scatter_update_fused(
             x, w, dict(zip(keys, st)), hyper, "dp", opt_kind=kind,
-            streaming=True, interpret=False)
+            streaming=streaming, interpret=False)
         return (g, w2) + tuple(st2[k] for k in keys)
 
-    shards = [sds((GRAD,), jnp.float32, chip.dp)] * (1 + len(keys))
-    assert kernels_in(on_mesh4(chip, fn, 2 + len(keys), 2 + len(keys)),
-                      sds((4 * GRAD,), jnp.float32, chip.dp), *shards) == 1
+    return (on_mesh4(chip, fn, 2 + len(keys), 2 + len(keys)),
+            sds((4 * n,), jnp.float32, chip.dp),
+            *[sds((n,), jnp.float32, chip.dp)] * (1 + len(keys)))
+
+
+@pytest.mark.parametrize("kind", ["sgd", "adamw"])
+def test_reduce_scatter_update_streaming_at_the_gradient_size(chip, kind):
+    assert kernels_in(*_rs_update(chip, True, kind, n=GRAD)) == 1
 
 
 # -- the one-chip loopback chip_smoke.py runs --------------------------------
@@ -192,6 +206,112 @@ def test_loopback_all_gather_32mib(chip):
             x, None, BFPConfig(), 8192, False, 8, loopback_n=4)),
         sds((2 << 20,), jnp.float32, chip.one))
     assert n == len(ring_pallas.ag_stream_segments(2 << 20, 8192, 16)) == 2
+
+
+# -- the names the kernels carry into the compiled program --------------------
+
+NAMED_CALL_RE = re.compile(
+    r'%(\S+) = [^\n]*custom_call_target="tpu_custom_call"[^\n]*'
+    r'kernel_metadata=\{([^}]*)\}')
+
+
+def named_calls(text):
+    """[(instruction name, kernel_metadata as a dict)] of every Pallas call
+    in a compiled program's text."""
+    return [(instr, json.loads("{%s}" % meta))
+            for instr, meta in NAMED_CALL_RE.findall(text)]
+
+
+def _rs(chip, streaming):
+    return (on_mesh4(chip, lambda x: ring_pallas.ring_reduce_scatter_fused(
+        x, "dp", streaming=streaming, interpret=False)),
+        sds((16 * 131_072,), jnp.float32, chip.dp))
+
+
+def _ag(chip, streaming):
+    return (on_mesh4(chip, lambda x: ring_pallas.ring_all_gather_fused(
+        x, "dp", streaming=streaming, interpret=False)),
+        sds((4 * (131_072 if streaming else 32_768),), jnp.float32, chip.dp))
+
+
+N_CODEC = 64 * TILE
+NAMED_SITES = {
+    "ring.rs": lambda c: _rs(c, False),
+    "ring.rs_stream": lambda c: _rs(c, True),
+    "ring.rs_update": lambda c: _rs_update(c, False),
+    "ring.rs_update_stream": lambda c: _rs_update(c, True, "adamw"),
+    "ring.ag": lambda c: _ag(c, False),
+    "ring.ag_stream": lambda c: _ag(c, True),
+    "codec.bfp_encode": lambda c: (
+        lambda x: bfp_pallas.bfp_encode_inline(x, interpret=False),
+        sds((N_CODEC,), jnp.float32, c.one)),
+    "codec.bfp_decode": lambda c: (
+        lambda m, s: bfp_pallas.bfp_decode_inline(m, s, interpret=False),
+        sds((N_CODEC,), jnp.int8, c.one),
+        sds((N_CODEC // 16,), jnp.int8, c.one)),
+    "codec.int8_encode": lambda c: (
+        lambda x: int8.int8_encode_pallas(x, rounding="nearest",
+                                          interpret=False),
+        sds((N_CODEC,), jnp.float32, c.one)),
+    "codec.int8_decode": lambda c: (
+        lambda q, s: int8.int8_decode_pallas(q, s, interpret=False),
+        sds((N_CODEC,), jnp.int8, c.one),
+        sds((N_CODEC // 16,), jnp.bfloat16, c.one)),
+    "attention.paged": lambda c: (
+        lambda q, pk, pv, pt, pos: paged_attend_pallas.paged_gather_attend(
+            q, pk, pv, pt, pos, page_size=128, interpret=False),
+        sds((2, 4, 1, 128), jnp.float32, c.one),
+        sds((16, 2, 128, 128), jnp.bfloat16, c.one),
+        sds((16, 2, 128, 128), jnp.bfloat16, c.one),
+        sds((2, 4), jnp.int32, c.one), sds((2,), jnp.int32, c.one)),
+}
+
+
+@pytest.mark.parametrize("name", sorted(NAMED_SITES))
+def test_compiled_kernel_holds_its_own_name(chip, name):
+    """`name=` names the instruction, `metadata=` rides in the
+    instruction's frontend attributes — which is where the v5e's profiler
+    prints it (seen on the chip, PR 25)."""
+    fn, *args = NAMED_SITES[name](chip)
+    calls = named_calls(compiled_text(fn, *args))
+    assert calls and all(
+        instr.startswith(meta["ainic_kernel"].replace(".", "_") + ".")
+        and meta["ainic_kernel"] in obs_names.KERNELS
+        for instr, meta in calls)
+    metas = [meta for _, meta in calls if meta["ainic_kernel"] == name]
+    assert metas
+    if "update" in name:
+        assert all(m["opt"] in ("sgd", "adamw") for m in metas)
+
+
+def test_ablated_kernel_says_so_in_the_compiled_text(chip):
+    (_, meta), = named_calls(compiled_text(on_one_chip(
+        chip, lambda x: ring_pallas._rs_stream_call(
+            x.reshape(-1, 128), None, 16, 8, "nearest", 8192, False, 7,
+            loopback_n=4, ablate="rdma")),
+        sds((4 * 131_072,), jnp.float32, chip.one)))
+    assert meta == {"ainic_kernel": "ring.rs_stream", "ablate": "rdma"}
+
+
+def test_flash_kernels_are_named_where_the_compiler_speaks_of_them(chip):
+    """`flash_pallas` does not compile for the chip yet (PERF.md, S3): the
+    refusal now names the kernel.  Once it compiles, all three kernels
+    must stand in the text under their names."""
+    qkv = [sds((2, 4, 256, 64), jnp.bfloat16, chip.one)] * 3
+
+    def grads(q, k, v):
+        return jax.grad(lambda *a: flash_pallas.flash_attention(
+            *a, interpret=False).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2))(q, k, v)
+
+    try:
+        text = compiled_text(grads, *qkv)
+    except ValueError as e:
+        assert "pallas_call attention_flash_" in str(e)
+    else:
+        assert {m["ainic_kernel"] for _, m in named_calls(text)} == {
+            "attention.flash_fwd", "attention.flash_dq",
+            "attention.flash_dkv"}
 
 
 # -- the semaphore bound, in plain Python ------------------------------------
@@ -263,3 +383,7 @@ def test_whole_fused_ring_step_compiles(monkeypatch, chip, dp, padded):
     want = 2 if dp == 1 else 1 + len(ring_pallas.ag_stream_segments(
         padded // dp, 8192, 16))
     assert hlo.count("tpu_custom_call") == want
+    got = sorted(meta["ainic_kernel"] for _, meta in named_calls(hlo))
+    assert got == (["codec.bfp_decode", "codec.bfp_encode"] if dp == 1 else
+                   ["ring.ag_stream"] * (want - 1)
+                   + ["ring.rs_update_stream"])
