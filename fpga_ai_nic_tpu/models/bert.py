@@ -15,8 +15,9 @@ Functional pytree params like models.mlp / models.llama.
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
-from typing import Dict, Optional
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -100,9 +101,10 @@ def _layernorm(x: jax.Array, p: Dict, eps: float) -> jax.Array:
     return ((xf - mu) * lax.rsqrt(var + eps)).astype(x.dtype) * p["g"] + p["b"]
 
 
-def apply(params: Dict, tokens: jax.Array, cfg: BertConfig,
-          attention_mask: Optional[jax.Array] = None) -> jax.Array:
-    """tokens [B, S] -> MLM logits [B, S, vocab].
+def encode(params: Dict, tokens: jax.Array, cfg: BertConfig,
+           attention_mask: Optional[jax.Array] = None) -> jax.Array:
+    """tokens [B, S] -> hidden states [B, S, D]: everything below the MLM
+    head.
 
     attention_mask: [B, S] bool/int, 1 = attend; derived from
     ``tokens != pad_id`` when omitted.
@@ -146,17 +148,156 @@ def apply(params: Dict, tokens: jax.Array, cfg: BertConfig,
 
         h = jax.nn.gelu((x @ lyr["w1"]).astype(jnp.float32)).astype(x.dtype)
         x = _layernorm(x + h @ lyr["w2"], lyr["ffn_norm"], cfg.norm_eps)
+    return x
 
-    h = jax.nn.gelu((x @ params["mlm_dense"]).astype(jnp.float32)
-                    ).astype(x.dtype)
+
+def mlm_head(params: Dict, hidden: jax.Array, cfg: BertConfig) -> jax.Array:
+    """hidden [..., D] -> MLM logits [..., vocab]: dense, GELU, LayerNorm,
+    tied decoder (logits through tok_emb^T), bias."""
+    h = jax.nn.gelu((hidden @ params["mlm_dense"]).astype(jnp.float32)
+                    ).astype(hidden.dtype)
     h = _layernorm(h, params["mlm_norm"], cfg.norm_eps)
     return h @ params["tok_emb"].T + params["mlm_bias"]   # tied decoder
+
+
+def apply(params: Dict, tokens: jax.Array, cfg: BertConfig,
+          attention_mask: Optional[jax.Array] = None) -> jax.Array:
+    """tokens [B, S] -> MLM logits [B, S, vocab], on every position (the
+    training loss computes the head on the masked positions only)."""
+    return mlm_head(params, encode(params, tokens, cfg, attention_mask), cfg)
+
+
+# Rows of logits alive at one time in the loss.  The head runs block by
+# block over the masked positions only, so a step holds [MLM_BLOCK, vocab]
+# logits and never [tokens, vocab].  Chosen on the chip (PERF.md, PR 26):
+# half a block of rows is computed for nothing on average, and every block
+# adds its weight gradients to [vocab, dim] float32 sums, which is why
+# smaller is not better (256 measured slower than 512 or 1,024).
+MLM_BLOCK = 1024
+
+
+def _mlm_blocks(n_positions: int, n_masked) -> Tuple[int, int, jax.Array]:
+    """(rows a block, blocks that cover every position, blocks that hold a
+    masked one).  The last is computed from the labels at run time."""
+    block = min(MLM_BLOCK, n_positions)
+    return block, -(-n_positions // block), -(-n_masked // block)
+
+
+def mlm_head_rows(labels) -> Tuple[int, int]:
+    """(rows `loss_fn` computes the MLM head on, positions): the masked
+    count rounded up to whole blocks — the head's cost follows the labels."""
+    labels = jnp.asarray(labels)
+    block, _, live = _mlm_blocks(labels.size, jnp.sum(labels >= 0))
+    return int(live) * block, labels.size
+
+
+def _varying_like(x: jax.Array, *like) -> jax.Array:
+    """x, varying over the manual mesh axes any leaf of `like` varies over:
+    under shard_map a loop's carry must enter with the type it leaves with
+    (as ops/ring.py::_varying)."""
+    axes = frozenset().union(*(jax.typeof(leaf).vma for leaf in
+                               jax.tree_util.tree_leaves(like)))
+    axes -= jax.typeof(x).vma
+    return lax.pcast(x, tuple(axes), to="varying") if axes else x
+
+
+def _gather_rows(hidden: jax.Array, idx: jax.Array) -> jax.Array:
+    """Rows `idx` (each at most once) of hidden [T, D]; T itself, one past
+    the end, reads a row of zeros."""
+    return hidden.at[idx].get(mode="fill", fill_value=0, unique_indices=True)
+
+
+def _rows_nll(head: Dict, hidden: jax.Array, labels: jax.Array,
+              cfg: BertConfig) -> jax.Array:
+    """Summed cross-entropy of the rows with ``labels >= 0``: hidden [R, D],
+    labels [R] -> scalar.  Logits [R, vocab] in float32."""
+    valid = labels >= 0
+    logz = jax.nn.log_softmax(
+        mlm_head(head, hidden, cfg).astype(jnp.float32), axis=-1)
+    nll = -jnp.take_along_axis(
+        logz, jnp.where(valid, labels, 0)[:, None], axis=-1)[:, 0]
+    return jnp.sum(jnp.where(valid, nll, 0.0))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _live_nll(cfg: BertConfig, head: Dict, hidden: jax.Array,
+              order: jax.Array, labels: jax.Array,
+              n_live: jax.Array) -> jax.Array:
+    """`_rows_nll` summed over the first `n_live` blocks of rows: hidden
+    [T, D]; order (row indices for `_gather_rows`) and labels, both
+    [blocks, block].  The trip count is a value of the run, so a block past
+    it costs nothing; reverse mode cannot differentiate such a loop itself,
+    hence the backward loop below, which recomputes each live block's
+    logits instead of keeping them."""
+    def body(i, total):
+        return total + _rows_nll(head, _gather_rows(hidden, order[i]),
+                                 labels[i], cfg)
+    zero = _varying_like(jnp.float32(0), head, hidden)
+    return lax.fori_loop(0, n_live, body, zero)
+
+
+def _live_nll_fwd(cfg, head, hidden, order, labels, n_live):
+    return (_live_nll(cfg, head, hidden, order, labels, n_live),
+            (head, hidden, order, labels, n_live))
+
+
+def _live_nll_bwd(cfg, res, g):
+    head, hidden, order, labels, n_live = res
+
+    def zeros(x, dtype):
+        return _varying_like(jnp.zeros(x.shape, dtype), head, hidden)
+
+    def body(i, carry):
+        g_head, g_hidden = carry
+        _, vjp = jax.vjp(lambda p, h: _rows_nll(p, h, labels[i], cfg),
+                         head, _gather_rows(hidden, order[i]))
+        d_head, d_rows = vjp(g)
+        g_head = jax.tree_util.tree_map(
+            lambda acc, d: acc + d.astype(acc.dtype), g_head, d_head)
+        return g_head, g_hidden.at[order[i]].set(d_rows, mode="drop",
+                                                 unique_indices=True)
+
+    # the weights' gradients are summed over the blocks in float32
+    g_head, g_hidden = lax.fori_loop(0, n_live, body, (
+        jax.tree_util.tree_map(lambda x: zeros(x, jnp.float32), head),
+        zeros(hidden, hidden.dtype)))
+    g_head = jax.tree_util.tree_map(lambda acc, x: acc.astype(x.dtype),
+                                    g_head, head)
+    return g_head, g_hidden, None, None, None
+
+
+_live_nll.defvjp(_live_nll_fwd, _live_nll_bwd)
+
+
+def _masked_nll(params: Dict, hidden: jax.Array, labels: jax.Array,
+                cfg: BertConfig) -> jax.Array:
+    """Summed cross-entropy of the masked positions: hidden [T, D], labels
+    [T] -> scalar, exact for every share of masked positions.
+
+    The positions with ``labels >= 0`` are listed first (stable) and the
+    list padded to whole blocks of MLM_BLOCK with unmasked rows; the head
+    and the cross-entropy run on the blocks that hold a masked position,
+    one at a time (`_live_nll`), and no other row is gathered."""
+    T = labels.shape[0]
+    valid = labels >= 0
+    block, n_blocks, n_live = _mlm_blocks(T, jnp.sum(valid))
+    order = jnp.concatenate([
+        jnp.argsort(jnp.logical_not(valid), stable=True),
+        jnp.arange(T, n_blocks * block)])
+    labels = labels.at[order].get(mode="fill", fill_value=-100)
+    head = jax.tree_util.tree_map(
+        lambda x: _varying_like(x, hidden),
+        {k: params[k] for k in
+         ("mlm_dense", "mlm_norm", "tok_emb", "mlm_bias")})
+    return _live_nll(cfg, head, hidden, order.reshape(n_blocks, block),
+                     labels.reshape(n_blocks, block), n_live)
 
 
 def loss_fn(params: Dict, batch, cfg: BertConfig, *,
             dp_axis: Optional[str] = None) -> jax.Array:
     """Masked-LM cross-entropy.  batch = (tokens, labels), labels [B, S]
-    with -100 on unmasked positions (standard MLM convention).
+    with -100 on unmasked positions (standard MLM convention).  The head is
+    computed on the masked positions only (`_masked_nll`).
 
     dp_axis: as in models.llama.loss_fn — under a dp trainer that averages
     gradients uniformly (mean over dp), masked-token counts differ per
@@ -166,11 +307,9 @@ def loss_fn(params: Dict, batch, cfg: BertConfig, *,
     """
     tokens, labels = batch
     valid = labels >= 0
-    safe = jnp.where(valid, labels, 0)
-    logits = apply(params, tokens, cfg)
-    logz = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-    nll = -jnp.take_along_axis(logz, safe[..., None], axis=-1)[..., 0]
-    nll = jnp.where(valid, nll, 0.0)
+    hidden = encode(params, tokens, cfg)
+    nll = _masked_nll(params, hidden.reshape(-1, hidden.shape[-1]),
+                      labels.reshape(-1), cfg)
     local_sum = jnp.sum(nll)
     count = jnp.sum(valid)
     if dp_axis is None:

@@ -7,6 +7,7 @@ with the BFP-compressed bucketed ring.
 """
 
 import dataclasses
+import re
 
 import numpy as np
 import pytest
@@ -216,3 +217,181 @@ def test_num_params_matches_init():
     total = sum(int(np.prod(l.shape))
                 for l in jax.tree_util.tree_leaves(params))
     assert total == bert.num_params(MCFG)
+
+
+# -- the MLM head runs on the masked positions only (PR 26) -------------------
+
+def _plain_loss(params, batch, cfg):
+    """The formula `loss_fn` had before it went block by block: the head on
+    every position, log-softmax over [B, S, vocab], the mask last."""
+    tokens, labels = batch
+    valid = labels >= 0
+    logz = jax.nn.log_softmax(
+        bert.apply(params, tokens, cfg).astype(jnp.float32), axis=-1)
+    nll = -jnp.take_along_axis(
+        logz, jnp.where(valid, labels, 0)[..., None], axis=-1)[..., 0]
+    return jnp.sum(jnp.where(valid, nll, 0.0)) / jnp.maximum(
+        jnp.sum(valid), 1)
+
+
+def _masked_batch(rng, cfg, n, S, share):
+    """share: a fraction of the positions, or "one" for a single position."""
+    toks = rng.integers(4, cfg.vocab, (n, S)).astype(np.int32)
+    if share == "one":
+        m = np.zeros((n, S), bool)
+        m[n // 2, S // 3] = True
+    else:
+        m = rng.random((n, S)) < share
+    labels = np.where(m, toks, -100).astype(np.int32)
+    return jnp.asarray(np.where(m, 3, toks)), jnp.asarray(labels)
+
+
+def _rel_err(got, want):
+    """Relative L2 error of every leaf, in float32."""
+    def one(g, w):
+        g, w = g.astype(jnp.float32), w.astype(jnp.float32)
+        return float(jnp.linalg.norm((g - w).ravel())
+                     / jnp.maximum(jnp.linalg.norm(w.ravel()), 1e-30))
+    return jax.tree_util.tree_map(one, got, want)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("n", [4, 5], ids=["T=2blocks", "T=2.5blocks"])
+@pytest.mark.parametrize("share", ["one", 0.15, 0.5, 1.0, 0.0],
+                         ids=["one", "15%", "50%", "all", "none"])
+def test_blockwise_loss_and_gradients_match_the_plain_formula(
+        rng, monkeypatch, share, n, dtype):
+    """float32: equal to 2e-6 on every leaf.  bfloat16: two roundings of
+    the same mathematics differ by more than that from each other, so both
+    are held against the plain formula in float32 on the same weights, and
+    the blockwise one may be no further from it than half again the plain
+    one's distance."""
+    monkeypatch.setattr(bert, "MLM_BLOCK", 64)
+    cfg = dataclasses.replace(MCFG, dtype=dtype)
+    cfg32 = dataclasses.replace(MCFG, dtype="float32")
+    params = bert.init(jax.random.PRNGKey(2), cfg)
+    batch = _masked_batch(rng, cfg, n, 32, share)
+    plain = jax.value_and_grad(_plain_loss)
+    want_l, want_g = plain(params, batch, cfg)
+    got_l, got_g = jax.jit(jax.value_and_grad(
+        lambda p, b: bert.loss_fn(p, b, cfg)))(params, batch)
+    for got, want in zip(jax.tree_util.tree_leaves(got_g),
+                         jax.tree_util.tree_leaves(want_g)):
+        assert got.dtype == want.dtype and got.shape == want.shape
+        assert np.all(np.isfinite(np.asarray(got, np.float32)))
+    if share == 0.0:
+        assert float(got_l) == 0.0
+        for leaf in jax.tree_util.tree_leaves(got_g):
+            assert not np.any(np.asarray(leaf, np.float32))
+        return
+    if dtype == "float32":
+        np.testing.assert_allclose(float(got_l), float(want_l), rtol=1e-6)
+        errs = _rel_err(got_g, want_g)
+        # tok_emb takes the head's gradient and the embedding's
+        assert errs["tok_emb"] <= 2e-6, errs
+        assert max(jax.tree_util.tree_leaves(errs)) <= 2e-6, errs
+        return
+    true_l, true_g = plain(jax.tree_util.tree_map(
+        lambda x: x.astype(jnp.float32), params), batch, cfg32)
+    assert abs(float(got_l) - float(true_l)) <= max(
+        abs(float(want_l) - float(true_l)), 1e-3 * float(true_l))
+    ours, theirs = _rel_err(got_g, true_g), _rel_err(want_g, true_g)
+    jax.tree_util.tree_map(
+        lambda a, b: np.testing.assert_array_less(a, 1.5 * b + 3e-3),
+        ours, theirs)
+
+
+@pytest.mark.parametrize("check_vma", [True, False])
+def test_blockwise_loss_under_dp_with_unequal_counts(rng, monkeypatch,
+                                                     check_vma):
+    """dp=4, one block a shard and a half: the shards hold 0, 1, 20 and 48
+    masked positions (none, one block, one block, two), so their loops run
+    different trip counts.  Loss = the global token-weighted mean; the
+    trainer-effective gradient (sum over replicas / n) = the single-device
+    gradient."""
+    monkeypatch.setattr(bert, "MLM_BLOCK", 32)
+    n_dp, per, S = 4, 3, 16                 # 48 positions a shard
+    params = bert.init(jax.random.PRNGKey(3), MCFG)
+    toks = rng.integers(4, MCFG.vocab, (n_dp * per, S)).astype(np.int32)
+    m = np.zeros((n_dp, per * S), bool)
+    m[1, 7] = True
+    m[2, rng.choice(per * S, 20, replace=False)] = True
+    m[3] = True
+    m = m.reshape(n_dp * per, S)
+    batch = (jnp.asarray(np.where(m, 3, toks)),
+             jnp.asarray(np.where(m, toks, -100).astype(np.int32)))
+    want_l, want_g = jax.value_and_grad(_plain_loss)(params, batch, MCFG)
+
+    def shard(p, b):
+        p = jax.tree_util.tree_map(
+            lambda x: lax.pcast(x, "dp", to="varying"), p)
+        loss, g = jax.value_and_grad(
+            lambda pp: bert.loss_fn(pp, b, MCFG, dp_axis="dp"))(p)
+        return (lax.pmax(loss, "dp"), jax.tree_util.tree_map(
+            lambda x: lax.psum(x, "dp") / n_dp, g))
+
+    mesh = make_mesh(MeshConfig(dp=n_dp), devices=jax.devices()[:n_dp])
+    got_l, got_g = jax.jit(jax.shard_map(
+        shard, mesh=mesh, in_specs=(P(), P("dp")), out_specs=(P(), P()),
+        check_vma=check_vma))(params, batch)
+    np.testing.assert_allclose(float(got_l), float(want_l), rtol=1e-6)
+    errs = _rel_err(got_g, want_g)
+    assert max(jax.tree_util.tree_leaves(errs)) <= 2e-6, errs
+
+
+def _array_sizes(text):
+    """Element counts of every tensor type in a lowered module's text."""
+    return [int(np.prod([int(d) for d in dims[:-1].split("x")]))
+            for dims in re.findall(r"tensor<((?:\d+x)+)[a-z]", text)]
+
+
+def test_no_array_of_tokens_by_vocab_in_the_gradient(rng, monkeypatch):
+    """vocab 512 and T = 128: tokens x vocab = 65,536 elements, twice the
+    next largest array of the step (tok_emb, 32,768).  The plain formula's
+    gradient holds such arrays; the blockwise one holds [block, vocab]."""
+    monkeypatch.setattr(bert, "MLM_BLOCK", 32)
+    cfg = dataclasses.replace(MCFG, vocab=512)
+    params = bert.init(jax.random.PRNGKey(4), cfg)
+    batch = _masked_batch(rng, cfg, 4, 32, 0.15)
+    T = batch[0].size
+
+    def sizes(loss):
+        return _array_sizes(jax.jit(jax.grad(loss)).lower(
+            params, batch).as_text())
+
+    plain = sizes(lambda p, b: _plain_loss(p, b, cfg))
+    assert max(plain) >= T * cfg.vocab          # the test can see them
+    got = sizes(lambda p, b: bert.loss_fn(p, b, cfg))
+    assert 32 * cfg.vocab in got                # one block of logits
+    assert max(got) < T * cfg.vocab, max(got)
+    assert max(got) == cfg.vocab * cfg.dim      # tok_emb and its gradient
+
+
+@pytest.mark.parametrize("T,masked,block,want", [
+    (160, 0, 64, 0), (160, 1, 64, 64), (160, 64, 64, 64), (160, 65, 64, 128),
+    (160, 160, 64, 192),                # 2.5 blocks: the last one is padded
+    (16384, 2458, 1024, 3072),          # the benchmark's cells at 15%
+    (16384, 16384, 1024, 16384),
+    (100, 1, 1024, 100)])               # fewer positions than a block
+def test_mlm_head_rows_against_counts_worked_by_hand(monkeypatch, T, masked,
+                                                     block, want):
+    monkeypatch.setattr(bert, "MLM_BLOCK", block)
+    labels = np.full(T, -100, np.int32)
+    labels[np.random.default_rng(T + masked).choice(T, masked,
+                                                    replace=False)] = 7
+    assert bert.mlm_head_rows(labels.reshape(4, -1)) == (want, T)
+
+
+def test_apply_is_the_head_on_the_encoder_at_every_position(rng):
+    params = bert.init(jax.random.PRNGKey(1), MCFG)
+    toks, _ = _data(rng, n=3)
+    hidden = bert.encode(params, toks, MCFG)
+    assert hidden.shape == (3, 32, MCFG.dim)
+    logits = bert.apply(params, toks, MCFG)
+    assert logits.shape == (3, 32, MCFG.vocab)
+    np.testing.assert_array_equal(
+        np.asarray(logits), np.asarray(bert.mlm_head(params, hidden, MCFG)))
+    rows = bert.mlm_head(params, hidden.reshape(-1, MCFG.dim)[5:9], MCFG)
+    np.testing.assert_allclose(np.asarray(rows),
+                               np.asarray(logits).reshape(-1, MCFG.vocab)[5:9],
+                               atol=1e-5)
