@@ -1,0 +1,330 @@
+"""Family `glm_moe`: GLM-4.7-Flash (`glm4_moe_lite`) — a pre-norm decoder
+with latent attention (MLA), one leading dense SwiGLU layer, then layers of
+sigmoid-routed experts beside a shared expert, an untied head.  Configuration
+keys are those of the published config.json; the file adds `router_width`
+(the router scores every expert of the layer, however few are held here),
+`ep_size` / `ep_rank` (this chip holds experts rank*held .. rank*held+held-1
+of an expert-parallel layer), `compute_dtype`, `attn_impl`, `attn_block`.
+
+The equations the reference below follows, for a token's residual x
+(RMSNorm eps `rms_norm_eps`; the parameter tree is the program's: `dense` a
+list of the leading layers, `moe` every expert layer stacked on a leading
+axis):
+
+- h = RMSNorm(x).  c_q = RMSNorm(h W_qa); q = c_q W_qb -> heads x (nope |
+  rope).  [c_kv | k_r] = h W_kva; c_kv = RMSNorm(c_kv); [k_nope | v] = c_kv
+  W_kvb -> heads x (nope | v); k_r is one rotary key for all heads.  q_h =
+  [q_nope | RoPE(q_rope)], k_h = [k_nope | RoPE(k_r)] (rotate-half, theta
+  `rope_theta`); scores q_h.k_h / sqrt(nope + rope), causal softmax, o_h = P
+  v_h; x += concat(o_h) W_o.
+- leading layers: x += W_2 (silu(W_1 h') * W_3 h'), h' = RMSNorm(x).
+- expert layers: s = sigmoid(h' W_r) over all `router_width` experts; the
+  `num_experts_per_tok` largest are selected (the selection bias is zero;
+  `n_group` = `topk_group` = 1 makes the group limit the identity); g_i =
+  `routed_scaling_factor` * s_i / sum_selected s_j; x += sum_{i selected and
+  held} g_i E_i(h') + E_shared(h').  What the absent experts would add is
+  left out, in the program and here alike.
+- logits = RMSNorm(x) W_head over the rows of the vocabulary held; the loss
+  is the summed next-token negative log-likelihood, nothing beside it.
+
+`program` is the only place that touches the system under test.
+"""
+
+import jax
+import jax.numpy as jnp
+
+ITEM = "tokens"
+THROUGHPUT = "tokens_per_s_per_chip"
+
+# First step against the float32 reference below; PERF.md (Findings, PR 29)
+# has the readings both limits are set from.
+LOSS_RTOL = 1e-2
+GRAD_TOL = 5e-2
+
+# For the control that must read `correct: false` (PERF.md): the type the
+# reference's matmul operands are rounded to.  None: float32, the reference.
+OPERAND_DTYPE = None
+
+
+def held_experts(config: dict) -> tuple:
+    """Ids of the routed experts this chip holds: the `ep_rank`-th run of
+    `n_routed_experts` (the count held) among `router_width`."""
+    n, first = config["n_routed_experts"], (config["ep_rank"]
+                                            * config["n_routed_experts"])
+    if config["ep_size"] * n != config["router_width"]:
+        raise ValueError(f"{config['ep_size']} chips of {n} experts are not "
+                         f"the router's {config['router_width']}")
+    return tuple(range(first, first + n))
+
+
+def model_config(config: dict):
+    from fpga_ai_nic_tpu.models import glm_moe
+    return glm_moe.GlmMoeConfig(
+        vocab=config["vocab_size"], dim=config["hidden_size"],
+        n_layers=config["num_hidden_layers"],
+        n_dense_layers=config["first_k_dense_replace"],
+        n_heads=config["num_attention_heads"],
+        q_lora_rank=config["q_lora_rank"],
+        kv_lora_rank=config["kv_lora_rank"],
+        qk_nope_dim=config["qk_nope_head_dim"],
+        qk_rope_dim=config["qk_rope_head_dim"],
+        v_dim=config["v_head_dim"], ffn_dim=config["intermediate_size"],
+        moe_ffn_dim=config["moe_intermediate_size"],
+        n_routed_experts=config["router_width"], held=held_experts(config),
+        top_k=config["num_experts_per_tok"],
+        routed_scale=config["routed_scaling_factor"],
+        norm_topk=config["norm_topk_prob"],
+        shared_expert=config["n_shared_experts"] == 1,
+        rope_theta=float(config["rope_theta"]),
+        norm_eps=config["rms_norm_eps"], dtype=config["compute_dtype"],
+        attn_block=config["attn_block"], attn_impl=config["attn_impl"],
+        head_block=config.get("head_block"))
+
+
+def program(config: dict, job: dict):
+    """(init(key) -> params, loss_fn(params, batch)) of the system under
+    test.  Across chips the loss is the token-weighted mean over `dp`."""
+    from fpga_ai_nic_tpu.models import glm_moe
+    mcfg = model_config(config)
+    dp_axis = "dp" if job["dp"] > 1 else None
+    return (lambda key: glm_moe.init(key, mcfg),
+            lambda params, batch: glm_moe.loss_fn(params, batch, mcfg,
+                                                  dp_axis=dp_axis))
+
+
+def global_batch(config: dict, job: dict) -> int:
+    return job["batch_per_chip"] * job["dp"]
+
+
+def items_per_step(config: dict, job: dict) -> int:
+    """Tokens a step trains on (the last position of a sequence has no
+    target and is counted all the same: it is computed)."""
+    return global_batch(config, job) * job["seq_len"]
+
+
+def make_batch(key, config: dict, job: dict):
+    """(tokens, labels) [B, S]: uniform ids from the slice of the
+    vocabulary held; the label of a position is the next token, -100 at a
+    sequence's last position."""
+    shape = (global_batch(config, job), job["seq_len"])
+    toks = jax.random.randint(key, shape, 0, config["vocab_size"], jnp.int32)
+    labels = jnp.concatenate(
+        [toks[:, 1:], jnp.full((shape[0], 1), -100, jnp.int32)], axis=1)
+    return toks, labels
+
+
+def attention_weights(config: dict) -> int:
+    """Weights of one latent-attention block a token is multiplied with."""
+    d, h = config["hidden_size"], config["num_attention_heads"]
+    qk = config["qk_nope_head_dim"] + config["qk_rope_head_dim"]
+    kv = config["qk_nope_head_dim"] + config["v_head_dim"]
+    return (d * config["q_lora_rank"] + config["q_lora_rank"] * h * qk
+            + d * (config["kv_lora_rank"] + config["qk_rope_head_dim"])
+            + config["kv_lora_rank"] * h * kv + h * config["v_head_dim"] * d)
+
+
+def expert_weights(config: dict) -> int:
+    """Weights of one routed (or the shared) expert: three matrices."""
+    return 3 * config["hidden_size"] * config["moe_intermediate_size"]
+
+
+def matmul_weights(config: dict) -> float:
+    """Weights a token is multiplied with, in expectation: the attention
+    projections of every layer, the dense layers' SwiGLU, per expert layer
+    the router, the shared expert and the routed experts at their
+    expectation under a uniform router (experts per token x held / router
+    width of an expert: 4 x 8 / 64 = 0.5 here), and the head over the rows
+    held.  The embedding is a gather and counts nothing."""
+    d = config["hidden_size"]
+    dense = config["first_k_dense_replace"]
+    sparse = config["num_hidden_layers"] - dense
+    routed = (config["num_experts_per_tok"] * config["n_routed_experts"]
+              / config["router_width"])
+    per_sparse = (d * config["router_width"]
+                  + (config["n_shared_experts"] + routed)
+                  * expert_weights(config))
+    return (config["num_hidden_layers"] * attention_weights(config)
+            + dense * 3 * d * config["intermediate_size"]
+            + sparse * per_sparse + d * config["vocab_size"])
+
+
+def flops_per_item(config: dict, job: dict) -> float:
+    """Forward: 2 per weight, plus per layer 2 * S * heads * (nope + rope)
+    for causal attention — scores and weighted values over HALF of the
+    square, the half a causal mask leaves (the program's XLA route computes
+    the whole square; what it computes beyond the half counts nothing).
+    Backward costs twice the forward.  Norms, softmax, sigmoid, RoPE and
+    recomputation count nothing."""
+    width = config["num_attention_heads"] * (config["qk_nope_head_dim"]
+                                             + config["qk_rope_head_dim"])
+    attention = config["num_hidden_layers"] * 2.0 * job["seq_len"] * width
+    return 3.0 * (2.0 * matmul_weights(config) + attention)
+
+
+def expert_flops(config: dict, rows: float) -> float:
+    """Operations the routed experts held need for `rows` assignments
+    (summed over the expert layers), forward and backward: 3 x 2 x the
+    expert's three matrices a row.  Rows routed, not rows padded."""
+    return 3.0 * 2.0 * expert_weights(config) * rows
+
+
+# -- the plain reference -----------------------------------------------------
+
+def _mm(a, b):
+    """a @ b; operands rounded to OPERAND_DTYPE where the control sets it."""
+    if OPERAND_DTYPE is not None:
+        a = a.astype(OPERAND_DTYPE).astype(jnp.float32)
+        b = b.astype(OPERAND_DTYPE).astype(jnp.float32)
+    return a @ b
+
+
+def _rmsnorm(x, w, eps):
+    return x / jnp.sqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * w
+
+
+def _rope(x, theta):
+    """x [..., S, r]: rotate-half over the last axis, position = index on
+    the axis before it."""
+    s, half = x.shape[-2], x.shape[-1] // 2
+    inv = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    ang = jnp.arange(s, dtype=jnp.float32)[:, None] * inv[None, :]
+    a, b = x[..., :half], x[..., half:]
+    return jnp.concatenate([a * jnp.cos(ang) - b * jnp.sin(ang),
+                            a * jnp.sin(ang) + b * jnp.cos(ang)], axis=-1)
+
+
+def _swiglu(h, w1, w3, w2):
+    return _mm(jax.nn.silu(_mm(h, w1)) * _mm(h, w3), w2)
+
+
+def _attention(lyr, x, config):
+    b, s, _ = x.shape
+    heads, eps = config["num_attention_heads"], config["rms_norm_eps"]
+    dn, dr = config["qk_nope_head_dim"], config["qk_rope_head_dim"]
+    dv, rank = config["v_head_dim"], config["kv_lora_rank"]
+    theta = float(config["rope_theta"])
+    h = _rmsnorm(x, lyr["attn_norm"], eps)
+    c_q = _rmsnorm(_mm(h, lyr["wq_a"]), lyr["q_norm"], eps)
+    q = _mm(c_q, lyr["wq_b"]).reshape(b, s, heads, dn + dr)
+    q = q.transpose(0, 2, 1, 3)
+    kv_a = _mm(h, lyr["wkv_a"])
+    c_kv = _rmsnorm(kv_a[..., :rank], lyr["kv_norm"], eps)
+    k_r = _rope(kv_a[..., rank:], theta)[:, None]           # [b, 1, s, dr]
+    kv = _mm(c_kv, lyr["wkv_b"]).reshape(b, s, heads, dn + dv)
+    kv = kv.transpose(0, 2, 1, 3)
+    q_r = _rope(q[..., dn:], theta)
+    scores = (_mm(q[..., :dn], kv[..., :dn].transpose(0, 1, 3, 2))
+              + _mm(q_r, k_r.transpose(0, 1, 3, 2))) / jnp.sqrt(
+                  jnp.float32(dn + dr))
+    causal = jnp.arange(s)[:, None] >= jnp.arange(s)[None, :]
+    scores = jnp.where(causal, scores, -jnp.inf)
+    probs = jax.nn.softmax(scores, axis=-1)
+    o = _mm(probs, kv[..., dn:]).transpose(0, 2, 1, 3)
+    return _mm(o.reshape(b, s, heads * dv), lyr["wo"])
+
+
+def _selected(scores, k):
+    """[T, E] bool: the k largest scores of each row."""
+    kth = jnp.sort(scores, axis=-1)[:, -k][:, None]
+    return scores >= kth
+
+
+def _expert_layer(lyr, x, config, held, with_selection=False):
+    x = x + _attention(lyr, x, config)
+    b, s, d = x.shape
+    h = _rmsnorm(x, lyr["mlp_norm"], config["rms_norm_eps"]).reshape(-1, d)
+    scores = jax.nn.sigmoid(h @ lyr["wr"])      # never rounded: float32
+    chosen = _selected(scores, config["num_experts_per_tok"])
+    gates = jnp.where(chosen, scores, 0.0)
+    gates = (config["routed_scaling_factor"] * gates
+             / jnp.sum(gates, axis=-1, keepdims=True))
+    y = jnp.zeros_like(h)
+    for slot, expert in enumerate(held):        # the experts held, each on
+        y = y + gates[:, expert, None] * _swiglu(        # every token
+            h, lyr["w1"][slot], lyr["w3"][slot], lyr["w2"][slot])
+    if config["n_shared_experts"]:
+        y = y + _swiglu(h, lyr["sw1"], lyr["sw3"], lyr["sw2"])
+    x = x + y.reshape(b, s, d)
+    return (x, chosen) if with_selection else x
+
+
+def _hidden(params, tokens, config, with_selection=False):
+    held = held_experts(config)
+    x = params["tok_emb"][tokens]
+    for lyr in params["dense"]:
+        def dense(lyr, x):
+            x = x + _attention(lyr, x, config)
+            return x + _swiglu(
+                _rmsnorm(x, lyr["mlp_norm"], config["rms_norm_eps"]),
+                lyr["w1"], lyr["w3"], lyr["w2"])
+        x = jax.checkpoint(dense)(lyr, x)
+    chosen = None
+    if "moe" in params:
+        def body(x, lyr):
+            out = _expert_layer(lyr, x, config, held, with_selection)
+            return out if with_selection else (out, None)
+        # the equal layers as one scanned body; checkpoint changes no
+        # arithmetic, it keeps one layer's activations for the backward
+        x, chosen = jax.lax.scan(jax.checkpoint(body), x, params["moe"])
+    return (x, chosen) if with_selection else x
+
+
+def reference_nll(params, batch, config: dict):
+    """(summed next-token negative log-likelihood, positions with a
+    target) of a block of sequences, plain float32 jax.numpy, scores
+    materialised, every held expert computed on every token."""
+    tokens, labels = batch
+    x = _hidden(params, tokens, config)
+    logits = _mm(_rmsnorm(x, params["final_norm"], config["rms_norm_eps"]),
+                 params["lm_head"])
+    logz = jax.nn.log_softmax(logits, axis=-1)
+    valid = labels >= 0
+    nll = -jnp.take_along_axis(logz, jnp.where(valid, labels, 0)[..., None],
+                               axis=-1)[..., 0]
+    return jnp.sum(jnp.where(valid, nll, 0.0)), jnp.sum(valid)
+
+
+# -- what the per-layer readers share ----------------------------------------
+
+def routing(run) -> dict:
+    """The program's `routing_stats` on the run's resident batch and the
+    seed's weights, as numpy (made once a run and kept on `run`): `rows`
+    [L, H], `held_share`, `max_over_mean`, `dropped` [L].  The harness hands
+    a reader no trained state (run.py drops it before the readers run), so
+    the weights are the seed's, not the window's last; at 1e-4 a step the
+    router has hardly moved.  Also logs what share of the selections differ
+    from the float32 reference's on the same weights and batch."""
+    if getattr(run, "glm_routing", None) is not None:
+        return run.glm_routing
+    import numpy as np
+
+    from fpga_ai_nic_tpu.models import glm_moe
+    mcfg = model_config(run.config)
+    params = jax.jit(lambda k: glm_moe.init(k, mcfg))(
+        jax.random.PRNGKey(run.trainer.cfg.seed))
+    stats = jax.device_get(jax.jit(
+        lambda p, b: glm_moe.routing_stats(p, b, mcfg))(params, run.batch))
+
+    def reference_selection(p, tokens):
+        p = jax.tree_util.tree_map(lambda a: a.astype(jnp.float32), p)
+        with jax.default_matmul_precision("highest"):
+            return _hidden(p, tokens, run.config, with_selection=True)[1]
+
+    block = run.job.get("reference_block", 1)
+    tokens = run.batch[0]
+    chosen = np.concatenate([
+        np.asarray(jax.jit(reference_selection)(params, tokens[i:i + block]))
+        for i in range(0, tokens.shape[0], block)], axis=1)   # [L, T, E]
+    mine = np.zeros_like(chosen)
+    layers, rows = np.indices(stats["selected"].shape[:2])
+    for j in range(stats["selected"].shape[2]):
+        mine[layers, rows, stats["selected"][:, :, j]] = True
+    differ = float(np.mean(np.any(mine != chosen, axis=-1)))
+    print(f"[bench] routing on the seed's weights: rows per held expert "
+          f"{stats['rows'].tolist()}; held share "
+          f"{[round(float(v), 4) for v in stats['held_share']]}; max over "
+          f"mean {[round(float(v), 3) for v in stats['max_over_mean']]}; "
+          f"dropped {stats['dropped'].tolist()}; tokens whose selection "
+          f"differs from the float32 reference's: {differ:.4%}", flush=True)
+    run.glm_routing = dict(stats, selection_differs=differ)
+    return run.glm_routing
