@@ -36,10 +36,30 @@ import jax.numpy as jnp
 ITEM = "tokens"
 THROUGHPUT = "tokens_per_s_per_chip"
 
-# First step against the float32 reference below; PERF.md (Findings, PR 29)
-# has the readings both limits are set from.
+# First step against the float32 reference below (the readings: PERF.md,
+# Findings, PR 29).
+# Loss: a next-token loss of 10.37 over 19,360 classes from bf16 logits;
+# 7e-6 to 1.3e-4 on the chip over twenty-three seeds.  The precision hardly
+# moves it (float8 operands in the reference: 4.4e-4), so it has the limit of
+# the harness's accepted transformer cells — and NO UPPER READING here:
+# neither control reaches it (the planted fault below reads 1.1e-3), so in
+# this cell the gradient's limit alone decides.
 LOSS_RTOL = 1e-2
-GRAD_TOL = 5e-2
+# Gradient, relative L2 over the flat vector: 6.7e-2 to 8.0e-2 on the chip
+# over twenty-three seeds.  Two parts: bf16 matmuls and a bf16 residual
+# stream through five layers at 4,096 positions (2.1e-2 with every expert
+# selected, so that no selection can flip), and the selections themselves:
+# the top 4 of 64 sigmoid scores are decided by gaps of ~0.1 in the logit,
+# the bf16 residual moves a logit by ~0.005, and 5.7% of the tokens pick
+# another fourth expert in some layer than the float32 reference does; such
+# a token's whole backward signal differs, which every earlier layer's
+# gradient sees (the error is 7.5e-2 on every leaf of the first layer and
+# 4e-2 on the last's).  2.5 times the worst reading is allowed.  Two upper
+# readings, both through the harness at the cell's size: the reference with
+# float8 (e4m3) matmul operands reads 0.94, and a planted fault, the
+# program's loss over one of the step's two sequences only, reads 1.00; both
+# miss the limit by a factor of 4.7 to 5.
+GRAD_TOL = 2e-1
 
 # For the control that must read `correct: false` (PERF.md): the type the
 # reference's matmul operands are rounded to.  None: float32, the reference.
@@ -77,8 +97,7 @@ def model_config(config: dict):
         shared_expert=config["n_shared_experts"] == 1,
         rope_theta=float(config["rope_theta"]),
         norm_eps=config["rms_norm_eps"], dtype=config["compute_dtype"],
-        attn_block=config["attn_block"], attn_impl=config["attn_impl"],
-        head_block=config.get("head_block"))
+        attn_block=config["attn_block"], attn_impl=config["attn_impl"])
 
 
 def program(config: dict, job: dict):
@@ -229,10 +248,9 @@ def _selected(scores, k):
     return scores >= kth
 
 
-def _expert_layer(lyr, x, config, held, with_selection=False):
-    x = x + _attention(lyr, x, config)
-    b, s, d = x.shape
-    h = _rmsnorm(x, lyr["mlp_norm"], config["rms_norm_eps"]).reshape(-1, d)
+def _expert_ffn(lyr, h, config, held):
+    """h [T, D] -> (sum over the selected experts held of g_i E_i(h) +
+    E_shared(h), the selection [T, router width])."""
     scores = jax.nn.sigmoid(h @ lyr["wr"])      # never rounded: float32
     chosen = _selected(scores, config["num_experts_per_tok"])
     gates = jnp.where(chosen, scores, 0.0)
@@ -244,6 +262,14 @@ def _expert_layer(lyr, x, config, held, with_selection=False):
             h, lyr["w1"][slot], lyr["w3"][slot], lyr["w2"][slot])
     if config["n_shared_experts"]:
         y = y + _swiglu(h, lyr["sw1"], lyr["sw3"], lyr["sw2"])
+    return y, chosen
+
+
+def _expert_layer(lyr, x, config, held, with_selection=False):
+    x = x + _attention(lyr, x, config)
+    b, s, d = x.shape
+    h = _rmsnorm(x, lyr["mlp_norm"], config["rms_norm_eps"]).reshape(-1, d)
+    y, chosen = _expert_ffn(lyr, h, config, held)
     x = x + y.reshape(b, s, d)
     return (x, chosen) if with_selection else x
 
@@ -291,9 +317,10 @@ def routing(run) -> dict:
     seed's weights, as numpy (made once a run and kept on `run`): `rows`
     [L, H], `held_share`, `max_over_mean`, `dropped` [L].  The harness hands
     a reader no trained state (run.py drops it before the readers run), so
-    the weights are the seed's, not the window's last; at 1e-4 a step the
-    router has hardly moved.  Also logs what share of the selections differ
-    from the float32 reference's on the same weights and batch."""
+    the weights are the seed's, not the window's last, whose routing is not
+    the same (PERF.md, Findings, PR 29: read once, by hand).  Also logs what
+    share of the selections differ from the float32 reference's on the same
+    weights and batch."""
     if getattr(run, "glm_routing", None) is not None:
         return run.glm_routing
     import numpy as np

@@ -4,7 +4,9 @@ the rows ROUTED to the experts held need, forward and backward
 over the expert layers from the program's `routing_stats`), over the peak,
 over the time of class `moe`.  It counts rows routed, not rows padded, so a
 grouped product that computes its padded bound reads low and none can read
-above 100."""
+above 100.  The rows are those the SEED's weights route (the family's
+`routing`), the time is the trained window's: the harness keeps no trained
+state for a reader (PERF.md section 7)."""
 
 
 def read(run):

@@ -9,7 +9,8 @@ the HLO instruction, and ``metadata=`` rides into the instruction's
 keeps in the event's name.  The benchmark's rules
 (``benchmark/op_classes/05-named-kernels.json``) and PERF.md section 3 agree
 with this table and with nothing else.  Names are compile-time only: nothing
-is added to a step.
+is added to a step.  `EXTERNAL_KERNELS` lists the kernels the compiler makes
+itself, under the names it gives them.
 """
 
 from typing import Dict, Tuple
@@ -50,6 +51,20 @@ KERNELS: Dict[str, Tuple[str, str]] = {
         "attention", "paged decode attention (ops/paged_attend_pallas.py)"),
 }
 
+# Mosaic kernels the TPU compiler makes of an XLA operation by itself.  No
+# call site of ours can hand them a name or metadata, so the table holds the
+# instruction names the compiler gives them ("%<name>.N = ... custom-call"):
+# name -> (layer, what it is).  tests/test_tpu_compile.py compiles the expert
+# layer for the chip and refuses a custom call that is neither in KERNELS
+# nor here; benchmark/op_classes/08-glm-moe.json classes them by
+# `external_kernel_regex()`, which tests/test_kernel_names.py holds it to.
+EXTERNAL_KERNELS: Dict[str, Tuple[str, str]] = {
+    "ragged-dot-none": (
+        "moe", "lax.ragged_dot's grouped product (ops/moe._grouped_dot)"),
+    "ragged-dot-metadata": (
+        "moe", "the grouped product's tiles, from the rows of each group"),
+}
+
 # the phases of DPTrainer's step, as jax.named_scope: metadata on the
 # instructions (op_name), the same program
 SCOPES: Dict[str, str] = {
@@ -58,7 +73,19 @@ SCOPES: Dict[str, str] = {
     "ainic.collective_update": "reduce-scatter, with the update where fused",
     "ainic.optimizer": "the optimizer's formula where XLA runs it",
     "ainic.gather": "owned shard -> replicated parameters",
+    # inside ainic.fwd_bwd, where the model has them (models/glm_moe.py)
+    "ainic.mla": "latent attention: compressed q and k/v, rotary key, heads",
+    "ainic.moe.route": "sigmoid scores over every expert, top-k, gates",
+    "ainic.moe.experts": "dropless grouped product over the experts held",
+    "ainic.moe.shared": "the shared expert, on every token",
 }
+
+
+def external_kernel_regex(layer: str) -> str:
+    """The pattern that matches, at its start, the instruction text of the
+    compiler's own kernels of `layer`."""
+    own = sorted(n for n, (l, _) in EXTERNAL_KERNELS.items() if l == layer)
+    return r"^%(?:" + "|".join(own) + r")[.\d]* = "
 
 
 def kernel(name: str, **extra: object) -> dict:

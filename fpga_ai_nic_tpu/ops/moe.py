@@ -21,6 +21,19 @@ Design (GShard/Switch-style, static shapes for XLA):
   O(T*k*D) memory;
 - load-balance aux loss computed over the *global* batch (psum over the
   batch axes) so sharded and unsharded training see the same regularizer.
+
+Beside the capacity path, and sharing nothing with it, the DROPLESS path of
+the sigmoid-routed expert models (`sigmoid_route`, `dropless_experts`,
+`held_experts_ffn`): scores are a sigmoid over every expert of the layer,
+the top-k of score + bias are selected, the gates are the selected scores
+normalised over the selection and scaled; assignments are sorted by expert
+and multiplied with `lax.ragged_dot`, whose cost follows the rows routed, so
+no expert has a capacity and no assignment is dropped.  `held` names the
+experts this chip holds of an expert-parallel layer: the router still scores
+all of them and the gates are normalised over all that were selected, the
+chip computes the part of the result its own experts give, and what the
+absent experts would add is left out — there is no stand-in for the absent
+chips or for their exchange.
 """
 
 from __future__ import annotations
@@ -31,8 +44,11 @@ from typing import Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
+
+from ..obs.names import scope
 
 
 @dataclass(frozen=True)
@@ -217,3 +233,191 @@ def moe_ffn(params: Dict, x: jax.Array, cfg: MoEConfig, *,
     if with_stats:
         return y, aux, _stats_from_routing(onehot, keep, C, batch_axes)
     return y, aux
+
+
+# -- dropless, sigmoid-routed, some experts held -----------------------------
+
+
+def sigmoid_route(wr: jax.Array, xf: jax.Array, *, top_k: int,
+                  bias: Optional[jax.Array] = None, scale: float = 1.0,
+                  norm_topk: bool = True) -> Tuple[jax.Array, jax.Array]:
+    """xf [T, D], wr [D, E] -> (gates [T, k] float32, experts [T, k] int32).
+
+    Scores are sigmoid(xf @ wr) in float32, the matmul at full precision
+    (a near tie between the k-th and the next score decides an expert).
+    The k largest of score + `bias` [E] are selected (the bias steers the
+    selection only: the aux-loss-free balancing of `noaux_tc`); the gates are
+    the selected SCORES, divided by their sum where `norm_topk`, times
+    `scale`.  The gradient reaches `wr` through the gates."""
+    with scope("ainic.moe.route"):
+        scores = jax.nn.sigmoid(jnp.dot(
+            xf.astype(jnp.float32), wr.astype(jnp.float32),
+            precision=lax.Precision.HIGHEST))
+        _, experts = lax.top_k(scores if bias is None else scores + bias,
+                               top_k)
+        gates = jnp.take_along_axis(scores, experts, axis=-1)
+        if norm_topk:
+            gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+        return gates * scale, experts
+
+
+def _zero_cotangent(ints: jax.Array):
+    return np.zeros(ints.shape, jax.dtypes.float0)
+
+
+@jax.custom_vjp
+def _rows_to_experts(xf, order, inv):
+    """xf [T, D] -> [T*k, D]: the row of assignment `order[p]` (token
+    `order[p] // k`) at sorted position p.  The transpose is a gather by
+    the inverse permutation and a sum over a token's k assignments, where
+    autodiff would scatter-add T*k rows."""
+    return xf[order // (order.shape[0] // xf.shape[0])]
+
+
+def _rows_to_experts_fwd(xf, order, inv):
+    return _rows_to_experts(xf, order, inv), (order, inv, xf.shape[0])
+
+
+def _rows_to_experts_bwd(res, g):
+    order, inv, tokens = res
+    return (g[inv].reshape(tokens, -1, g.shape[-1]).sum(axis=1),
+            _zero_cotangent(order), _zero_cotangent(inv))
+
+
+_rows_to_experts.defvjp(_rows_to_experts_fwd, _rows_to_experts_bwd)
+
+
+@jax.custom_vjp
+def _rows_to_tokens(ys, order, inv):
+    """ys [T*k, D] in sorted order -> the same rows in assignment order
+    (token-major); the transpose is the gather by `order`."""
+    return ys[inv]
+
+
+def _rows_to_tokens_fwd(ys, order, inv):
+    return ys[inv], (order, inv)
+
+
+def _rows_to_tokens_bwd(res, g):
+    order, inv = res
+    return g[order], _zero_cotangent(order), _zero_cotangent(inv)
+
+
+_rows_to_tokens.defvjp(_rows_to_tokens_fwd, _rows_to_tokens_bwd)
+
+
+def _grouped_dot(x: jax.Array, w: jax.Array, sizes: jax.Array,
+                 live: jax.Array) -> jax.Array:
+    """Rows of x [A, K], sorted by group, times their group's w [G, K, N].
+    Rows past sum(sizes) belong to no group held here: `lax.ragged_dot`
+    leaves them undefined, so they are zeroed going in and coming out —
+    which also zeroes both cotangents of such a row."""
+    x = jnp.where(live, x, jnp.zeros((), x.dtype))
+    y = lax.ragged_dot(x, w, sizes)
+    return jnp.where(live, y, jnp.zeros((), y.dtype))
+
+
+def _held_lookup(num_experts: int, held: Optional[Sequence[int]]):
+    """(local index of every expert, H where it is absent; H)."""
+    held = tuple(range(num_experts)) if held is None else tuple(held)
+    if len(set(held)) != len(held) or not all(
+            0 <= e < num_experts for e in held):
+        raise ValueError(f"held experts {held} are not distinct ids below "
+                         f"{num_experts}")
+    lookup = np.full((num_experts,), len(held), np.int32)
+    lookup[list(held)] = np.arange(len(held), dtype=np.int32)
+    return lookup, len(held)
+
+
+def dispatch_plan(experts: jax.Array, num_experts: int,
+                  held: Optional[Sequence[int]] = None) -> Dict[str, jax.Array]:
+    """Where every assignment of `experts` [T, k] goes: `order` [T*k] (the
+    assignments sorted by the local index of their expert, absent experts
+    last; stable, so token-major inside an expert), its inverse `inv`,
+    `sizes` [H] rows per expert held, `live` [T*k, 1] (sorted positions
+    that hold a row of a held expert)."""
+    lookup, n_held = _held_lookup(num_experts, held)
+    local = jnp.asarray(lookup)[experts.reshape(-1)]            # [T*k]
+    order = jnp.argsort(local, stable=True).astype(jnp.int32)
+    inv = jnp.zeros_like(order).at[order].set(
+        jnp.arange(order.shape[0], dtype=jnp.int32))
+    sizes = jnp.sum(local[:, None] == jnp.arange(n_held)[None, :],
+                    axis=0, dtype=jnp.int32)
+    live = (jnp.arange(order.shape[0]) < jnp.sum(sizes))[:, None]
+    return {"order": order, "inv": inv, "sizes": sizes, "live": live,
+            "local": local}
+
+
+def dropless_experts(params: Dict, xf: jax.Array, gates: jax.Array,
+                     plan: Dict[str, jax.Array]) -> jax.Array:
+    """sum over the selected experts HELD HERE of gate * SwiGLU_e(x), for
+    xf [T, D] and gates [T, k]: w1, w3 [H, D, F] and w2 [H, F, D] are the
+    held experts' weights in the order of `held`, `plan` the
+    `dispatch_plan` of the selection.  Every assignment to a held expert is
+    computed, whatever the routing: the rows are sorted by expert and one
+    grouped product a matrix runs over as many rows as were routed."""
+    T, D = xf.shape
+    with scope("ainic.moe.experts"):
+        order, inv = plan["order"], plan["inv"]
+        sizes, live = plan["sizes"], plan["live"]
+        xs = _rows_to_experts(xf, order, inv)                   # [T*k, D]
+        g = _grouped_dot(xs, params["w1"], sizes, live)
+        u = _grouped_dot(xs, params["w3"], sizes, live)
+        h = jax.nn.silu(g.astype(jnp.float32)).astype(xs.dtype) * u
+        ys = _rows_to_tokens(_grouped_dot(h, params["w2"], sizes, live),
+                             order, inv).reshape(T, -1, D)
+        return jnp.sum(ys.astype(jnp.float32) * gates[..., None],
+                       axis=1).astype(xf.dtype)
+
+
+def swiglu(x: jax.Array, w1: jax.Array, w3: jax.Array,
+           w2: jax.Array) -> jax.Array:
+    """(silu(x w1) * (x w3)) w2, the gate's silu in float32."""
+    g = jax.nn.silu((x @ w1).astype(jnp.float32)).astype(x.dtype)
+    return (g * (x @ w3)) @ w2
+
+
+def shared_expert(params: Dict, xf: jax.Array) -> jax.Array:
+    """The SwiGLU every token passes through, beside the routed ones:
+    sw1, sw3 [D, F], sw2 [F, D]."""
+    with scope("ainic.moe.shared"):
+        return swiglu(xf, params["sw1"], params["sw3"], params["sw2"])
+
+
+def routing_counts(plan: Dict, experts: jax.Array) -> Dict[str, jax.Array]:
+    """What one layer's dropless dispatch did, from its own plan: `rows`
+    [H] per expert held, `held_share` of all assignments that landed on a
+    held expert, `max_over_mean` of the rows over the held experts, and
+    `dropped`: assignments to a held expert that have no live row in the
+    grouped product (0 by construction); `selected` is `experts` itself."""
+    n_held = plan["sizes"].shape[0]
+    to_held = jnp.sum(plan["local"] < n_held)
+    computed = jnp.sum(plan["live"][plan["inv"], 0] & (plan["local"] < n_held))
+    rows = plan["sizes"].astype(jnp.float32)
+    return {"rows": plan["sizes"], "selected": experts,
+            "held_share": to_held / jnp.float32(experts.size),
+            "max_over_mean": jnp.max(rows) / jnp.maximum(jnp.mean(rows), 1.0),
+            "dropped": to_held - computed}
+
+
+def held_experts_ffn(params: Dict, x: jax.Array, *, num_experts: int,
+                     top_k: int, held: Optional[Sequence[int]] = None,
+                     scale: float = 1.0, norm_topk: bool = True,
+                     bias: Optional[jax.Array] = None,
+                     with_counts: bool = False):
+    """The expert layer of a sigmoid-routed model on a chip that holds
+    `held` of its `num_experts` experts (None: all): x [B, S, D] ->
+    sum_{selected & held} gate_i E_i(x) + E_shared(x) (the shared expert
+    where `params` has one).  `params`: wr [D, num_experts] float32, w1/w3/w2
+    of the experts held, optionally sw1/sw3/sw2.  No auxiliary loss.  With
+    `with_counts`, also `routing_counts` of this pass."""
+    B, S, D = x.shape
+    xf = x.reshape(B * S, D)
+    gates, experts = sigmoid_route(params["wr"], xf, top_k=top_k, bias=bias,
+                                   scale=scale, norm_topk=norm_topk)
+    plan = dispatch_plan(experts, num_experts, held)
+    y = dropless_experts(params, xf, gates, plan)
+    if "sw1" in params:
+        y = y + shared_expert(params, xf)
+    y = y.reshape(B, S, D)
+    return (y, routing_counts(plan, experts)) if with_counts else y

@@ -244,7 +244,8 @@ def full_attention(q, k, v, *, causal=True, sm_scale=None):
 
 
 def flash_attention_remat(q, k, v, *, causal=True, sm_scale=None,
-                          k_block: Optional[int] = 512, impl: str = "auto"):
+                          k_block: Optional[int] = 512, impl: str = "auto",
+                          q_offset: int = 0):
     """Memory-bounded exact attention for model code — picks the best
     backward story available:
 
@@ -256,17 +257,24 @@ def flash_attention_remat(q, k, v, *, causal=True, sm_scale=None,
       ``flash_attention`` under attention-only ``jax.checkpoint`` —
       without it the scan's per-block residuals reconstitute O(S^2)
       backward memory (measured 22 GB at S=16,384; models/llama.py
-      carried this wrapper before round 5 moved the choice here)."""
+      carried this wrapper before round 5 moved the choice here).
+
+    `q_offset` is the position of q's first row among the keys: a caller
+    that takes the queries of a causal sequence in chunks hands each chunk
+    only the keys at or before its last row, and skips the rest of the
+    square."""
     from . import flash_pallas
     if pallas_route(impl, q, kv_seq_len=k.shape[2]):
         b = k_block or flash_pallas._DEF_BLOCK
         return flash_pallas.flash_attention(q, k, v, causal=causal,
                                             sm_scale=sm_scale,
+                                            q_offset=q_offset,
                                             block_q=b, block_k=b)
     return jax.checkpoint(
         lambda q2, k2, v2: flash_attention(q2, k2, v2, causal=causal,
                                            sm_scale=sm_scale,
-                                           k_block=k_block))(q, k, v)
+                                           k_block=k_block,
+                                           q_offset=q_offset))(q, k, v)
 
 
 def gathered_attention(q, k, v, axis_name: str, *, causal=True,
@@ -319,19 +327,20 @@ def gathered_attention(q, k, v, axis_name: str, *, causal=True,
 
 
 def flash_attention(q, k, v, *, causal=True, sm_scale=None,
-                    k_block: Optional[int] = 512):
+                    k_block: Optional[int] = 512, q_offset: int = 0):
     """Single-device flash-blocked exact attention: the same
     `_attend_chunk` online-softmax accumulation the ring/gathered
     variants use, with no collectives — peak score memory
     O(S * k_block) instead of full_attention's O(S^2) f32 score matrix
     (which XLA also saves for the backward, forcing remat on long
     sequences).  Bit-differences vs full_attention are f32 summation
-    order only; both are exact softmax attention."""
+    order only; both are exact softmax attention.  q may be a chunk of the
+    sequence's queries: its first row is key position `q_offset`."""
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
     B, H, S, dh = q.shape
     qf = q.astype(jnp.float32)
-    pos = lax.broadcasted_iota(jnp.int32, (S, 1), 0)[:, 0]
+    pos = q_offset + lax.broadcasted_iota(jnp.int32, (S, 1), 0)[:, 0]
     # q may be batch-sharded under an outer shard_map even though this
     # attention itself is collective-free
     vma = (set(jax.typeof(qf).vma) | set(jax.typeof(k).vma)

@@ -255,11 +255,50 @@ def lowered_step(request):
     return tr.step_fn.lower(state, batch).as_text(debug_info=True)
 
 
-@pytest.mark.parametrize("scope", sorted(names.SCOPES))
+# the scopes a model brings into ainic.fwd_bwd; the others are the step's own
+MODEL_SCOPES = sorted(s for s in names.SCOPES
+                      if s.startswith(("ainic.mla", "ainic.moe.")))
+
+
+@pytest.mark.parametrize("scope", sorted(set(names.SCOPES)
+                                         - set(MODEL_SCOPES)))
 def test_lowered_step_holds_the_scope(lowered_step, scope):
     """Metadata only: the scope stands in the instructions' locations
     (`op_name` once compiled), e.g. jit(_step)/.../ainic.fwd_bwd/..."""
     assert re.search(r"[/\"]%s/" % re.escape(scope), lowered_step)
+
+
+@pytest.fixture(scope="module")
+def lowered_glm_step():
+    """DPTrainer's step for a tiny models/glm_moe.py, lowered as text."""
+    from fpga_ai_nic_tpu.models import glm_moe
+    from fpga_ai_nic_tpu.parallel import DPTrainer, make_mesh
+
+    mcfg = glm_moe.GlmMoeConfig.tiny(held=(0, 1, 2))
+    cfg = TrainConfig(
+        global_batch=2, mesh=MeshConfig(dp=2),
+        collective=CollectiveConfig(impl="ring", compression=BFPConfig(),
+                                    fused_optimizer=True),
+        optimizer=OptimizerConfig(kind="adamw", learning_rate=1e-3))
+    tr = DPTrainer(lambda p, b: glm_moe.loss_fn(p, b, mcfg, dp_axis="dp"),
+                   make_mesh(cfg.mesh, devices=jax.devices()[:2]), cfg)
+    state = tr.init_state(glm_moe.init(jax.random.PRNGKey(0), mcfg))
+    batch = tr.shard_batch((np.zeros((2, 16), np.int32),
+                            np.zeros((2, 16), np.int32)))
+    return tr.step_fn.lower(state, batch).as_text(debug_info=True)
+
+
+def test_the_model_scopes_are_the_four_the_table_lists():
+    assert MODEL_SCOPES == ["ainic.mla", "ainic.moe.experts",
+                            "ainic.moe.route", "ainic.moe.shared"]
+
+
+@pytest.mark.parametrize("scope", MODEL_SCOPES)
+def test_lowered_glm_step_holds_the_model_scope(lowered_glm_step, scope):
+    """As the step's own: the scanned layer's body keeps its own locations
+    ("ainic.moe.route/dot_general"), inside ainic.fwd_bwd's."""
+    assert re.search(r"[/\"]%s/" % re.escape(scope), lowered_glm_step)
+    assert re.search(r"[/\"]ainic\.fwd_bwd/", lowered_glm_step)
 
 
 # -- the benchmark's rules read the names back -------------------------------
@@ -341,6 +380,45 @@ def test_named_rules_sort_first_and_give_no_new_class():
     assert [r["class"] for r in named] == ["ring", "codec", "attention"]
     layers = {layer for layer, _ in names.KERNELS.values()}
     assert {r["class"] for r in named} == layers
+
+
+# -- the compiler's own kernels: the table owns their names too --------------
+
+def _rules_of(cls):
+    rules = []
+    d = os.path.join(ROOT, "benchmark", "op_classes")
+    for f in sorted(os.listdir(d)):
+        with open(os.path.join(d, f)) as fh:
+            rules += [r["regex"] for r in json.load(fh)["rules"]
+                      if r["class"] == cls]
+    return rules
+
+
+@pytest.mark.parametrize("layer", sorted(
+    {layer for layer, _ in names.EXTERNAL_KERNELS.values()}))
+def test_a_rule_reads_the_external_kernels_from_the_table(layer):
+    """The class of a kernel the compiler makes is told by the table's
+    pattern, letter for letter: a name changed here or there fails."""
+    assert names.external_kernel_regex(layer) in _rules_of(layer)
+
+
+@pytest.mark.parametrize("name", sorted(names.EXTERNAL_KERNELS))
+def test_external_kernel_events_take_the_table_s_layer(name):
+    layer, _ = names.EXTERNAL_KERNELS[name]
+    assert name not in names.KERNELS
+    rules = trace_reduce.load_rules()
+    for instr in (name, name + ".12"):
+        event = _event(instr, "bf16[32768,1536]{1,0:T(8,128)(2,1)}",
+                       "s32[1]{0:T(128)} %gte.5")
+        assert trace_reduce.classify(event, rules) == layer
+        assert kernel_events.kernel_name(event) is None
+
+
+def test_a_kernel_the_compiler_names_otherwise_is_unknown():
+    event = _event("ragged-dot-renamed.1", "bf16[32768,1536]{1,0}",
+                   "s32[1]{0:T(128)} %gte.5")
+    assert trace_reduce.classify(
+        event, trace_reduce.load_rules()) == "pallas_unknown"
 
 
 def test_recorded_trace_of_pr23_reads_as_before_with_both_rule_files():

@@ -364,3 +364,152 @@ def test_capacity_binding_sharded_divergence_bounded(rng):
                                np.abs(sh[differs]).max(axis=1))
         assert (gap <= row_bound * (1 + 1e-5) + 1e-6).all(), (
             gap, row_bound)
+
+
+# -- the dropless, sigmoid-routed path (beside the capacity path above) ------
+
+def _sigmoid_params(rng, n=8, held=None, shared=False, d=D, f=F):
+    h = n if held is None else len(held)
+    p = {"wr": rng.standard_normal((d, n)).astype(np.float32) * d ** -0.5,
+         "w1": rng.standard_normal((h, d, f)).astype(np.float32) * d ** -0.5,
+         "w3": rng.standard_normal((h, d, f)).astype(np.float32) * d ** -0.5,
+         "w2": rng.standard_normal((h, f, d)).astype(np.float32) * f ** -0.5}
+    if shared:
+        p.update(sw1=rng.standard_normal((d, f)).astype(np.float32) * .3,
+                 sw3=rng.standard_normal((d, f)).astype(np.float32) * .3,
+                 sw2=rng.standard_normal((f, d)).astype(np.float32) * .3)
+    return {k: jnp.asarray(v) for k, v in p.items()}
+
+
+def _silu(a):
+    return a / (1.0 + np.exp(-a))
+
+
+def _ref_sigmoid_moe(params, x, top_k, held, scale, bias=None):
+    """Per-token numpy reference: sigmoid scores, top-k of score + bias,
+    gates normalised over the selection, only held experts computed."""
+    p = {k: np.asarray(v, np.float64) for k, v in params.items()}
+    xf = np.asarray(x, np.float64).reshape(-1, x.shape[-1])
+    n = p["wr"].shape[1]
+    held = list(range(n)) if held is None else list(held)
+    scores = 1.0 / (1.0 + np.exp(-(xf @ p["wr"])))
+    y = np.zeros_like(xf)
+    for t in range(xf.shape[0]):
+        choice = scores[t] + (0.0 if bias is None else np.asarray(bias))
+        top = np.argsort(-choice, kind="stable")[:top_k]
+        g = scale * scores[t, top] / scores[t, top].sum()
+        for gi, e in zip(g, top):
+            if e in held:
+                s = held.index(e)
+                y[t] += gi * (_silu(xf[t] @ p["w1"][s])
+                              * (xf[t] @ p["w3"][s])) @ p["w2"][s]
+        if "sw1" in p:
+            y[t] += (_silu(xf[t] @ p["sw1"]) * (xf[t] @ p["sw3"])) @ p["sw2"]
+    return y.reshape(x.shape)
+
+
+@pytest.mark.parametrize("held,shared", [(None, False), ((2, 3), True),
+                                         ((7, 0, 4), False)],
+                         ids=["all", "share-2-3", "unordered-share"])
+def test_dropless_matches_per_token_reference(rng, held, shared):
+    params = _sigmoid_params(rng, held=held, shared=shared)
+    x = jnp.asarray(rng.standard_normal((2, 12, D)), jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        y, counts = moe.held_experts_ffn(
+            params, x, num_experts=8, top_k=3, held=held, scale=1.8,
+            with_counts=True)
+    np.testing.assert_allclose(
+        np.asarray(y), _ref_sigmoid_moe(params, x, 3, held, 1.8),
+        rtol=2e-4, atol=2e-5)
+    assert int(counts["dropped"]) == 0
+    assert counts["rows"].shape == (8 if held is None else len(held),)
+    if held is None:
+        assert int(counts["rows"].sum()) == 2 * 12 * 3
+        assert float(counts["held_share"]) == 1.0
+
+
+def test_sigmoid_route_bias_steers_selection_not_gates(rng):
+    params = _sigmoid_params(rng)
+    xf = jnp.asarray(rng.standard_normal((16, D)), jnp.float32)
+    bias = jnp.zeros((8,)).at[5].set(10.0)      # expert 5 always selected
+    gates, experts = moe.sigmoid_route(params["wr"], xf, top_k=2, bias=bias,
+                                       scale=2.5)
+    assert bool(jnp.all(jnp.any(experts == 5, axis=-1)))
+    np.testing.assert_allclose(np.asarray(gates.sum(-1)), 2.5, rtol=1e-6)
+    scores = jax.nn.sigmoid(xf @ params["wr"])
+    picked = jnp.take_along_axis(scores, experts, axis=-1)
+    np.testing.assert_allclose(
+        np.asarray(gates), np.asarray(2.5 * picked / picked.sum(-1,
+                                                                keepdims=True)),
+        rtol=1e-5)
+    raw, _ = moe.sigmoid_route(params["wr"], xf, top_k=2, norm_topk=False)
+    assert float(jnp.max(raw)) < 1.0            # scores, not normalised
+
+
+def test_dropless_keeps_every_token_under_a_skewed_router(rng):
+    """Every token picks the same held expert: the capacity path drops what
+    overflows, the dropless path computes all of it."""
+    held = (2, 3)
+    params = _sigmoid_params(rng, held=held)
+    x = jnp.abs(jnp.asarray(rng.standard_normal((1, 40, D)), jnp.float32))
+    wr = np.full((D, 8), -1.0, np.float32)
+    wr[:, 3] = 1.0                              # all-positive x: 3 wins
+    wr[:, 6] = 0.5                              # then 6, which is absent
+    params["wr"] = jnp.asarray(wr)
+    with jax.default_matmul_precision("highest"):
+        y, counts = moe.held_experts_ffn(
+            params, x, num_experts=8, top_k=2, held=held, with_counts=True)
+    assert np.asarray(counts["rows"]).tolist() == [0, 40]
+    assert int(counts["dropped"]) == 0
+    assert float(counts["held_share"]) == 0.5
+    assert float(counts["max_over_mean"]) == 2.0
+    np.testing.assert_allclose(
+        np.asarray(y), _ref_sigmoid_moe(params, x, 2, held, 1.0),
+        rtol=2e-4, atol=2e-5)
+    assert float(jnp.min(jnp.sum(jnp.abs(y), axis=-1))) > 0   # none dropped
+    # the capacity path on the same skew does drop
+    cap = moe.MoEConfig(num_experts=E, top_k=1, capacity_factor=1.0)
+    cp = _params(rng)
+    cp["wr"] = jnp.asarray(np.where(np.arange(E) == 1, 1.0, -1.0)[None]
+                           * np.ones((D, 1)), jnp.float32)
+    stats = moe.expert_stats(cp, x, cap)
+    assert float(stats["drop_frac"]) > 0.5
+
+
+def test_dropless_gradient_matches_plain_autodiff(rng):
+    """The permutations' hand-written transposes (gathers by the inverse)
+    against the same layer written with one dense product per expert."""
+    held = (1, 4, 6)
+    params = _sigmoid_params(rng, held=held, shared=True)
+    x = jnp.asarray(rng.standard_normal((2, 10, D)), jnp.float32)
+
+    def dense(params, x):
+        xf = x.reshape(-1, D)
+        gates, experts = moe.sigmoid_route(params["wr"], xf, top_k=3,
+                                           scale=1.8)
+        y = moe.shared_expert(params, xf)
+        for slot, e in enumerate(held):
+            g = jnp.sum(jnp.where(experts == e, gates, 0.0), axis=-1)
+            y = y + g[:, None] * (
+                (jax.nn.silu(xf @ params["w1"][slot])
+                 * (xf @ params["w3"][slot])) @ params["w2"][slot])
+        return jnp.sum(jnp.sin(y))
+
+    def dropless(params, x):
+        return jnp.sum(jnp.sin(moe.held_experts_ffn(
+            params, x, num_experts=8, top_k=3, held=held, scale=1.8)))
+
+    with jax.default_matmul_precision("highest"):
+        want = jax.grad(dense, argnums=(0, 1))(params, x)
+        got = jax.jit(jax.grad(dropless, argnums=(0, 1)))(params, x)
+    for g, w in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=2e-4, atol=2e-5)
+
+
+def test_held_experts_must_be_distinct_ids_of_the_layer():
+    with pytest.raises(ValueError, match="held experts"):
+        moe.dispatch_plan(jnp.zeros((4, 2), jnp.int32), 8, held=(1, 1))
+    with pytest.raises(ValueError, match="held experts"):
+        moe.dispatch_plan(jnp.zeros((4, 2), jnp.int32), 8, held=(8,))

@@ -343,6 +343,48 @@ def test_gather_slot_window_past_the_budget_is_refused_by_name():
         opstream.ag_n_slots(4, 256)
 
 
+# -- the dropless expert layer at GLM-4.7-Flash's widths ----------------------
+
+def test_dropless_expert_layer_compiles_to_grouped_kernels(chip):
+    """`ops.moe.held_experts_ffn`, forward and backward, at the benchmark
+    cell's sizes: 8,192 tokens, top-4 of 64 sigmoid-routed experts, the 8
+    held here of width 2048 x 1536, a shared expert.  On the v5e
+    `lax.ragged_dot` becomes the compiler's own grouped-matmul kernels
+    (`ragged-dot-*` custom calls): nine products (three forward, six
+    backward) over 32,768 sorted rows, no [experts, capacity, width]
+    buffer, and no scatter of 32,768 rows back onto the tokens."""
+    from fpga_ai_nic_tpu.ops import moe
+    T, D, F, H, E = 8192, 2048, 1536, 8, 64
+    bf = jnp.bfloat16
+    params = {"wr": sds((D, E), jnp.float32, chip.one),
+              "w1": sds((H, D, F), bf, chip.one),
+              "w3": sds((H, D, F), bf, chip.one),
+              "w2": sds((H, F, D), bf, chip.one),
+              "sw1": sds((D, F), bf, chip.one),
+              "sw3": sds((D, F), bf, chip.one),
+              "sw2": sds((F, D), bf, chip.one)}
+
+    def grads(params, x):
+        return jax.grad(lambda p, y: moe.held_experts_ffn(
+            p, y, num_experts=E, top_k=4, held=tuple(range(H)), scale=1.8
+        ).astype(jnp.float32).sum(), argnums=(0, 1))(params, x)
+
+    text = compiled_text(grads, params, sds((2, T // 2, D), bf, chip.one))
+    products = re.findall(r"^\s*(?:ROOT )?%ragged-dot-none[.\d]* = (\w+\[[\d,]+\])",
+                          text, re.M)
+    assert sorted(products) == sorted(
+        ["bf16[32768,1536]"] * 3 + ["bf16[32768,2048]"] * 3
+        + ["bf16[8,2048,1536]"] * 2 + ["bf16[8,1536,2048]"])
+    assert not re.search(r"scatter\(\w+\[32768,2048\]", text)
+    # every Mosaic kernel in it is the compiler's own, under a name the
+    # table lists: the benchmark's rule reads the class from there
+    from fpga_ai_nic_tpu.obs import names
+    made = set(re.findall(
+        r"^\s*(?:ROOT )?%([\w-]+?)[.\d]* = [^\n]*tpu_custom_call", text,
+        re.M))
+    assert made == set(names.EXTERNAL_KERNELS)
+
+
 # -- the whole step (about a minute each: not tier-1) ------------------------
 
 @pytest.mark.slow
