@@ -19,7 +19,7 @@ Layer equations, for a token's residual x (RMSNorm eps `norm_eps`):
 The equal expert layers are one `lax.scan` body under `jax.checkpoint`, their
 parameters stacked on a leading axis (`params["moe"]`), so the step compiles
 one such layer and keeps one layer's activations.  Attention runs through
-`ops.ring_attention.flash_attention_remat` (scores in k-blocks, never
+`ops.ring_attention.flash_attention_remat` (scores in blocks, never
 [B, H, S, S]); that route accumulates the output at the width of q, so
 nope + rope must equal the value width, as it does in this family (192 + 64
 = 256).  The multi-token-prediction module of the published model is not
@@ -35,19 +35,17 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.ad_checkpoint import checkpoint_name
 
 from ..obs.names import scope
 from ..ops import moe as moe_ops
-from ..ops.ring_attention import flash_attention_remat
+from ..ops import ring_attention
 from .llama import _rmsnorm, _rope, _token_nll
 
 
-# what a layer's checkpoint keeps beside its input: the attention output, so
-# that the backward pass runs the attention route's forward once more (its
-# own recompute) and not twice (the layer's as well)
-ATTENTION_OUT = "glm_moe.attention_out"
-_KEEP = jax.checkpoint_policies.save_only_these_names(ATTENTION_OUT)
+# what a layer's checkpoint keeps beside its input: the attention route's
+# output and logsumexp, which are all its backward needs of its forward, so
+# that the layer's recompute does not run the attention again
+_KEEP = jax.checkpoint_policies.save_only_these_names(ring_attention.SAVED)
 
 
 @dataclass(frozen=True)
@@ -181,20 +179,12 @@ def num_params(cfg: GlmMoeConfig) -> int:
 
 
 def _causal_attention(q, k, v, cfg: GlmMoeConfig) -> jax.Array:
-    """[B, H, S, d] each -> [B, H, S, d], through the memory-bounded route.
-    The queries go in chunks of `attn_block` rows, each against the keys at
-    or before its last row only: the route computes every block of scores
-    it is handed, so this skips the blocks of the square that lie wholly
-    above the diagonal (36 of 64 are left at eight chunks).  A sequence no
-    longer than a block is one chunk."""
-    S = q.shape[2]
-    qb = cfg.attn_block or S
-    return jnp.concatenate([
-        flash_attention_remat(
-            q[:, :, i:i + qb], k[:, :, :i + qb], v[:, :, :i + qb],
-            causal=True, k_block=cfg.attn_block, impl=cfg.attn_impl,
-            q_offset=i)
-        for i in range(0, S, qb)], axis=2)
+    """[B, H, S, d] each -> [B, H, S, d], through the memory-bounded route:
+    queries and keys in blocks of `attn_block` rows, of which the route
+    visits those at or below the diagonal (36 of 64 at eight blocks a
+    side)."""
+    return ring_attention.flash_attention_remat(
+        q, k, v, causal=True, k_block=cfg.attn_block, impl=cfg.attn_impl)
 
 
 def mla(lyr: Dict, x: jax.Array, pos: jax.Array,
@@ -217,8 +207,7 @@ def mla(lyr: Dict, x: jax.Array, pos: jax.Array,
             [q[..., :dn], _rope(q[..., dn:], pos, cfg)], axis=-1)
         k = jnp.concatenate(
             [kv[..., :dn], jnp.broadcast_to(k_r, (B, H, S, dr))], axis=-1)
-        o = checkpoint_name(_causal_attention(q, k, kv[..., dn:], cfg),
-                            ATTENTION_OUT)
+        o = _causal_attention(q, k, kv[..., dn:], cfg)
         return o.transpose(0, 2, 1, 3).reshape(B, S, H * dv) @ lyr["wo"]
 
 

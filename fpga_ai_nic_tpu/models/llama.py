@@ -57,8 +57,8 @@ class LlamaConfig:
     # sp-sharded paths (ring/gathered) block independently of this knob.
     attn_block: "Optional[int]" = None
     # which flash implementation backs attn_block: "auto" = the fused
-    # Pallas kernels on TPU (ops.flash_pallas, custom-vjp backward),
-    # XLA-blocked scan elsewhere; "pallas"/"xla" pin one for A/B runs
+    # Pallas kernels on TPU (ops.flash_pallas), XLA blocks elsewhere
+    # (both a custom-vjp backward); "pallas"/"xla" pin one for A/B runs
     attn_impl: str = "auto"
     # MoE: when moe_experts > 0, every FFN becomes a top-k routed expert
     # layer (ops.moe); dense SwiGLU otherwise.  Not composable with the
@@ -279,9 +279,9 @@ def _block(lyr: Dict, x: jax.Array, pos: jax.Array, cfg: LlamaConfig,
                else ring_attention(q, k, v, sp_axis, causal=True,
                                    impl=cfg.attn_impl))
     elif cfg.attn_block is not None:
-        # memory-bounded single-device attention; the remat/backward
-        # choice (fused Pallas kernel vs checkpointed XLA scan) lives in
-        # ops.ring_attention.flash_attention_remat
+        # memory-bounded single-device attention; the choice of backend
+        # (fused Pallas kernels or blocked XLA, one (out, lse) contract)
+        # lives in ops.ring_attention.flash_attention_remat
         att = flash_attention_remat(q, k, v, causal=True,
                                     k_block=cfg.attn_block,
                                     impl=cfg.attn_impl)

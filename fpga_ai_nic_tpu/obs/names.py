@@ -75,6 +75,9 @@ SCOPES: Dict[str, str] = {
     "ainic.gather": "owned shard -> replicated parameters",
     # inside ainic.fwd_bwd, where the model has them (models/glm_moe.py)
     "ainic.mla": "latent attention: compressed q and k/v, rotary key, heads",
+    # inside a model's attention (ops/ring_attention.flash_attention)
+    "ainic.attn.fwd": "the XLA route's forward: out and lse, block by block",
+    "ainic.attn.bwd": "its backward: p from lse again, dQ, one dK and dV",
     "ainic.moe.route": "sigmoid scores over every expert, top-k, gates",
     "ainic.moe.experts": "dropless grouped product over the experts held",
     "ainic.moe.shared": "the shared expert, on every token",

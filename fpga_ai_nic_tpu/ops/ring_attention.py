@@ -16,11 +16,15 @@ to full attention on the unsharded sequence (up to fp reassociation).
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
+
+from ..obs.names import scope
 
 # "minus infinity" that survives exp() safely.  A plain float, NOT a
 # jnp scalar: creating a device array at import time initializes the XLA
@@ -30,16 +34,29 @@ from jax import lax
 _NEG = -1e30
 
 
+def _iota(n):
+    return lax.broadcasted_iota(jnp.int32, (n, 1), 0)[:, 0]
+
+
+def _rows(x, i, n):
+    """Block i of n rows of [B, H, S, .]."""
+    return lax.dynamic_slice_in_dim(x, i * n, n, axis=2)
+
+
+def _varying(x, vma):
+    """`x` widened to `vma` when the caller sits inside shard_map (loop
+    carries must enter with the vma type the body produces)."""
+    vma = tuple(sorted(vma))
+    return lax.pcast(x, vma, to="varying") if vma else x
+
+
 def _init_acc(B, H, S, dh, vma=()):
     """Fresh online-softmax accumulators (running max / normalizer /
-    output), widened to `vma` when the caller sits inside shard_map (scan
-    carries must enter with the vma type the body produces)."""
-    accs = (jnp.full((B, H, S, 1), _NEG, jnp.float32),
-            jnp.zeros((B, H, S, 1), jnp.float32),
-            jnp.zeros((B, H, S, dh), jnp.float32))
-    vma = tuple(sorted(vma))
-    return tuple(lax.pcast(z, vma, to="varying") if vma else z
-                 for z in accs)
+    output), widened to `vma`."""
+    return tuple(_varying(z, vma) for z in (
+        jnp.full((B, H, S, 1), _NEG, jnp.float32),
+        jnp.zeros((B, H, S, 1), jnp.float32),
+        jnp.zeros((B, H, S, dh), jnp.float32)))
 
 
 def _finish(o, l, out_dtype):
@@ -48,17 +65,24 @@ def _finish(o, l, out_dtype):
     return (o / jnp.where(l == 0, 1.0, l)).astype(out_dtype)
 
 
+def _scores(q, k, q_pos, k_pos, sm_scale, causal):
+    """Scaled float32 scores [B,H,Sq,Sk] of one block, _NEG where a key
+    lies after its query."""
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
+                   preferred_element_type=jnp.float32) * sm_scale
+    if causal:
+        mask = k_pos[None, :] > q_pos[:, None]           # [Sq, Sk]
+        s = jnp.where(mask[None, None], _NEG, s)
+    return s
+
+
 def _block_attend(q, k, v, q_pos, k_pos, m, l, o, sm_scale, causal):
     """One online-softmax accumulation step against a visiting K/V block.
 
     q: [B,H,Sq,dh]; k,v: [B,H,Sk,dh]; positions: [Sq]/[Sk];
     m,l: [B,H,Sq,1] running max / normalizer; o: [B,H,Sq,dh] running output.
     """
-    s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
-                   preferred_element_type=jnp.float32) * sm_scale
-    if causal:
-        mask = k_pos[None, :] > q_pos[:, None]           # [Sq, Sk]
-        s = jnp.where(mask[None, None], _NEG, s)
+    s = _scores(q, k, q_pos, k_pos, sm_scale, causal)
     m_blk = jnp.max(s, axis=-1, keepdims=True)           # [B,H,Sq,1]
     m_new = jnp.maximum(m, m_blk)
     alpha = jnp.exp(m - m_new)                           # rescale old state
@@ -68,6 +92,15 @@ def _block_attend(q, k, v, q_pos, k_pos, m, l, o, sm_scale, causal):
                                    v.astype(jnp.float32),
                                    preferred_element_type=jnp.float32)
     return m_new, l_new, o_new
+
+
+def _fit_block(S: int, block: Optional[int]) -> int:
+    """The block size that keeps the memory bound for any S: the largest
+    divisor of S <= `block` (smaller blocks cost iterations, never memory);
+    S itself where `block` is None."""
+    if block is None or block >= S:
+        return S
+    return next(d for d in range(block, 0, -1) if S % d == 0)
 
 
 def _attend_chunk(qf, k, v, q_pos, k_pos0, m, l, o, sm_scale, causal,
@@ -82,24 +115,17 @@ def _attend_chunk(qf, k, v, q_pos, k_pos0, m, l, o, sm_scale, causal,
     hardware: it never buffers a whole vector, it streams 32 KiB slices
     through fixed-size working sets (hw/all_reduce.sv:101-103)."""
     S = k.shape[2]
-    if k_block is not None and S % k_block:
-        # keep the memory bound for any S: largest divisor of S <= k_block
-        # (smaller blocks cost iterations, never memory)
-        k_block = next(d for d in range(min(k_block, S), 0, -1) if S % d == 0)
-    if k_block is None or k_block >= S:
-        k_pos = k_pos0 + lax.broadcasted_iota(jnp.int32, (S, 1), 0)[:, 0]
+    k_block = _fit_block(S, k_block)
+    if k_block == S:
+        k_pos = k_pos0 + _iota(S)
         return _block_attend(qf, k.astype(jnp.float32), v, q_pos, k_pos,
                              m, l, o, sm_scale, causal)
 
     def step(carry, j):
-        m, l, o = carry
-        ks = lax.dynamic_slice_in_dim(k, j * k_block, k_block, axis=2)
-        vs = lax.dynamic_slice_in_dim(v, j * k_block, k_block, axis=2)
-        kp = (k_pos0 + j * k_block
-              + lax.broadcasted_iota(jnp.int32, (k_block, 1), 0)[:, 0])
-        m, l, o = _block_attend(qf, ks.astype(jnp.float32), vs, q_pos, kp,
-                                m, l, o, sm_scale, causal)
-        return (m, l, o), None
+        kp = k_pos0 + j * k_block + _iota(k_block)
+        return _block_attend(
+            qf, _rows(k, j, k_block).astype(jnp.float32),
+            _rows(v, j, k_block), q_pos, kp, *carry, sm_scale, causal), None
 
     # remat_blocks: recompute each block's scores in the backward (the
     # flash-attention backward) — without it, differentiating the scan
@@ -187,7 +213,7 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array, axis_name: str,
     if sm_scale is None:
         sm_scale = dh ** -0.5
     qf = q.astype(jnp.float32)
-    q_pos = idx * S + lax.broadcasted_iota(jnp.int32, (S, 1), 0)[:, 0]
+    q_pos = idx * S + _iota(S)
 
     # hop 0: attend the local block first (a causal token always sees
     # itself, so the row max is finite and the carry enters the ring loop
@@ -236,7 +262,7 @@ def full_attention(q, k, v, *, causal=True, sm_scale=None):
                    preferred_element_type=jnp.float32) * sm_scale
     S = q.shape[2]
     if causal:
-        pos = lax.broadcasted_iota(jnp.int32, (S, 1), 0)[:, 0]
+        pos = _iota(S)
         s = jnp.where((pos[None, :] > pos[:, None])[None, None], _NEG, s)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", p, v.astype(jnp.float32)
@@ -245,24 +271,19 @@ def full_attention(q, k, v, *, causal=True, sm_scale=None):
 
 def flash_attention_remat(q, k, v, *, causal=True, sm_scale=None,
                           k_block: Optional[int] = 512, impl: str = "auto",
-                          q_offset: int = 0):
-    """Memory-bounded exact attention for model code — picks the best
-    backward story available:
+                          q_offset=0):
+    """Memory-bounded exact attention for model code, over the whole
+    sequence's queries.  Both backends have one contract: the forward gives
+    `out` and `lse = m + log l`, the hand-written backward recomputes p
+    from `lse`, so residual memory is O(S) and no ``jax.checkpoint``
+    wrapper is needed (one would only run the forward again).
 
     - ``pallas`` (auto on TPU when shapes tile): the fused
-      ops.flash_pallas kernels; the custom-vjp backward recomputes p
-      from the saved logsumexp, so no ``jax.checkpoint`` wrapper is
-      needed (wrapping one would only re-run the forward kernel).
-    - ``xla`` (auto off-TPU / odd shapes): the k-block-scanned
-      ``flash_attention`` under attention-only ``jax.checkpoint`` —
-      without it the scan's per-block residuals reconstitute O(S^2)
-      backward memory (measured 22 GB at S=16,384; models/llama.py
-      carried this wrapper before round 5 moved the choice here).
+      ops.flash_pallas kernels.
+    - ``xla`` (auto off-TPU / odd shapes): `flash_attention` below.
 
-    `q_offset` is the position of q's first row among the keys: a caller
-    that takes the queries of a causal sequence in chunks hands each chunk
-    only the keys at or before its last row, and skips the rest of the
-    square."""
+    `q_offset` is the position of q's first row among the keys, for a
+    caller that holds a shard of a causal sequence's queries."""
     from . import flash_pallas
     if pallas_route(impl, q, kv_seq_len=k.shape[2]):
         b = k_block or flash_pallas._DEF_BLOCK
@@ -270,11 +291,8 @@ def flash_attention_remat(q, k, v, *, causal=True, sm_scale=None,
                                             sm_scale=sm_scale,
                                             q_offset=q_offset,
                                             block_q=b, block_k=b)
-    return jax.checkpoint(
-        lambda q2, k2, v2: flash_attention(q2, k2, v2, causal=causal,
-                                           sm_scale=sm_scale,
-                                           k_block=k_block,
-                                           q_offset=q_offset))(q, k, v)
+    return flash_attention(q, k, v, causal=causal, sm_scale=sm_scale,
+                           k_block=k_block, q_offset=q_offset)
 
 
 def gathered_attention(q, k, v, axis_name: str, *, causal=True,
@@ -282,9 +300,9 @@ def gathered_attention(q, k, v, axis_name: str, *, causal=True,
                        impl: str = "auto"):
     """Sequence-parallel attention via KV all-gather: queries stay
     sequence-sharded, keys/values gather once over `axis_name`, and the
-    local attention runs the same flash-style k-blocked online softmax as
-    ring_attention (`_attend_chunk`), so peak score memory stays
-    O(S_local * k_block) — only the gathered K/V buffers are O(S_global).
+    local attention is `flash_attention_remat` with the shard's position as
+    its `q_offset`, so peak score memory stays one block — only the
+    gathered K/V buffers are O(S_global).
 
     Why it exists next to ring_attention: the 1F1B schedulers run the
     attention inside stage-divergent `lax.cond` branches, and a
@@ -299,53 +317,141 @@ def gathered_attention(q, k, v, axis_name: str, *, causal=True,
     exact attention).  Reference analogue: none — the reference has no
     attention; this is the standard all-gather sequence-parallel form.
 
-    impl: "auto" keeps the (replica-grouped, cond-safe) all_gather and
-    runs the LOCAL attention through the fused Pallas kernel with
-    q_offset = idx*S_local (global-position causality); "xla"/"pallas"
-    pin a backend.
+    impl: as `flash_attention_remat`'s; the (replica-grouped, cond-safe)
+    all_gather stays on either backend.
     """
-    n = lax.axis_size(axis_name)
-    idx = lax.axis_index(axis_name)
-    B, H, Sl, dh = q.shape
-    if sm_scale is None:
-        sm_scale = dh ** -0.5
     kf = lax.all_gather(k, axis_name, axis=2, tiled=True)
     vf = lax.all_gather(v, axis_name, axis=2, tiled=True)
-    if pallas_route(impl, q, kv_seq_len=kf.shape[2]):
-        from . import flash_pallas
-        b = k_block or flash_pallas._DEF_BLOCK
-        return flash_pallas.flash_attention(
-            q, kf, vf, causal=causal, sm_scale=sm_scale,
-            q_offset=idx * Sl, block_q=b, block_k=b)
-    qf = q.astype(jnp.float32)
-    q_pos = idx * Sl + lax.broadcasted_iota(jnp.int32, (Sl, 1), 0)[:, 0]
-    m0, l0, o0 = _init_acc(B, H, Sl, dh,
-                           {axis_name} | set(jax.typeof(qf).vma))
-    m, l, o = _attend_chunk(qf, kf, vf, q_pos, 0, m0, l0, o0,
-                            sm_scale, causal, k_block)
-    return _finish(o, l, q.dtype)
+    return flash_attention_remat(
+        q, kf, vf, causal=causal, sm_scale=sm_scale, k_block=k_block,
+        impl=impl, q_offset=lax.axis_index(axis_name) * q.shape[2])
+
+
+# the name the route's residuals `out` and `lse` carry: a caller whose
+# jax.checkpoint keeps it (checkpoint_policies.save_only_these_names) does
+# not run the attention forward again in its recompute
+SAVED = "ainic.attn.out_lse"
+
+
+def _join(x):
+    """Stacked chunks [n, B, H, rows, .] -> [B, H, n * rows, .]."""
+    n, B, H, rows, d = x.shape
+    return jnp.moveaxis(x, 0, 2).reshape(B, H, n * rows, d)
+
+
+def _blocking(q, k, off, block, causal):
+    """Rows per query chunk and per k block, the number of chunks, and the
+    number of k blocks chunk i reads: under a causal mask those whose first
+    key is at or before the chunk's last row, so the blocks wholly above
+    the diagonal are never visited."""
+    qb, kb = _fit_block(q.shape[2], block), _fit_block(k.shape[2], block)
+    nk = k.shape[2] // kb
+
+    def visible(i):
+        if not causal:
+            return nk
+        return jnp.minimum(nk, (off + (i + 1) * qb - 1) // kb + 1)
+
+    return qb, kb, q.shape[2] // qb, visible
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _blocked(q, k, v, off, sm_scale, causal, block):
+    return _blocked_fwd(q, k, v, off, sm_scale, causal, block)[0]
+
+
+def _blocked_fwd(q, k, v, off, sm_scale, causal, block):
+    B, H, _, dh = q.shape
+    qb, kb, nq, visible = _blocking(q, k, off, block, causal)
+    vma = set().union(*(jax.typeof(x).vma for x in (q, k, v, off)))
+
+    def chunk(i):
+        qf = _rows(q, i, qb).astype(jnp.float32)
+        q_pos = off + i * qb + _iota(qb)
+
+        def attend(j, mlo):
+            return _block_attend(
+                qf, _rows(k, j, kb).astype(jnp.float32), _rows(v, j, kb),
+                q_pos, j * kb + _iota(kb), *mlo, sm_scale, causal)
+
+        m, l, o = lax.fori_loop(0, visible(i), attend,
+                                _init_acc(B, H, qb, dh, vma))
+        return _finish(o, l, q.dtype), m + jnp.log(jnp.where(l == 0, 1.0, l))
+
+    # the chunks stacked and joined: written into the whole in place, the
+    # output cost 1.2 ms a layer more on the v5e (PERF.md, PR 31)
+    with scope("ainic.attn.fwd"):
+        out, lse = map(_join, lax.map(chunk, jnp.arange(nq)))
+    out, lse = checkpoint_name(out, SAVED), checkpoint_name(lse, SAVED)
+    return out, (q, k, v, off, out, lse)
+
+
+def _blocked_bwd(sm_scale, causal, block, res, do):
+    """The flash-attention backward: with p = exp(s - lse) recomputed a
+    block at a time, ds = p * (dp - delta) * scale where dp = dO v^T and
+    delta = rowsum(dO * O); one float32 dK and one dV for all the keys,
+    each block's rows added in place."""
+    q, k, v, off, out, lse = res
+    B, H, _, dh = q.shape
+    qb, kb, nq, visible = _blocking(q, k, off, block, causal)
+    vma = set().union(*(jax.typeof(x).vma for x in (q, k, v, off, do)))
+
+    def add_rows(acc, j, part):
+        return lax.dynamic_update_slice_in_dim(
+            acc, _rows(acc, j, kb) + part, j * kb, axis=2)
+
+    def chunk(dkv, i):
+        qf = _rows(q, i, qb).astype(jnp.float32)
+        dof = _rows(do, i, qb).astype(jnp.float32)
+        lse_i, delta_i = _rows(lse, i, qb), _rows(delta, i, qb)
+        q_pos = off + i * qb + _iota(qb)
+
+        def block_grads(j, acc):
+            dq, dk, dv = acc
+            kf = _rows(k, j, kb).astype(jnp.float32)
+            vf = _rows(v, j, kb).astype(jnp.float32)
+            p = jnp.exp(_scores(qf, kf, q_pos, j * kb + _iota(kb), sm_scale,
+                                causal) - lse_i)
+            dp = jnp.einsum("bhqd,bhkd->bhqk", dof, vf,
+                            preferred_element_type=jnp.float32)
+            ds = p * (dp - delta_i) * sm_scale
+            dq = dq + jnp.einsum("bhqk,bhkd->bhqd", ds, kf,
+                                 preferred_element_type=jnp.float32)
+            dk = add_rows(dk, j, jnp.einsum(
+                "bhqk,bhqd->bhkd", ds, qf,
+                preferred_element_type=jnp.float32))
+            dv = add_rows(dv, j, jnp.einsum(
+                "bhqk,bhqd->bhkd", p, dof,
+                preferred_element_type=jnp.float32))
+            return dq, dk, dv
+
+        dq0 = _varying(jnp.zeros((B, H, qb, dh), jnp.float32), vma)
+        dq, dk, dv = lax.fori_loop(0, visible(i), block_grads, (dq0, *dkv))
+        return (dk, dv), dq.astype(q.dtype)
+
+    with scope("ainic.attn.bwd"):
+        delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
+                        axis=-1, keepdims=True)
+        zeros = _varying(jnp.zeros(k.shape, jnp.float32), vma)
+        (dk, dv), dq = lax.scan(chunk, (zeros, zeros), jnp.arange(nq))
+    return _join(dq), dk.astype(k.dtype), dv.astype(v.dtype), None
+
+
+_blocked.defvjp(_blocked_fwd, _blocked_bwd)
 
 
 def flash_attention(q, k, v, *, causal=True, sm_scale=None,
-                    k_block: Optional[int] = 512, q_offset: int = 0):
-    """Single-device flash-blocked exact attention: the same
-    `_attend_chunk` online-softmax accumulation the ring/gathered
-    variants use, with no collectives — peak score memory
-    O(S * k_block) instead of full_attention's O(S^2) f32 score matrix
-    (which XLA also saves for the backward, forcing remat on long
-    sequences).  Bit-differences vs full_attention are f32 summation
-    order only; both are exact softmax attention.  q may be a chunk of the
-    sequence's queries: its first row is key position `q_offset`."""
+                    k_block: Optional[int] = 512, q_offset=0):
+    """Single-device flash-blocked exact attention, the XLA route: queries
+    and keys in blocks of `k_block` rows (its largest divisor of each
+    length; None: whole), the same `_block_attend` online softmax the
+    ring/gathered variants use, over exactly the blocks a causal mask
+    leaves — peak score memory one [B, H, k_block, k_block] block instead of
+    full_attention's O(S^2) float32 score matrix, forward and backward.
+    Differences from full_attention are float32 summation order only; both
+    are exact softmax attention.  q may be a shard of the sequence's
+    queries: its first row is key position `q_offset` (traced or not)."""
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
-    B, H, S, dh = q.shape
-    qf = q.astype(jnp.float32)
-    pos = q_offset + lax.broadcasted_iota(jnp.int32, (S, 1), 0)[:, 0]
-    # q may be batch-sharded under an outer shard_map even though this
-    # attention itself is collective-free
-    vma = (set(jax.typeof(qf).vma) | set(jax.typeof(k).vma)
-           | set(jax.typeof(v).vma))
-    m0, l0, o0 = _init_acc(B, H, S, dh, vma)
-    m, l, o = _attend_chunk(qf, k, v, pos, 0, m0, l0, o0,
-                            sm_scale, causal, k_block)
-    return _finish(o, l, q.dtype)
+    return _blocked(q, k, v, jnp.asarray(q_offset, jnp.int32), sm_scale,
+                    causal, k_block)

@@ -156,6 +156,27 @@ def test_mla_block_at_the_published_head_widths():
     assert float(jnp.max(jnp.abs(flat - want))) > 1e-3
 
 
+@pytest.mark.parametrize("attn_block", [8, 6, 4])
+def test_blocks_of_queries_equal_one_chunk(attn_block):
+    """`_causal_attention` with `attn_block` < S visits the blocks at or
+    below the diagonal only; which those are is the route's bookkeeping
+    (ops/ring_attention._blocking).  Loss and gradients must equal the same
+    call with the whole sequence as one chunk, which has no diagonal to
+    keep."""
+    cfg = FAMILY.model_config(dict(TINY, attn_block=attn_block))
+    whole = FAMILY.model_config(dict(TINY, attn_block=None))
+    q, k, v, w = jax.random.normal(jax.random.PRNGKey(7), (4, 2, 2, 24, 16))
+
+    def run(c):
+        return jax.jit(jax.value_and_grad(
+            lambda *a: jnp.sum(glm_moe._causal_attention(*a, c) * w),
+            argnums=(0, 1, 2)))(q, k, v)
+
+    (got, got_grads), (want, want_grads) = run(cfg), run(whole)
+    assert abs(float(got) - float(want)) < 1e-5 * abs(float(want))
+    assert rel_l2(got_grads, want_grads) < 1e-5
+
+
 def test_unequal_key_and_value_widths_are_refused():
     with pytest.raises(ValueError, match="value width"):
         glm_moe.GlmMoeConfig.tiny(v_dim=8)

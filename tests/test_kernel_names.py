@@ -257,7 +257,8 @@ def lowered_step(request):
 
 # the scopes a model brings into ainic.fwd_bwd; the others are the step's own
 MODEL_SCOPES = sorted(s for s in names.SCOPES
-                      if s.startswith(("ainic.mla", "ainic.moe.")))
+                      if s.startswith(("ainic.mla", "ainic.moe.",
+                                       "ainic.attn.")))
 
 
 @pytest.mark.parametrize("scope", sorted(set(names.SCOPES)
@@ -288,9 +289,10 @@ def lowered_glm_step():
     return tr.step_fn.lower(state, batch).as_text(debug_info=True)
 
 
-def test_the_model_scopes_are_the_four_the_table_lists():
-    assert MODEL_SCOPES == ["ainic.mla", "ainic.moe.experts",
-                            "ainic.moe.route", "ainic.moe.shared"]
+def test_the_model_scopes_are_the_six_the_table_lists():
+    assert MODEL_SCOPES == ["ainic.attn.bwd", "ainic.attn.fwd", "ainic.mla",
+                            "ainic.moe.experts", "ainic.moe.route",
+                            "ainic.moe.shared"]
 
 
 @pytest.mark.parametrize("scope", MODEL_SCOPES)

@@ -1,5 +1,7 @@
 """Ring attention (sequence parallel) vs full attention golden."""
 
+import re
+
 import numpy as np
 import pytest
 
@@ -213,3 +215,137 @@ def test_flash_matches_full(rng, causal, k_block):
         a, b, c, causal=causal, k_block=k_block))(q, k, v)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-6)
+
+
+# -- the XLA route of model code: flash_attention_remat(impl="xla") ----------
+# out and lse forward, a hand-written backward (PR 31)
+
+def _scan_route(q, k, v, *, causal, k_block, q_offset=0):
+    """The route as it was before its backward was written by hand, kept
+    here as the reference: the online-softmax scan over k blocks under
+    jax.checkpoint, differentiated by JAX."""
+    def attend(q, k, v):
+        B, H, S, dh = q.shape
+        pos = q_offset + jnp.arange(S, dtype=jnp.int32)
+        m, l, o = ra._attend_chunk(
+            q.astype(jnp.float32), k, v, pos, 0, *ra._init_acc(B, H, S, dh),
+            dh ** -0.5, causal, k_block)
+        return ra._finish(o, l, q.dtype)
+    return jax.checkpoint(attend)(q, k, v)
+
+
+def _full_rows(q, k, v, *, causal, k_block=None, q_offset=0):
+    """full_attention of the rows q holds: q laid at rows q_offset.. of a
+    whole sequence's queries, the other rows zero and dropped again."""
+    if q.shape[2] == k.shape[2]:
+        return ra.full_attention(q, k, v, causal=causal)
+    whole = jnp.zeros(k.shape[:3] + q.shape[3:], q.dtype)
+    whole = jax.lax.dynamic_update_slice_in_dim(whole, q, q_offset, axis=2)
+    return ra.full_attention(whole, k, v, causal=causal)[
+        :, :, q_offset:q_offset + q.shape[2]]
+
+
+def _rel_l2(a, b):
+    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+    return float(np.sqrt(np.sum((a - b) ** 2) / np.sum(b ** 2)))
+
+
+@pytest.mark.parametrize(
+    "Sq,Sk,k_block,q_offset,causal,dtype,reference,tol", [
+        (16, 16, 16, 0, True, jnp.float32, _full_rows, 1e-5),
+        (64, 64, 16, 0, True, jnp.float32, _full_rows, 1e-5),
+        (128, 128, 16, 0, True, jnp.float32, _full_rows, 1e-5),
+        (48, 48, 10, 0, True, jnp.float32, _full_rows, 1e-5),
+        (32, 64, 16, 32, True, jnp.float32, _full_rows, 1e-5),
+        (32, 64, 16, 24, True, jnp.float32, _full_rows, 1e-5),
+        (64, 64, 16, 0, False, jnp.float32, _full_rows, 1e-5),
+        (64, 64, None, 0, True, jnp.float32, _full_rows, 1e-5),
+        (64, 64, 16, 0, True, jnp.bfloat16, _scan_route, 1e-2),
+        (32, 64, 16, 32, True, jnp.bfloat16, _scan_route, 1e-2),
+    ], ids=["one-block", "four-blocks", "eight-blocks",
+            "a-k_block-that-divides-nothing", "a-shard-of-the-queries",
+            "a-shard-that-starts-inside-a-block", "no-mask", "no-blocks",
+            "bfloat16-against-the-scan", "bfloat16-shard-against-the-scan"])
+def test_xla_route_out_and_gradients(rng, Sq, Sk, k_block, q_offset, causal,
+                                     dtype, reference, tol):
+    """out, dQ, dK and dV of the route against full attention (float32) or
+    against the scan it replaced (bfloat16: same operand types, so the two
+    differ by the output's rounding alone), B and H above one."""
+    B, H, dh = 2, 3, 16
+    q, k, v, w = (jnp.asarray(rng.standard_normal((B, H, S_, dh)), dtype)
+                  for S_ in (Sq, Sk, Sk, Sq))
+
+    def run(fn):
+        def loss(q, k, v):
+            out = fn(q, k, v, causal=causal, k_block=k_block,
+                     q_offset=q_offset)
+            return jnp.sum((out * w).astype(jnp.float32)), out
+        (_, out), grads = jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+        return (out, *grads)
+
+    got = run(lambda *a, **kw: ra.flash_attention_remat(*a, impl="xla", **kw))
+    for name, a, b in zip(("out", "dq", "dk", "dv"), got, run(reference)):
+        assert a.dtype == b.dtype == dtype and a.shape == b.shape, name
+        assert _rel_l2(a, b) <= tol, (name, _rel_l2(a, b))
+
+
+def test_xla_route_takes_a_traced_offset(rng):
+    """A sequence-parallel caller's q_offset is axis_index * S_local: a
+    traced value, which decides the loops' trip counts at run time."""
+    B, H, dh = 1, 2, 8
+    q, k, v = (jnp.asarray(rng.standard_normal((B, H, S_, dh)), jnp.float32)
+               for S_ in (16, 32, 32))
+    got = jax.jit(lambda off: ra.flash_attention(
+        q, k, v, k_block=8, q_offset=off))(jnp.int32(16))
+    want = _full_rows(q, k, v, causal=True, q_offset=16)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-6)
+
+
+def test_xla_route_backward_memory_is_o_s():
+    """What the jax.checkpoint wrapper was there to guarantee: compiled
+    temporaries of the gradient grow about linearly in S at one k_block —
+    the backward holds one block of scores, not every block's."""
+    def temp_bytes(S_):
+        x = jnp.zeros((1, 2, S_, 64), jnp.float32)
+        grad = jax.jit(jax.grad(
+            lambda q, k, v: jnp.sum(ra.flash_attention_remat(
+                q, k, v, k_block=256, impl="xla")), argnums=(0, 1, 2)))
+        return grad.lower(x, x, x).compile().memory_analysis(
+            ).temp_size_in_bytes
+
+    at_1k, at_4k = temp_bytes(1024), temp_bytes(4096)
+    assert at_4k < 8 * at_1k, (at_1k, at_4k)
+    # the whole square of float32 scores at S = 4,096 alone: 134 MB
+    assert at_4k < 2 * 4096 * 4096 * 4 // 4, at_4k
+
+
+@pytest.mark.parametrize("policy,products", [
+    ("the-model's", 3), ("nothing-saved", 4)])
+def test_layer_checkpoint_keeps_out_and_lse(policy, products):
+    """A layer under the model's checkpoint policy computes each block of
+    scores once forward (q k^T) and once backward (q k^T again and dO v^T):
+    three products shaped [B, H, block, block] in the compiled gradient.
+    Were `lse` not saved with the output, the layer's recompute would run
+    the route's forward again for it: four, as the control shows."""
+    from fpga_ai_nic_tpu.models import glm_moe
+    cfg = glm_moe.GlmMoeConfig(
+        vocab=64, dim=32, n_layers=1, n_dense_layers=1, n_heads=2,
+        q_lora_rank=16, kv_lora_rank=8, qk_nope_dim=12, qk_rope_dim=4,
+        v_dim=16, ffn_dim=64, moe_ffn_dim=24, n_routed_experts=2, held=(0,),
+        top_k=1, dtype="float32", attn_block=8, attn_impl="xla")
+    lyr = glm_moe.init(jax.random.PRNGKey(0), cfg)["dense"][0]
+    x = jnp.zeros((2, 32, cfg.dim), jnp.float32)
+    pos = jnp.arange(32, dtype=jnp.int32)
+    keep = {"the-model's": glm_moe._KEEP,
+            "nothing-saved": jax.checkpoint_policies.nothing_saveable}[policy]
+
+    def loss(lyr, x):
+        return jnp.sum(jax.checkpoint(
+            lambda l, y: glm_moe._dense_block(l, y, pos, cfg),
+            policy=keep)(lyr, x) ** 2)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        lyr, x).compile().as_text()
+    assert len(re.findall(r"f32\[2,2,8,8\]\S* dot\(", text)) == products
