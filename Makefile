@@ -1,5 +1,5 @@
 # Top-level targets mirroring CI (.github/workflows/ci.yml).
-.PHONY: ci test codec bench chip-smoke collective perf multichip-bench multichip-dryrun chaos-bench codec-bench fused-opt-bench reshard-bench tune-bench serve-bench fleet-bench integrity-bench slo-bench adapt-bench ckpt-bench obs-gate lint lint-fixtures modelcheck
+.PHONY: ci test codec chip-smoke collective chaos-bench codec-bench fused-opt-bench reshard-bench tune-bench serve-bench fleet-bench integrity-bench slo-bench adapt-bench ckpt-bench obs-gate lint lint-fixtures modelcheck
 
 codec:
 	$(MAKE) -C fpga_ai_nic_tpu/csrc
@@ -63,10 +63,9 @@ lint-fixtures:
 
 ci: codec test lint modelcheck obs-gate
 
-# both need the chip: run them through the chip tool, one process per chip
-bench:
-	python bench.py
-
+# needs the chip: run it through the chip tool, one process per chip.
+# The measured surface is the benchmark (BENCHMARK.json, benchmark/run.py;
+# numbers in the root PERF.md and PERF_LEDGER.jsonl)
 chip-smoke:
 	python chip_smoke.py
 
@@ -79,10 +78,6 @@ collective:
 	@latest=$$(ls -t artifacts/collective_tpu_*.json artifacts/collective_2*.json 2>/dev/null | head -1); \
 	  cp $$latest COLLECTIVE_$(ROUND).json; \
 	  echo "saved $$latest -> COLLECTIVE_$(ROUND).json"
-
-# regenerate docs/PERF.md strictly from committed artifacts
-perf:
-	python tools/gen_perf_md.py
 
 # the codec x {vmem, streaming} matrix: every registered compression
 # codec's encode/decode/roundtrip slope rates at both payload classes,
@@ -106,22 +101,6 @@ fused-opt-bench:
 	@latest=$$(ls -t artifacts/fused_opt_bench_*.json 2>/dev/null | head -1); \
 	  cp $$latest FUSED_OPT_BENCH_$(ROUND).json; \
 	  echo "saved $$latest -> FUSED_OPT_BENCH_$(ROUND).json"
-
-# multi-chip conversion kit: on any >= 2-real-chip surface this banks the
-# canary -> busbw (bf16 psum vs BFP rings) -> trace-attribution ladder
-# unattended (tools/multichip_bench.py docstring states the claims each
-# stage settles); the dryrun variant validates every code path on the
-# 8-device virtual CPU mesh, artifacts marked {"dryrun": true}
-multichip-bench:
-	python tools/multichip_bench.py
-
-multichip-dryrun:
-	python tools/multichip_bench.py --dryrun
-
-# trace every zoo config abstractly on CPU (no hardware): config bugs
-# must never cost chip time
-zoo-validate:
-	python tools/zoo_tpu.py --validate
 
 # the chaos fault matrix: every fault class x injection site x wire
 # format, each cell a real supervised run that must recover (or absorb)

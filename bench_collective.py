@@ -51,7 +51,7 @@ TIMED_ITERS = 3
 
 def _timeit(fn, sync, iters=TIMED_ITERS):
     """Median-free simple timing: warmup (compile) + timed loop + honest
-    sync (jitted scalar reduction fetch — see bench.py docstring)."""
+    sync (jitted scalar reduction fetch)."""
     out = fn()
     sync(out)
     t0 = time.perf_counter()

@@ -197,9 +197,9 @@ def git_sha(repo_dir=None) -> str:
 
 
 def save_artifact(prefix: str, result: dict) -> str:
-    """Write a timestamped raw-evidence JSON under artifacts/.  Every perf
-    claim in docs/PERF.md must trace to one of these files (round-2 verdict:
-    a number without a committed artifact is asserted, not measured)."""
+    """Write a timestamped raw-evidence JSON under artifacts/ (round-2
+    verdict: a number without a committed artifact is asserted, not
+    measured)."""
     here = os.path.dirname(os.path.abspath(__file__))
     art_dir = os.path.join(here, "artifacts")
     os.makedirs(art_dir, exist_ok=True)

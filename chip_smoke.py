@@ -32,7 +32,7 @@ import warnings
 import numpy as np
 
 WIDTH, LAYERS, BATCH_PER_CHIP = 2048, 10, 4096
-LR = 0.1                      # bench.py's: the loss falls from step one
+LR = 0.1                      # the reference's (sw/run.sh): the loss falls from step one
 STEPS = 5                     # after the warm-up step
 LOOPBACK_BYTES = 32 << 20
 VIRTUAL_N = 4

@@ -142,16 +142,6 @@ def main(argv):
         "wall_s": wall,
         "profile": prof.report(),
     }
-    if trace_dir:
-        # stall attribution from the trace itself (SURVEY.md §5): how much
-        # async collective/DMA time compute hid vs left exposed
-        try:
-            from fpga_ai_nic_tpu.utils import trace_analysis
-            out["trace_analysis"] = trace_analysis.summarize(
-                trace_analysis.analyze_trace(trace_dir))
-        except Exception as e:  # noqa: BLE001 — a corrupt trace must never
-            # discard the training result the run existed to produce
-            out["trace_analysis"] = {"error": f"{type(e).__name__}: {e}"}
     print(json.dumps(out))
 
 

@@ -175,7 +175,7 @@ def default_targets(repo_root: str) -> List[str]:
             for fn in sorted(filenames):
                 if fn.endswith(".py"):
                     targets.append(os.path.join(dirpath, fn))
-    for fn in ("bench.py", "bench_collective.py", "bench_common.py"):
+    for fn in ("bench_collective.py", "bench_common.py"):
         p = os.path.join(repo_root, fn)
         if os.path.exists(p):
             targets.append(p)

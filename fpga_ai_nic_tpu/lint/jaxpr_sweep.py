@@ -85,9 +85,10 @@ def _collect(jaxpr) -> Dict[str, Any]:
                            "wire_unknown": False, "axes": set(),
                            "donated": None}
     for eqn in jaxpr.eqns:
-        # first top-level pjit = the jitted step call whose donation
-        # mask J3 inspects (leading convert/broadcast eqns are fine)
-        if eqn.primitive.name == "pjit":
+        # first top-level jit call (the primitive was named "pjit";
+        # jax 0.9.0 prints "jit") = the jitted step whose donation mask
+        # J3 inspects (leading convert/broadcast eqns are fine)
+        if eqn.primitive.name in ("pjit", "jit"):
             out["donated"] = tuple(eqn.params.get("donated_invars", ()))
             break
     for eqn, mult in _iter_eqns(jaxpr):

@@ -60,8 +60,8 @@ def init_cache(cfg: LlamaConfig, batch: int, max_seq: int, *,
     sequences cannot share a byte.  That is the right trade for a single
     fixed-shape generate() call; it is the wrong one for a serving plane
     multiplexing thousands of requests (see `serve.paged.init_pool` +
-    `forward_paged`: one shared page pool, per-sequence page tables,
-    docs/PERF.md "Serving" for the measured comparison)."""
+    `forward_paged`: one shared page pool, per-sequence page tables;
+    `tools/serve_bench.py` banks the byte comparison)."""
     kv_local = kv_local_heads(cfg, tp_size)
     dt = jnp.dtype(dtype or cfg.dtype)
     shape = (batch, kv_local, max_seq, cfg.head_dim)

@@ -4,8 +4,9 @@ The reference answers "was the wire hidden by compute?" with stall-cause
 CSR counters read over MMIO (stall_host_in/out, stall_eth_in/out,
 hw/all_reduce.sv:94-97).  The TPU answer is a *timeline*: host spans
 (Profiler buckets, elastic attempts), the collective queue's issue/wait
-ticket intervals, and the device plane's sync/async op intervals
-(utils.trace_analysis), all merged onto one time axis and emitted as
+ticket intervals, and the device plane's sync/async op intervals (read
+from the profiler's XPlane here, ``_device_intervals``), all merged onto
+one time axis and emitted as
 Chrome-trace JSON — load the file in https://ui.perfetto.dev (or
 chrome://tracing) and the stall attribution is visible instead of argued:
 a ticket span with no sync compute under it IS exposed wire time.
@@ -30,6 +31,8 @@ from __future__ import annotations
 
 import argparse
 import json
+import os
+import re
 import sys
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
@@ -160,12 +163,120 @@ def _device_trace_events(device_intervals: Sequence[Dict[str, Any]],
     return out
 
 
+# ---------------------------------------------------------------------------
+# the profiler's XPlane -> raw per-op intervals.  Only the Perfetto export
+# reads a trace here; every NUMBER from a trace (kernel time, exposed wire
+# time, idle share) is benchmark/trace_reduce.py's, which knows the ring's
+# kernels by name.
+# ---------------------------------------------------------------------------
+
+# hyphenated HLO collective op names, matched as substrings.  The short
+# jax-primitive names ("psum", ...) must NOT live here: any fusion merely
+# NAMED after a psum consumer (e.g. "psum_invariant_fusion") would classify.
+_COLLECTIVE_MARKERS = (
+    "all-reduce", "all-gather", "reduce-scatter", "collective-permute",
+    "all-to-all", "collective-broadcast", "ragged-all-to-all",
+)
+# jax-level instruction names (XLA's CPU thunk executor names HLO
+# collectives after the primitive that built them, e.g. "psum.7").
+# Matched as the WHOLE base name plus an optional ".uid" suffix — never as
+# a substring — so "psum.7" classifies but "my_psum_like_fusion" does not.
+_CPU_PRIMITIVE_RE = re.compile(
+    r"(?:psum|ppermute|all_gather|all_to_all|psum_scatter|reduce_scatter"
+    r"|pmax|pmin)(?:\.\d+)?")
+# thunks execute on the per-shard executor threads AND the shared Eigen
+# intra-op pool threads; both carry leaf op events.  The executor line's
+# prefix follows the CPU client's name across jaxlibs: TfrtCpuClient
+# before the PjRt rename (jax <= 0.4.x), PjRtCpuClient after.
+_CPU_LINE_PREFIXES = ("tf_XLAPjRtCpuClient", "tf_XLATfrtCpuClient",
+                      "tf_XLAEigen")
+# leaf thunk events are bare HLO instruction names ("wrapped_tanh",
+# "psum.7", "broadcast_add_fusion"); executor infrastructure events mostly
+# carry spaces or "::" ("ThunkExecutor::Execute (...)", "end: X",
+# "Wait: pending_threads=2/8") — the bare-word exceptions are listed
+_CPU_OP_RE = re.compile(r"[\w.\-]+")
+_CPU_INFRA = frozenset({"Rendezvous"})   # collective-internal wait event,
+# already inside the enclosing psum/ppermute thunk interval
+# control-flow thunks ENCLOSE their body's thunk events — a while-loop's
+# full span would blanket every collective inside it
+_CPU_CONTAINER_RE = re.compile(r"(while|call|conditional)(\.\d+)?")
+
+
+def _find_xplane(trace_dir: str) -> str:
+    """Newest .xplane.pb under a jax.profiler.trace output directory."""
+    cands = []
+    for root, _, files in os.walk(trace_dir):
+        for f in files:
+            if f.endswith(".xplane.pb"):
+                p = os.path.join(root, f)
+                cands.append((os.path.getmtime(p), p))
+    if not cands:
+        raise FileNotFoundError(f"no .xplane.pb under {trace_dir}")
+    return max(cands)[1]
+
+
+def _is_cpu_collective(base: str) -> bool:
+    """CPU thunk classifier: HLO collective names, plus bare jax-primitive
+    instruction names ("psum.7") matched on the full base name."""
+    n = base.lower()
+    return (any(m in n for m in _COLLECTIVE_MARKERS)
+            or _CPU_PRIMITIVE_RE.fullmatch(n) is not None)
+
+
+def _device_intervals(trace_dir: str) -> List[Dict]:
+    """Raw per-op intervals of a jax profiler trace: every device-plane
+    sync/async event as ``{"plane", "line", "name", "start_ns", "end_ns",
+    "cls"}`` — TPU device planes when the trace has them, the CPU
+    thunk-executor lines otherwise (virtual-mesh traces; capture with
+    ``ProfileOptions.host_tracer_level=3`` so per-op thunk events appear;
+    a collective thunk's interval INCLUDES its rendezvous wait)."""
+    from jax.profiler import ProfileData
+    data = ProfileData.from_file(_find_xplane(trace_dir))
+
+    def interval(plane, line, ev, name, is_async):
+        return {"plane": plane.name, "line": line.name, "name": name,
+                "start_ns": ev.start_ns,
+                "end_ns": ev.start_ns + ev.duration_ns,
+                "cls": "async" if is_async else "sync"}
+
+    out: List[Dict] = []
+    for plane in data.planes:
+        if "/device:" not in plane.name:
+            continue
+        for line in plane.lines:
+            if line.name not in ("XLA Ops", "Async XLA Ops"):
+                continue
+            for ev in line.events:
+                if ev.duration_ns:
+                    out.append(interval(plane, line, ev,
+                                        ev.name.split(" = ")[0],
+                                        line.name == "Async XLA Ops"))
+    if out:
+        return out
+    for plane in data.planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            if not line.name.startswith(_CPU_LINE_PREFIXES):
+                continue
+            for ev in line.events:
+                if (not _CPU_OP_RE.fullmatch(ev.name)
+                        or not ev.duration_ns
+                        or ev.name in _CPU_INFRA
+                        or _CPU_CONTAINER_RE.fullmatch(ev.name)):
+                    continue
+                out.append(interval(
+                    plane, line, ev, ev.name, _is_cpu_collective(
+                        ev.name.removeprefix("wrapped_"))))
+    return out
+
+
 def chrome_trace(host_events: Sequence[Dict[str, Any]],
                  device_intervals: Optional[Sequence[Dict[str, Any]]] = None,
                  anchor_span: str = DEFAULT_ANCHOR_SPAN,
                  header: Optional[Dict[str, Any]] = None) -> Dict[str, Any]:
     """Merge host events (obs.events snapshot/JSONL shape) and optional
-    device intervals (utils.trace_analysis.device_intervals shape) into
+    device intervals (the ``_device_intervals`` shape) into
     one Chrome-trace JSON object.  All timestamps are rebased to the
     earliest host event so the trace opens at t=0."""
     device_intervals = list(device_intervals or [])
@@ -219,8 +330,7 @@ def build(events_jsonl: Optional[str] = None,
         header, host_events = events_lib.read_jsonl(events_jsonl)
     device_intervals = None
     if trace_dir is not None:
-        from ..utils import trace_analysis
-        device_intervals = trace_analysis.device_intervals(trace_dir)
+        device_intervals = _device_intervals(trace_dir)
     return chrome_trace(host_events, device_intervals,
                         anchor_span=anchor_span, header=header)
 

@@ -300,8 +300,8 @@ def break_even(encode_gbps: float, decode_gbps: float,
                   "ratio includes the 8-row RDMA tile padding; the XLA "
                   "ring's unpadded ratio is wire_ratio_vs_f32)"),
         # False = every link rate below is a documented fallback
-        # constant, not a measurement (gen_perf_md badges such rows
-        # model-only; route rates through link_rate_candidates)
+        # constant, not a measurement (route rates through
+        # link_rate_candidates)
         "calibrated": bool(calibrated),
         "codec_rates_source": source,
         "encode_gbps": round(encode_gbps, 2),

@@ -174,7 +174,7 @@ def contiguous_cache_bytes(cfg: LlamaConfig, batch: int, max_seq: int, *,
                            tp_size: int = 1,
                            dtype: Optional[str] = None) -> int:
     """Exact bytes `init_cache` would allocate for the same concurrency —
-    the HBM cost the paged pool is measured against (docs/PERF.md)."""
+    the HBM cost the paged pool is measured against."""
     kv_local = llama_decode.kv_local_heads(cfg, tp_size)
     dt = jnp.dtype(dtype or cfg.dtype)
     return cfg.n_layers * 2 * batch * kv_local * max_seq \

@@ -35,7 +35,7 @@ plan):
   - a component with NO banked measurement falls back to the documented
     constants (`ops.ring_cost.DEFAULT_LINK_RATES` and the fallbacks
     below) and the calibration says so: ``calibrated=False`` for that
-    component, so `gen_perf_md` can badge model-only rows.
+    component.
 
 No jax import — calibration must load (and fail meaningfully) on a
 machine with no chip, exactly like tools/obs_gate.py.

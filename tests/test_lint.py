@@ -187,7 +187,7 @@ class TestTreeIsClean:
         for must in ("fpga_ai_nic_tpu/ops/ring.py",
                      "fpga_ai_nic_tpu/parallel/train.py",
                      "fpga_ai_nic_tpu/runtime/queue.py",
-                     "tools/multichip_bench.py", "bench_collective.py"):
+                     "tools/chaos_bench.py", "bench_collective.py"):
             assert must in targets, must
 
 
@@ -302,7 +302,7 @@ class TestJaxprSweep:
         import jax
         import jax.numpy as jnp
         from fpga_ai_nic_tpu.lint.jaxpr_sweep import _check_cell
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             jx = jax.make_jaxpr(
                 lambda x: x.astype(jnp.float64) * 2.0)(
                 jax.ShapeDtypeStruct((4,), "float32"))
@@ -357,6 +357,17 @@ class TestJaxprSweep:
             jax.ShapeDtypeStruct((64,), jnp.float32))
         c = _collect(jx.jaxpr)
         assert c["wire_unknown"] and c["wire_bytes"] == 0, c
+
+    def test_collect_reads_the_jit_calls_donation(self):
+        """J3's input: the step's donation mask, under the name the
+        installed jax gives the call primitive ("pjit", or "jit")."""
+        import jax
+        import jax.numpy as jnp
+        from fpga_ai_nic_tpu.lint.jaxpr_sweep import _collect
+
+        jx = jax.make_jaxpr(jax.jit(lambda x: x + 1, donate_argnums=0))(
+            jax.ShapeDtypeStruct((8,), jnp.float32))
+        assert _collect(jx.jaxpr)["donated"] == (True,)
 
     def test_j5_detects_foreign_axis(self):
         phases, L, n = self._dp_phases(codec="bfp")

@@ -359,12 +359,62 @@ def test_obs_gate_cli_exit_codes(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# trace-analysis CLI (device-plane attribution without writing code)
+# the timeline's XPlane helpers (the program's only trace reader)
 # ---------------------------------------------------------------------------
 
-def test_trace_analysis_cli_error_path():
-    from fpga_ai_nic_tpu.utils import trace_analysis as ta
-    assert ta.main(["/nonexistent-trace-dir"]) == 1
+def test_find_xplane_missing_raises(tmp_path):
+    with pytest.raises(FileNotFoundError):
+        timeline._find_xplane(str(tmp_path))
+
+
+def test_cpu_thunk_classification_is_word_scoped():
+    # bare primitive instruction names (with XLA's .uid) classify
+    assert timeline._is_cpu_collective("psum.7")
+    assert timeline._is_cpu_collective("ppermute")
+    assert timeline._is_cpu_collective("all_gather.12")
+    # hyphenated HLO names still classify on the CPU path too
+    assert timeline._is_cpu_collective("all-reduce-start.1")
+    # but a name that merely CONTAINS a primitive does not
+    assert not timeline._is_cpu_collective("psum_invariant_fusion.3")
+    assert not timeline._is_cpu_collective("my_psum")
+    assert not timeline._is_cpu_collective("broadcast_add_fusion")
+
+
+def test_cpu_thunk_trace_attributes_collectives(tmp_path):
+    """A REAL collective, traced and read back as intervals: the 8-device
+    mesh's psum thunks must come out `async`, the tanh compute on the
+    shards' executor threads `sync`, one line per shard at least."""
+    from jax import lax
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    if not hasattr(jax.profiler, "ProfileOptions"):
+        pytest.skip("this jaxlib has no jax.profiler.ProfileOptions "
+                    "(host_tracer_level is not settable)")
+    mesh = Mesh(np.array(jax.devices()), ("dp",))
+    f = jax.jit(jax.shard_map(
+        lambda v: lax.psum(jnp.tanh(lax.pcast(v, "dp", to="varying")), "dp"),
+        mesh=mesh, in_specs=P(), out_specs=P(), check_vma=False))
+    x = jnp.ones((8, 1 << 18), jnp.float32)
+    f(x).block_until_ready()                   # compile outside the trace
+    opts = jax.profiler.ProfileOptions()
+    opts.host_tracer_level = 3                 # per-op thunk events
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    for _ in range(3):
+        f(x).block_until_ready()
+    jax.profiler.stop_trace()
+
+    ivs = timeline._device_intervals(str(tmp_path))
+    by_cls = {c: [iv for iv in ivs if iv["cls"] == c]
+              for c in ("async", "sync")}
+    assert len(by_cls["async"]) + len(by_cls["sync"]) == len(ivs)
+    assert by_cls["async"] and by_cls["sync"], {
+        c: len(v) for c, v in by_cls.items()}
+    # three calls on eight shards: every psum thunk, and nothing else
+    assert {iv["name"].split(".")[0] for iv in by_cls["async"]} == {"psum"}
+    assert len(by_cls["async"]) == 3 * 8
+    assert any("tanh" in iv["name"] for iv in by_cls["sync"])
+    assert all(iv["end_ns"] > iv["start_ns"] for iv in ivs)
+    assert len({iv["line"] for iv in ivs}) >= 8   # one line per shard thread
 
 
 # ---------------------------------------------------------------------------

@@ -614,8 +614,7 @@ def main(argv=None) -> int:
                          "unless gating also fails")
     ap.add_argument("--save-artifact", action="store_true",
                     help="bank the summary + verdict under artifacts/ "
-                         "(obs_summary_*.json, rendered into docs/PERF.md "
-                         "by tools/gen_perf_md.py)")
+                         "(obs_summary_*.json)")
     ap.add_argument("--threshold-scale", type=float, default=1.0,
                     help="multiply every per-metric tolerance (e.g. 0.5 "
                          "for a stricter manual check)")

@@ -12,7 +12,7 @@ latency CURVE a serving SLO is negotiated on.  Per row the bench banks:
   - EXACT byte accounting: the paged pool + page table vs what
     `init_cache` would zero-fill up front for the same concurrency at
     max_seq — the measured version of the `[B, kv, max_seq, hd]`
-    up-front HBM cost documented in docs/PERF.md
+    up-front HBM cost
   - pool utilization (peak pages in use / usable pages) and evictions
   - ``recompiles_steady`` — MUST be 0: the whole schedule (admissions,
     evictions, page churn) runs on the warmup traces (graftlint J10)
@@ -560,7 +560,7 @@ def main() -> int:
         "rows": rows,
         # the init_cache comparison at the curve's top concurrency: what
         # the contiguous [B, kv, max_seq, hd] zero-fill would cost vs
-        # the shared pool actually allocated (docs/PERF.md "Serving")
+        # the shared pool actually allocated
         "init_cache_comparison": {
             "max_reqs": top["max_reqs"],
             "contiguous_cache_bytes": top["contiguous_cache_bytes"],
@@ -572,8 +572,7 @@ def main() -> int:
     }
     # the kernel axis at the curve's top concurrency: the modeled
     # decode roofline of the gathered view vs the paged kernel — the
-    # numbers obs-gate pins exactly (serve.attend.*) and docs/PERF.md's
-    # decode roofline table reports
+    # numbers obs-gate pins exactly (serve.attend.*)
     by = {(r["max_reqs"], r["attend_impl"]): r["decode_roofline"]
           for r in rows}
     c_top = CONCURRENCIES[len(CONCURRENCIES) - 1]
