@@ -23,6 +23,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..ops.ring_attention import flash_attention_remat
+
 _NEG = jnp.float32(-1e30)
 
 
@@ -37,9 +39,10 @@ class BertConfig:
     pad_id: int = 0
     norm_eps: float = 1e-12
     dtype: str = "bfloat16"
-    # attention backend: "auto" = the fused Pallas flash kernels on TPU
-    # when shapes tile (padding mask rides the kernels' key_bias
-    # channel), XLA softmax elsewhere; "pallas"/"xla" pin for A/B
+    # attention backend, ops.ring_attention.flash_attention_remat's `impl`
+    # as in every model: "xla" = the blocked route (a score block at a time,
+    # hand-written backward), "pallas" = the fused flash kernels, "auto" =
+    # those on TPU when shapes tile; the padding mask is `key_bias` in both
     attn_impl: str = "auto"
 
     @property
@@ -116,12 +119,7 @@ def encode(params: Dict, tokens: jax.Array, cfg: BertConfig,
     H, Hd = cfg.n_heads, cfg.head_dim
     if attention_mask is None:
         attention_mask = tokens != cfg.pad_id
-    mask_bool = attention_mask.astype(bool)              # [B, S]
-    key_bias2d = jnp.where(mask_bool, jnp.float32(0), _NEG)      # [B, S]
-    from ..ops.ring_attention import pallas_route
-    use_flash = pallas_route(cfg.attn_impl, (B, H, S, Hd))
-    if not use_flash:
-        key_bias = key_bias2d[:, None, None, :]          # [B, 1, 1, S]
+    key_bias = jnp.where(attention_mask.astype(bool), jnp.float32(0), _NEG)
 
     pos = lax.broadcasted_iota(jnp.int32, (S, 1), 0)[:, 0]
     x = params["tok_emb"][tokens] + params["pos_emb"][pos]
@@ -132,18 +130,10 @@ def encode(params: Dict, tokens: jax.Array, cfg: BertConfig,
         q = (x @ lyr["wq"]).reshape(B, S, H, Hd).transpose(0, 2, 1, 3)
         k = (x @ lyr["wk"]).reshape(B, S, H, Hd).transpose(0, 2, 1, 3)
         v = (x @ lyr["wv"]).reshape(B, S, H, Hd).transpose(0, 2, 1, 3)
-        if use_flash:
-            # the padding mask rides the fused kernels' key_bias channel
-            from ..ops import flash_pallas
-            att = flash_pallas.flash_attention(
-                q, k, v, causal=False, sm_scale=scale,
-                key_bias=key_bias2d)
-        else:
-            s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
-                           preferred_element_type=jnp.float32) * scale
-            p = jax.nn.softmax(s + key_bias, axis=-1)
-            att = jnp.einsum("bhqk,bhkd->bhqd", p, v.astype(jnp.float32))
-        att = att.astype(x.dtype).transpose(0, 2, 1, 3).reshape(B, S, -1)
+        # the padding mask rides the route's bias over the keys [B, S]
+        att = flash_attention_remat(q, k, v, causal=False, sm_scale=scale,
+                                    impl=cfg.attn_impl, key_bias=key_bias)
+        att = att.transpose(0, 2, 1, 3).reshape(B, S, -1)
         x = _layernorm(x + att @ lyr["wo"], lyr["attn_norm"], cfg.norm_eps)
 
         h = jax.nn.gelu((x @ lyr["w1"]).astype(jnp.float32)).astype(x.dtype)

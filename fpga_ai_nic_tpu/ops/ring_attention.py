@@ -65,24 +65,41 @@ def _finish(o, l, out_dtype):
     return (o / jnp.where(l == 0, 1.0, l)).astype(out_dtype)
 
 
-def _scores(q, k, q_pos, k_pos, sm_scale, causal):
+def _scores(q, k, q_pos, k_pos, sm_scale, causal, bias=None):
     """Scaled float32 scores [B,H,Sq,Sk] of one block, _NEG where a key
-    lies after its query."""
+    lies after its query.  bias: `_bias_block`'s pair, added over the keys."""
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
                    preferred_element_type=jnp.float32) * sm_scale
     if causal:
         mask = k_pos[None, :] > q_pos[:, None]           # [Sq, Sk]
         s = jnp.where(mask[None, None], _NEG, s)
+    if bias is not None:
+        # the sum rounds as the plain softmax(s + bias) rounds it; taking
+        # the sequence's largest bias off afterwards leaves every row's
+        # softmax as it is and keeps `lse` of a sequence whose keys are
+        # all masked (bias -1e30) in a range where log l still counts
+        s = (s + bias[0]) - bias[1]
     return s
 
 
-def _block_attend(q, k, v, q_pos, k_pos, m, l, o, sm_scale, causal):
+def _bias_block(key_bias, top, j, n):
+    """`_scores`' bias: block j of n keys of a [B, Sk] bias and `top`, each
+    sequence's largest value [B], both to broadcast against [B,H,Sq,n]
+    scores; None where there is no bias."""
+    if key_bias is None:
+        return None
+    return (lax.dynamic_slice_in_dim(key_bias, j * n, n, axis=1)[:, None, None],
+            top[:, None, None, None])
+
+
+def _block_attend(q, k, v, q_pos, k_pos, m, l, o, sm_scale, causal,
+                  bias=None):
     """One online-softmax accumulation step against a visiting K/V block.
 
     q: [B,H,Sq,dh]; k,v: [B,H,Sk,dh]; positions: [Sq]/[Sk];
     m,l: [B,H,Sq,1] running max / normalizer; o: [B,H,Sq,dh] running output.
     """
-    s = _scores(q, k, q_pos, k_pos, sm_scale, causal)
+    s = _scores(q, k, q_pos, k_pos, sm_scale, causal, bias)
     m_blk = jnp.max(s, axis=-1, keepdims=True)           # [B,H,Sq,1]
     m_new = jnp.maximum(m, m_blk)
     alpha = jnp.exp(m - m_new)                           # rescale old state
@@ -253,8 +270,9 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array, axis_name: str,
     return _finish(o, l, q.dtype)
 
 
-def full_attention(q, k, v, *, causal=True, sm_scale=None):
-    """Unsharded reference implementation (the golden model for tests)."""
+def full_attention(q, k, v, *, causal=True, sm_scale=None, key_bias=None):
+    """Unsharded reference implementation (the golden model for tests);
+    key_bias [B, Sk] float32 is added to every query's scores."""
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
     s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
@@ -264,6 +282,8 @@ def full_attention(q, k, v, *, causal=True, sm_scale=None):
     if causal:
         pos = _iota(S)
         s = jnp.where((pos[None, :] > pos[:, None])[None, None], _NEG, s)
+    if key_bias is not None:
+        s = s + key_bias[:, None, None, :]
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", p, v.astype(jnp.float32)
                       ).astype(q.dtype)
@@ -271,28 +291,33 @@ def full_attention(q, k, v, *, causal=True, sm_scale=None):
 
 def flash_attention_remat(q, k, v, *, causal=True, sm_scale=None,
                           k_block: Optional[int] = 512, impl: str = "auto",
-                          q_offset=0):
+                          q_offset=0, key_bias=None):
     """Memory-bounded exact attention for model code, over the whole
-    sequence's queries.  Both backends have one contract: the forward gives
-    `out` and `lse = m + log l`, the hand-written backward recomputes p
-    from `lse`, so residual memory is O(S) and no ``jax.checkpoint``
-    wrapper is needed (one would only run the forward again).
+    sequence's queries: what every model's `attn_impl` selects.  Both
+    backends have one contract: the forward gives `out` and
+    `lse = m + log l`, the hand-written backward recomputes p from `lse`,
+    so residual memory is O(S) and no ``jax.checkpoint`` wrapper is needed
+    (one would only run the forward again).
 
     - ``pallas`` (auto on TPU when shapes tile): the fused
       ops.flash_pallas kernels.
     - ``xla`` (auto off-TPU / odd shapes): `flash_attention` below.
 
     `q_offset` is the position of q's first row among the keys, for a
-    caller that holds a shard of a causal sequence's queries."""
+    caller that holds a shard of a causal sequence's queries.  `key_bias`
+    [B, Sk] float32 is added to every query's scores over the keys (a
+    padding mask: 0 to attend, -1e30 not to); it has no gradient."""
     from . import flash_pallas
     if pallas_route(impl, q, kv_seq_len=k.shape[2]):
         b = k_block or flash_pallas._DEF_BLOCK
         return flash_pallas.flash_attention(q, k, v, causal=causal,
                                             sm_scale=sm_scale,
                                             q_offset=q_offset,
-                                            block_q=b, block_k=b)
+                                            block_q=b, block_k=b,
+                                            key_bias=key_bias)
     return flash_attention(q, k, v, causal=causal, sm_scale=sm_scale,
-                           k_block=k_block, q_offset=q_offset)
+                           k_block=k_block, q_offset=q_offset,
+                           key_bias=key_bias)
 
 
 def gathered_attention(q, k, v, axis_name: str, *, causal=True,
@@ -355,103 +380,186 @@ def _blocking(q, k, off, block, causal):
     return qb, kb, q.shape[2] // qb, visible
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
-def _blocked(q, k, v, off, sm_scale, causal, block):
-    return _blocked_fwd(q, k, v, off, sm_scale, causal, block)[0]
+# The most float32 scores one block [b, H, qb, kb] may hold, in bytes: what
+# XLA keeps in the v5e's fast memory (`S(1)` in the compiled step) with the
+# products, the mask, exp and the row sums fused around it.  A batch whose
+# block would be larger goes through in equal groups of sequences, one after
+# the other.  Measured on the chip (PERF.md, PR 31 and 33): the GLM cell's
+# f32[2,20,512,512], 40 MiB, is one group; BERT-Base at S = 512 (12 MiB a
+# sequence) took 1.29 / 1.21 / 2.02 / 4.22 ms a layer, forward and
+# backward, in blocks of 24 / 48 / 96 / 192 MiB against 5.62 ms with the
+# scores of all 32 sequences at once (403 MB).
+SCORE_BLOCK_BYTES = 48 * 2 ** 20
 
 
-def _blocked_fwd(q, k, v, off, sm_scale, causal, block):
-    B, H, _, dh = q.shape
+def _group(B, H, qb, kb):
+    """Sequences a group: the largest divisor of B whose float32 score
+    block [b, H, qb, kb] stays within SCORE_BLOCK_BYTES (one sequence where
+    none does)."""
+    return _fit_block(B, max(1, SCORE_BLOCK_BYTES // (4 * H * qb * kb)))
+
+
+def _in_groups(fn, b, *xs):
+    """fn(*xs) over the leading (batch) axis in groups of b sequences, the
+    results joined again; one group is one plain call.  An x may be None."""
+    B = xs[0].shape[0]
+    if b == B:
+        return fn(*xs)
+    out = lax.map(lambda g: fn(*g), jax.tree_util.tree_map(
+        lambda x: x.reshape(B // b, b, *x.shape[1:]), xs))
+    return jax.tree_util.tree_map(
+        lambda y: y.reshape(B, *y.shape[2:]), out)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _blocked(q, k, v, off, key_bias, sm_scale, causal, block):
+    return _blocked_fwd(q, k, v, off, key_bias, sm_scale, causal, block)[0]
+
+
+def _vma(*xs):
+    """The manual mesh axes any of xs (None: no array) varies over."""
+    return set().union(*(jax.typeof(x).vma for x in xs if x is not None))
+
+
+# The forward and the backward below are jitted on their own: a model that
+# calls the route once a layer from a Python loop (BERT: 12 times) then
+# traces and lowers each once for all its layers, and XLA inlines the calls
+# (traced per layer, BERT's warm set-up took 9 s longer: PERF.md, PR 33).
+# `b`, the sequences a group, is static with the rest of the blocking.
+@functools.partial(jax.jit, static_argnums=(5, 6, 7, 8))
+def _fwd_groups(q, k, v, off, key_bias, sm_scale, causal, block, b):
     qb, kb, nq, visible = _blocking(q, k, off, block, causal)
-    vma = set().union(*(jax.typeof(x).vma for x in (q, k, v, off)))
+    vma = _vma(q, k, v, off, key_bias)
 
-    def chunk(i):
-        qf = _rows(q, i, qb).astype(jnp.float32)
-        q_pos = off + i * qb + _iota(qb)
+    def group(q, k, v, key_bias):
+        B, H, _, dh = q.shape
+        top = None if key_bias is None else jnp.max(key_bias, axis=1)
 
-        def attend(j, mlo):
-            return _block_attend(
-                qf, _rows(k, j, kb).astype(jnp.float32), _rows(v, j, kb),
-                q_pos, j * kb + _iota(kb), *mlo, sm_scale, causal)
+        def chunk(i):
+            qf = _rows(q, i, qb).astype(jnp.float32)
+            q_pos = off + i * qb + _iota(qb)
 
-        m, l, o = lax.fori_loop(0, visible(i), attend,
-                                _init_acc(B, H, qb, dh, vma))
-        return _finish(o, l, q.dtype), m + jnp.log(jnp.where(l == 0, 1.0, l))
+            def attend(j, mlo):
+                return _block_attend(
+                    qf, _rows(k, j, kb).astype(jnp.float32), _rows(v, j, kb),
+                    q_pos, j * kb + _iota(kb), *mlo, sm_scale, causal,
+                    _bias_block(key_bias, top, j, kb))
 
-    # the chunks stacked and joined: written into the whole in place, the
-    # output cost 1.2 ms a layer more on the v5e (PERF.md, PR 31)
+            m, l, o = lax.fori_loop(0, visible(i), attend,
+                                    _init_acc(B, H, qb, dh, vma))
+            return (_finish(o, l, q.dtype),
+                    m + jnp.log(jnp.where(l == 0, 1.0, l)))
+
+        # the chunks stacked and joined: written into the whole in place,
+        # the output cost 1.2 ms a layer more on the v5e (PERF.md, PR 31)
+        return tuple(map(_join, lax.map(chunk, jnp.arange(nq))))
+
+    return _in_groups(group, b, q, k, v, key_bias)
+
+
+def _blocked_fwd(q, k, v, off, key_bias, sm_scale, causal, block):
+    qb, kb, _, _ = _blocking(q, k, off, block, causal)
     with scope("ainic.attn.fwd"):
-        out, lse = map(_join, lax.map(chunk, jnp.arange(nq)))
+        out, lse = _fwd_groups(q, k, v, off, key_bias, sm_scale, causal,
+                               block, _group(*q.shape[:2], qb, kb))
     out, lse = checkpoint_name(out, SAVED), checkpoint_name(lse, SAVED)
-    return out, (q, k, v, off, out, lse)
+    return out, (q, k, v, off, key_bias, out, lse)
 
 
-def _blocked_bwd(sm_scale, causal, block, res, do):
+@functools.partial(jax.jit, static_argnums=(8, 9, 10, 11))
+def _bwd_groups(q, k, v, off, key_bias, out, lse, do, sm_scale, causal,
+                block, b):
     """The flash-attention backward: with p = exp(s - lse) recomputed a
     block at a time, ds = p * (dp - delta) * scale where dp = dO v^T and
-    delta = rowsum(dO * O); one float32 dK and one dV for all the keys,
-    each block's rows added in place."""
-    q, k, v, off, out, lse = res
-    B, H, _, dh = q.shape
+    delta = rowsum(dO * O); one float32 dK and one dV for all the keys of a
+    group, each block's rows added in place.  The bias has no gradient."""
     qb, kb, nq, visible = _blocking(q, k, off, block, causal)
-    vma = set().union(*(jax.typeof(x).vma for x in (q, k, v, off, do)))
+    vma = _vma(q, k, v, off, key_bias, do)
 
     def add_rows(acc, j, part):
         return lax.dynamic_update_slice_in_dim(
             acc, _rows(acc, j, kb) + part, j * kb, axis=2)
 
-    def chunk(dkv, i):
-        qf = _rows(q, i, qb).astype(jnp.float32)
-        dof = _rows(do, i, qb).astype(jnp.float32)
-        lse_i, delta_i = _rows(lse, i, qb), _rows(delta, i, qb)
-        q_pos = off + i * qb + _iota(qb)
+    def group(q, k, v, key_bias, out, lse, do):
+        B, H, _, dh = q.shape
+        top = None if key_bias is None else jnp.max(key_bias, axis=1)
 
-        def block_grads(j, acc):
-            dq, dk, dv = acc
-            kf = _rows(k, j, kb).astype(jnp.float32)
-            vf = _rows(v, j, kb).astype(jnp.float32)
-            p = jnp.exp(_scores(qf, kf, q_pos, j * kb + _iota(kb), sm_scale,
-                                causal) - lse_i)
-            dp = jnp.einsum("bhqd,bhkd->bhqk", dof, vf,
-                            preferred_element_type=jnp.float32)
-            ds = p * (dp - delta_i) * sm_scale
-            dq = dq + jnp.einsum("bhqk,bhkd->bhqd", ds, kf,
-                                 preferred_element_type=jnp.float32)
-            dk = add_rows(dk, j, jnp.einsum(
-                "bhqk,bhqd->bhkd", ds, qf,
-                preferred_element_type=jnp.float32))
-            dv = add_rows(dv, j, jnp.einsum(
-                "bhqk,bhqd->bhkd", p, dof,
-                preferred_element_type=jnp.float32))
-            return dq, dk, dv
+        def chunk(dkv, i):
+            qf = _rows(q, i, qb).astype(jnp.float32)
+            dof = _rows(do, i, qb).astype(jnp.float32)
+            lse_i, delta_i = _rows(lse, i, qb), _rows(delta, i, qb)
+            q_pos = off + i * qb + _iota(qb)
 
-        dq0 = _varying(jnp.zeros((B, H, qb, dh), jnp.float32), vma)
-        dq, dk, dv = lax.fori_loop(0, visible(i), block_grads, (dq0, *dkv))
-        return (dk, dv), dq.astype(q.dtype)
+            def block_grads(j, acc):
+                dq, dk, dv = acc
+                kf = _rows(k, j, kb).astype(jnp.float32)
+                vf = _rows(v, j, kb).astype(jnp.float32)
+                p = jnp.exp(_scores(
+                    qf, kf, q_pos, j * kb + _iota(kb), sm_scale, causal,
+                    _bias_block(key_bias, top, j, kb)) - lse_i)
+                dp = jnp.einsum("bhqd,bhkd->bhqk", dof, vf,
+                                preferred_element_type=jnp.float32)
+                ds = p * (dp - delta_i) * sm_scale
+                dq = dq + jnp.einsum("bhqk,bhkd->bhqd", ds, kf,
+                                     preferred_element_type=jnp.float32)
+                dk = add_rows(dk, j, jnp.einsum(
+                    "bhqk,bhqd->bhkd", ds, qf,
+                    preferred_element_type=jnp.float32))
+                dv = add_rows(dv, j, jnp.einsum(
+                    "bhqk,bhqd->bhkd", p, dof,
+                    preferred_element_type=jnp.float32))
+                return dq, dk, dv
 
-    with scope("ainic.attn.bwd"):
+            dq0 = _varying(jnp.zeros((B, H, qb, dh), jnp.float32), vma)
+            dq, dk, dv = lax.fori_loop(0, visible(i), block_grads,
+                                       (dq0, *dkv))
+            return (dk, dv), dq.astype(q.dtype)
+
         delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                         axis=-1, keepdims=True)
         zeros = _varying(jnp.zeros(k.shape, jnp.float32), vma)
         (dk, dv), dq = lax.scan(chunk, (zeros, zeros), jnp.arange(nq))
-    return _join(dq), dk.astype(k.dtype), dv.astype(v.dtype), None
+        return _join(dq), dk.astype(k.dtype), dv.astype(v.dtype)
+
+    return _in_groups(group, b, q, k, v, key_bias, out, lse, do)
+
+
+def _blocked_bwd(sm_scale, causal, block, res, do):
+    q, k, v, off, key_bias, out, lse = res
+    qb, kb, _, _ = _blocking(q, k, off, block, causal)
+    with scope("ainic.attn.bwd"):
+        dq, dk, dv = _bwd_groups(q, k, v, off, key_bias, out, lse, do,
+                                 sm_scale, causal, block,
+                                 _group(*q.shape[:2], qb, kb))
+    return dq, dk, dv, None, None
 
 
 _blocked.defvjp(_blocked_fwd, _blocked_bwd)
 
 
 def flash_attention(q, k, v, *, causal=True, sm_scale=None,
-                    k_block: Optional[int] = 512, q_offset=0):
+                    k_block: Optional[int] = 512, q_offset=0, key_bias=None):
     """Single-device flash-blocked exact attention, the XLA route: queries
     and keys in blocks of `k_block` rows (its largest divisor of each
     length; None: whole), the same `_block_attend` online softmax the
     ring/gathered variants use, over exactly the blocks a causal mask
-    leaves — peak score memory one [B, H, k_block, k_block] block instead of
+    leaves — peak score memory one [b, H, k_block, k_block] block instead of
     full_attention's O(S^2) float32 score matrix, forward and backward.
+    b follows from the shape (`_group`): the whole batch where its block
+    fits SCORE_BLOCK_BYTES, else the largest divisor of B that does, the
+    groups of b sequences one after the other.
     Differences from full_attention are float32 summation order only; both
     are exact softmax attention.  q may be a shard of the sequence's
-    queries: its first row is key position `q_offset` (traced or not)."""
+    queries: its first row is key position `q_offset` (traced or not).
+
+    key_bias [B, Sk] float32 is added to the scores over the keys, each k
+    block's slice to that block's, forward and in the backward's recomputed
+    p; the answer is softmax(s + key_bias)'s, also for a sequence whose
+    keys are all masked (-1e30: that softmax is uniform).  It has no
+    gradient, and None adds nothing to the program."""
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
-    return _blocked(q, k, v, jnp.asarray(q_offset, jnp.int32), sm_scale,
-                    causal, k_block)
+    if key_bias is not None:
+        key_bias = key_bias.astype(jnp.float32)
+    return _blocked(q, k, v, jnp.asarray(q_offset, jnp.int32), key_bias,
+                    sm_scale, causal, k_block)

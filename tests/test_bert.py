@@ -255,6 +255,18 @@ def _rel_err(got, want):
     return jax.tree_util.tree_map(one, got, want)
 
 
+def _assert_no_further_from_float32(got_l, got_g, want_l, want_g, true_l,
+                                    true_g):
+    """Two bfloat16 roundings of one formula held against it in float32:
+    ours may be no further from the truth than half again theirs."""
+    assert abs(float(got_l) - float(true_l)) <= max(
+        abs(float(want_l) - float(true_l)), 1e-3 * float(true_l))
+    ours, theirs = _rel_err(got_g, true_g), _rel_err(want_g, true_g)
+    jax.tree_util.tree_map(
+        lambda a, b: np.testing.assert_array_less(a, 1.5 * b + 3e-3),
+        ours, theirs)
+
+
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("n", [4, 5], ids=["T=2blocks", "T=2.5blocks"])
 @pytest.mark.parametrize("share", ["one", 0.15, 0.5, 1.0, 0.0],
@@ -293,12 +305,8 @@ def test_blockwise_loss_and_gradients_match_the_plain_formula(
         return
     true_l, true_g = plain(jax.tree_util.tree_map(
         lambda x: x.astype(jnp.float32), params), batch, cfg32)
-    assert abs(float(got_l) - float(true_l)) <= max(
-        abs(float(want_l) - float(true_l)), 1e-3 * float(true_l))
-    ours, theirs = _rel_err(got_g, true_g), _rel_err(want_g, true_g)
-    jax.tree_util.tree_map(
-        lambda a, b: np.testing.assert_array_less(a, 1.5 * b + 3e-3),
-        ours, theirs)
+    _assert_no_further_from_float32(got_l, got_g, want_l, want_g, true_l,
+                                    true_g)
 
 
 @pytest.mark.parametrize("check_vma", [True, False])
@@ -395,3 +403,104 @@ def test_apply_is_the_head_on_the_encoder_at_every_position(rng):
     np.testing.assert_allclose(np.asarray(rows),
                                np.asarray(logits).reshape(-1, MCFG.vocab)[5:9],
                                atol=1e-5)
+
+
+# -- attention is one call of the blocked route (PR 33) ----------------------
+
+def _plain_attention(q, k, v, *, causal, sm_scale, impl, key_bias):
+    """What `encode` did itself before it called the route, kept as the
+    golden: every score of the batch at once, float32[B, H, S, S]."""
+    assert not causal
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
+                   preferred_element_type=jnp.float32) * sm_scale
+    p = jax.nn.softmax(s + key_bias[:, None, None, :], axis=-1)
+    return jnp.einsum("bhqk,bhkd->bhqd", p, v.astype(jnp.float32)
+                      ).astype(q.dtype)
+
+
+def _padded_batch(rng, cfg, n, S):
+    """An MLM batch whose sequences end in padded tails of unequal length
+    (one has none), 15% of the other positions masked."""
+    toks, labels = (np.asarray(x).copy()
+                    for x in _masked_batch(rng, cfg, n, S, 0.15))
+    for row, tail in enumerate(rng.integers(1, S // 2, n - 1)):
+        toks[row, S - tail:] = cfg.pad_id
+        labels[row, S - tail:] = -100
+    labels[:, 0] = toks[:, 0]                  # a target in every sequence
+    return jnp.asarray(toks), jnp.asarray(labels)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("S,budget", [(32, None), (64, None),
+                                      (64, 2 * 4 * 64 * 64 * 4)],
+                         ids=["S=32", "S=64", "S=64-two-groups"])
+def test_loss_and_gradients_match_the_plain_attention(rng, monkeypatch, S,
+                                                      budget, dtype):
+    """`bert.loss_fn` through the route against the same loss with the
+    plain einsum / softmax / einsum in the route's place, on a batch with
+    padded tails.  float32: 1e-5 on every leaf (the two differ by the
+    order of float32 sums).  bfloat16: both against the plain formula in
+    float32 on the same weights, the route's no further than half again
+    the plain one's distance."""
+    from fpga_ai_nic_tpu.ops import ring_attention as ra
+    if budget is not None:
+        monkeypatch.setattr(ra, "SCORE_BLOCK_BYTES", budget)
+    cfg = dataclasses.replace(MCFG, dtype=dtype)
+    params = bert.init(jax.random.PRNGKey(5), cfg)
+    batch = _padded_batch(rng, cfg, 4, S)
+
+    def value_and_grad(params, cfg):
+        return jax.jit(jax.value_and_grad(
+            lambda p, b: bert.loss_fn(p, b, cfg)))(params, batch)
+
+    got_l, got_g = value_and_grad(params, cfg)
+    monkeypatch.setattr(bert, "flash_attention_remat", _plain_attention)
+    want_l, want_g = value_and_grad(params, cfg)
+    for got, want in zip(jax.tree_util.tree_leaves(got_g),
+                         jax.tree_util.tree_leaves(want_g)):
+        assert got.dtype == want.dtype and got.shape == want.shape
+        assert np.all(np.isfinite(np.asarray(got, np.float32)))
+    if dtype == "float32":
+        np.testing.assert_allclose(float(got_l), float(want_l), rtol=1e-6)
+        errs = _rel_err(got_g, want_g)
+        assert max(jax.tree_util.tree_leaves(errs)) <= 1e-5, errs
+        return
+    true_l, true_g = value_and_grad(
+        jax.tree_util.tree_map(lambda x: x.astype(jnp.float32), params),
+        dataclasses.replace(cfg, dtype="float32"))
+    _assert_no_further_from_float32(got_l, got_g, want_l, want_g, true_l,
+                                    true_g)
+
+
+def test_no_array_of_every_score_in_the_gradient(rng, monkeypatch):
+    """B x H x S x S float32 over the route's budget: the plain attention's
+    gradient holds the scores of the whole batch, the route's holds a
+    group's block [b, H, S, S] and nothing of the whole's size."""
+    from fpga_ai_nic_tpu.ops import ring_attention as ra
+    B, S, H = 8, 32, MCFG.n_heads
+    monkeypatch.setattr(ra, "SCORE_BLOCK_BYTES", 2 * H * S * S * 4)
+    params = bert.init(jax.random.PRNGKey(6), MCFG)
+    batch = _padded_batch(rng, MCFG, B, S)
+
+    def lowered():
+        return jax.jit(jax.grad(
+            lambda p, b: bert.loss_fn(p, b, MCFG))).lower(
+                params, batch).as_text()
+
+    whole, block = (f"tensor<{b}x{H}x{S}x{S}xf32>" for b in (B, 2))
+    got = lowered()
+    assert block in got and whole not in got
+    monkeypatch.setattr(bert, "flash_attention_remat", _plain_attention)
+    assert whole in lowered()                   # the test can see them
+
+
+@pytest.mark.parametrize("scope", ["ainic.attn.fwd", "ainic.attn.bwd"])
+def test_lowered_gradient_holds_the_routes_scopes(rng, scope):
+    """The route's scopes stand in BERT's lowered gradient, `attn_impl`
+    pinned to "xla" as the benchmark's configuration pins it (under
+    jax.grad a location reads "transpose(jvp(ainic.attn.bwd))/mul")."""
+    cfg = dataclasses.replace(MCFG, attn_impl="xla")
+    params = bert.init(jax.random.PRNGKey(7), cfg)
+    text = jax.jit(jax.grad(lambda p, b: bert.loss_fn(p, b, cfg))).lower(
+        params, _padded_batch(rng, cfg, 4, 32)).as_text(debug_info=True)
+    assert re.search(r"[/\"(]%s[/)]" % re.escape(scope), text)

@@ -250,6 +250,16 @@ def _rel_l2(a, b):
     return float(np.sqrt(np.sum((a - b) ** 2) / np.sum(b ** 2)))
 
 
+def _out_and_grads(fn, q, k, v, w, **kw):
+    """(out, dQ, dK, dV) of the loss sum(fn(q, k, v, **kw) * w)."""
+    def loss(q, k, v):
+        out = fn(q, k, v, **kw)
+        return jnp.sum((out * w).astype(jnp.float32)), out
+    (_, out), grads = jax.jit(jax.value_and_grad(
+        loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+    return (out, *grads)
+
+
 @pytest.mark.parametrize(
     "Sq,Sk,k_block,q_offset,causal,dtype,reference,tol", [
         (16, 16, 16, 0, True, jnp.float32, _full_rows, 1e-5),
@@ -276,13 +286,8 @@ def test_xla_route_out_and_gradients(rng, Sq, Sk, k_block, q_offset, causal,
                   for S_ in (Sq, Sk, Sk, Sq))
 
     def run(fn):
-        def loss(q, k, v):
-            out = fn(q, k, v, causal=causal, k_block=k_block,
-                     q_offset=q_offset)
-            return jnp.sum((out * w).astype(jnp.float32)), out
-        (_, out), grads = jax.jit(jax.value_and_grad(
-            loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
-        return (out, *grads)
+        return _out_and_grads(fn, q, k, v, w, causal=causal, k_block=k_block,
+                              q_offset=q_offset)
 
     got = run(lambda *a, **kw: ra.flash_attention_remat(*a, impl="xla", **kw))
     for name, a, b in zip(("out", "dq", "dk", "dv"), got, run(reference)):
@@ -349,3 +354,155 @@ def test_layer_checkpoint_keeps_out_and_lse(policy, products):
     text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
         lyr, x).compile().as_text()
     assert len(re.findall(r"f32\[2,2,8,8\]\S* dot\(", text)) == products
+
+
+# -- the route's bias over the keys and its groups of sequences (PR 33) ------
+
+def _padding_bias(B, Sk, valid):
+    """[B, Sk] float32: 0 on each sequence's first valid[b] keys, -1e30 on
+    the padded tail (valid[b] == 0: every key of that sequence padded)."""
+    keep = np.arange(Sk)[None, :] < np.asarray(valid)[:, None]
+    return jnp.asarray(np.where(keep, 0.0, -1e30), jnp.float32)
+
+
+def _route(q, k, v, **kw):
+    return ra.flash_attention_remat(q, k, v, impl="xla", **kw)
+
+
+@pytest.mark.parametrize("S,k_block,causal,dtype,valid,budget,tol", [
+    (32, 32, False, jnp.float32, (20, 32, 7, 29), None, 1e-5),
+    (64, 16, False, jnp.float32, (50, 64, 1, 33), None, 1e-5),
+    (64, 16, True, jnp.float32, (50, 64, 17, 33), None, 1e-5),
+    (48, 10, True, jnp.float32, (48, 5, 40, 24), None, 1e-5),
+    (64, 16, False, jnp.bfloat16, (50, 64, 9, 33), None, 1e-2),
+    (64, 16, True, jnp.bfloat16, (50, 64, 9, 33), None, 1e-2),
+    (32, 32, False, jnp.float32, (20, 0, 32, 0), None, 1e-5),
+    (64, 16, True, jnp.float32, (0, 64, 0, 33), None, 1e-5),
+    (64, 16, False, jnp.float32, (50, 64, 0, 33), 2 * 3 * 16 * 16 * 4, 1e-5),
+    (64, 16, True, jnp.float32, (50, 64, 1, 33), 3 * 16 * 16 * 4, 1e-5),
+    (64, 16, True, jnp.bfloat16, (50, 64, 9, 33), 2 * 3 * 16 * 16 * 4, 1e-2),
+], ids=["one-block", "four-blocks", "four-blocks-causal",
+        "a-k_block-that-divides-nothing", "bfloat16", "bfloat16-causal",
+        "sequences-all-padding", "sequences-all-padding-causal",
+        "two-groups-of-two", "four-groups-of-one", "bfloat16-two-groups"])
+def test_xla_route_key_bias_out_and_gradients(rng, monkeypatch, S, k_block,
+                                              causal, dtype, valid, budget,
+                                              tol):
+    """out, dQ, dK and dV with a padding bias over the keys against
+    full_attention with the same bias added: softmax(s + bias), which for
+    a sequence whose keys are all padding is the uniform one.  `budget`
+    (bytes of one score block) makes the batch go through in groups."""
+    B, H, dh = 4, 3, 16
+    q, k, v, w = (jnp.asarray(rng.standard_normal((B, H, S, dh)), dtype)
+                  for _ in range(4))
+    bias = _padding_bias(B, S, valid)
+    if budget is not None:
+        monkeypatch.setattr(ra, "SCORE_BLOCK_BYTES", budget)
+        qb = ra._fit_block(S, k_block)
+        assert ra._group(B, H, qb, qb) == budget // (H * qb * qb * 4) < B
+    got = _out_and_grads(_route, q, k, v, w, causal=causal, k_block=k_block,
+                         key_bias=bias)
+    want = _out_and_grads(ra.full_attention, q, k, v, w, causal=causal,
+                          key_bias=bias)
+    for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
+        assert a.dtype == b.dtype == dtype and a.shape == b.shape, name
+        assert np.all(np.isfinite(np.asarray(a, np.float32))), name
+        assert _rel_l2(a, b) <= tol, (name, _rel_l2(a, b))
+
+
+@pytest.mark.parametrize("causal,biased", [(False, True), (True, True),
+                                           (True, False)])
+def test_xla_route_groups_equal_one_group(rng, monkeypatch, causal, biased):
+    """A batch whose B x H forces several groups gives what the same call
+    gives under a budget that takes it whole: the groups are the same
+    algorithm on fewer sequences at a time."""
+    B, H, S, dh = 6, 2, 32, 8
+    q, k, v, w = (jnp.asarray(rng.standard_normal((B, H, S, dh)),
+                              jnp.float32) for _ in range(4))
+    kw = dict(causal=causal, k_block=8,
+              key_bias=_padding_bias(B, S, (32, 20, 0, 9, 32, 1))
+              if biased else None)
+
+    def run(budget, groups):
+        monkeypatch.setattr(ra, "SCORE_BLOCK_BYTES", budget)
+        assert B // ra._group(B, H, 8, 8) == groups
+        return _out_and_grads(_route, q, k, v, w, **kw)
+
+    whole = run(B * H * 8 * 8 * 4, 1)
+    for budget, groups in ((3 * H * 8 * 8 * 4, 2), (5 * H * 8 * 8 * 4, 2),
+                           (2 * H * 8 * 8 * 4, 3), (1, 6)):
+        for name, a, b in zip(("out", "dq", "dk", "dv"),
+                              run(budget, groups), whole):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-6, atol=1e-6, err_msg=name)
+
+
+@pytest.mark.parametrize("shape,blocks,group", [
+    ((2, 20, 4096, 256), 512, 2),       # the GLM cell: one group, as PR 31
+    ((32, 12, 512, 64), 512, 4),        # bert-base-seq512: 12 MiB a sequence
+    ((128, 12, 128, 64), 512, 64),      # bert-base-seq128: 0.75 MiB
+    ((1, 32, 8192, 128), 512, 1),
+    ((3, 64, 2048, 128), 512, 1),       # no sequence fits: one at a time
+    ((6, 16, 1024, 64), 256, 6),
+], ids=["glm47-s4096", "bert-s512", "bert-s128", "one-sequence",
+        "over-budget-alone", "small-blocks"])
+def test_group_size_follows_from_the_shape(shape, blocks, group):
+    """The group is a function of (B, H, qb, kb) and the module's constant:
+    the largest divisor of B whose float32 score block stays inside it."""
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    qb, kb, _, _ = ra._blocking(x, x, 0, blocks, True)
+    b = ra._group(*shape[:2], qb, kb)
+    assert b == group and shape[0] % b == 0
+    assert b == 1 or b * shape[1] * qb * kb * 4 <= ra.SCORE_BLOCK_BYTES
+
+
+def _count_eqns(jaxpr, want):
+    """Equations of a jaxpr and of every jaxpr inside it that `want`
+    accepts."""
+    def inner(p):
+        if hasattr(p, "eqns"):
+            yield p
+        elif hasattr(p, "jaxpr"):
+            yield from inner(p.jaxpr)
+        elif isinstance(p, (tuple, list)):
+            for x in p:
+                yield from inner(x)
+
+    return sum(int(want(e)) + sum(_count_eqns(j, want)
+                                  for p in e.params.values()
+                                  for j in inner(p))
+               for e in jaxpr.eqns)
+
+
+def test_no_key_bias_adds_nothing_to_the_program():
+    """key_bias=None traces to the program the route had before it knew a
+    bias (the GLM cell and llama pass none; since PR 33 inside the jit
+    around the forward and the one around the backward): the same jaxpr
+    as a call that does not name the argument, one group at the GLM
+    cell's shape (no 5-D array: no outer loop), and no addition on a
+    block of scores; with a bias there are two, the forward's and the
+    recomputed p's."""
+    x = jax.ShapeDtypeStruct((2, 20, 4096, 256), jnp.bfloat16)
+    bias = jax.ShapeDtypeStruct((2, 4096), jnp.float32)
+
+    def grad(**kw):
+        return jax.make_jaxpr(jax.value_and_grad(
+            lambda q, k, v, *b: jnp.sum(ra.flash_attention_remat(
+                q, k, v, causal=True, k_block=512, impl="xla",
+                **(dict(kw, key_bias=b[0]) if b else kw)
+            ).astype(jnp.float32)), argnums=(0, 1, 2)))
+
+    none = grad(key_bias=None)(x, x, x)
+    assert str(none) == str(grad()(x, x, x))
+
+    def adds_on_scores(e):
+        return e.primitive.name == "add" and any(
+            v.aval.shape == (2, 20, 512, 512) for v in e.outvars)
+
+    def five_d(e):
+        return any(getattr(v.aval, "ndim", 0) == 5 and v.aval.shape[0] == 1
+                   for v in e.outvars)
+
+    assert _count_eqns(none.jaxpr, adds_on_scores) == 0
+    assert _count_eqns(none.jaxpr, five_d) == 0
+    assert _count_eqns(grad()(x, x, x, bias).jaxpr, adds_on_scores) == 2

@@ -314,6 +314,40 @@ def test_flash_kernels_are_named_where_the_compiler_speaks_of_them(chip):
             "attention.flash_dkv"}
 
 
+# -- the XLA attention route at the cells' shapes ----------------------------
+
+@pytest.mark.parametrize("shape,block,biased", [
+    ((32, 12, 512, 64), (4, 12, 512, 512), True),
+    ((128, 12, 128, 64), (64, 12, 128, 128), True),
+    ((2, 20, 4096, 256), (2, 20, 512, 512), False),
+], ids=["bert-base-seq512", "bert-base-seq128", "glm47-flash-s4096"])
+def test_xla_route_keeps_its_score_block_in_fast_memory(chip, shape, block,
+                                                        biased):
+    """What `ring_attention.SCORE_BLOCK_BYTES` is set for (PERF.md, PR 31
+    and 33): in the route's compiled gradient the float32 score block of
+    one group stands in the v5e's fast memory (`S(1)`), forward and
+    backward, and no float32 array holds the scores of the whole batch."""
+    from fpga_ai_nic_tpu.ops import ring_attention as ra
+    x = sds(shape, jnp.bfloat16, chip.one)
+    bias = sds((shape[0], shape[2]), jnp.float32, chip.one)
+    B, H, S, _ = shape
+
+    def grads(q, k, v, w, bias):
+        return jax.grad(lambda q, k, v: jnp.sum((ra.flash_attention_remat(
+            q, k, v, causal=not biased, impl="xla",
+            key_bias=bias if biased else None) * w).astype(jnp.float32)),
+            argnums=(0, 1, 2))(q, k, v)
+
+    text = compiled_text(grads, x, x, x, x, bias)
+    assert ra._group(B, H, *block[2:]) == block[0]
+    fast = re.findall(r"f32\[%d,%d,%d,%d\]\{[^}]*S\(1\)\}" % block, text)
+    for scoped in ("ainic.attn.fwd", "ainic.attn.bwd"):
+        assert any(scoped in line and "S(1)" in line
+                   for line in text.splitlines()), scoped
+    assert len(fast) >= 4, len(fast)
+    assert not re.search(r"f32\[%d,%d,%d,%d\]" % (B, H, S, S), text)
+
+
 # -- the semaphore bound, in plain Python ------------------------------------
 
 @pytest.mark.parametrize("mib", [1, 32, 160])
