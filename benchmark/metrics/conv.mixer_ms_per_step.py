@@ -1,0 +1,12 @@
+"""Device time per step of the gated short-convolution mixers' in-projection
+and gating, in ms: every operation that touches an array whose last
+dimension is three times the hidden size — W_in, the [B | C | x~] array it
+makes, the gates, the 3-tap causal filter, and the backward of each (class
+`conv` of op_classes/075-lfm2-moe.json).  W_out's product is not in it: it
+has the shapes of any 2048 x 2048 product.  Part of model.xla_ms_per_step."""
+
+
+def read(run):
+    if not run.trace:
+        return None
+    return run.trace.class_ms_per_step("conv")

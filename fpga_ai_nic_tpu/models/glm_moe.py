@@ -39,13 +39,9 @@ from jax import lax
 from ..obs.names import scope
 from ..ops import moe as moe_ops
 from ..ops import ring_attention
-from .llama import _rmsnorm, _rope, _token_nll
-
-
-# what a layer's checkpoint keeps beside its input: the attention route's
-# output and logsumexp, which are all its backward needs of its forward, so
-# that the layer's recompute does not run the attention again
-_KEEP = jax.checkpoint_policies.save_only_these_names(ring_attention.SAVED)
+from .decoder import KEEP as _KEEP
+from .decoder import head_nll, init_leaves as _init_leaves, next_token_loss
+from .llama import _rmsnorm, _rope
 
 
 @dataclass(frozen=True)
@@ -130,22 +126,6 @@ def _moe_shapes(cfg: GlmMoeConfig) -> Dict[str, Tuple[int, ...]]:
     if cfg.shared_expert:
         shapes.update(sw1=(D, F), sw3=(D, F), sw2=(F, D))
     return shapes
-
-
-def _init_leaves(key: jax.Array, shapes: Dict[str, Tuple[int, ...]],
-                 lead: Tuple[int, ...], dt) -> Dict:
-    """Norms at one; matrices normal with variance 1/fan_in (the dimension
-    before the last); the router stays float32 (ops/moe.py)."""
-    out = {}
-    for k, (name, shape) in zip(jax.random.split(key, len(shapes)),
-                                sorted(shapes.items())):
-        if name.endswith("norm"):
-            out[name] = jnp.ones(lead + shape, dt)
-        else:
-            w = (jax.random.normal(k, lead + shape, jnp.float32)
-                 * shape[-2] ** -0.5)
-            out[name] = w if name == "wr" else w.astype(dt)
-    return out
 
 
 def init(key: jax.Array, cfg: GlmMoeConfig) -> Dict:
@@ -251,42 +231,18 @@ def hidden(params: Dict, tokens: jax.Array, cfg: GlmMoeConfig,
     return (x, counts) if with_counts else x
 
 
-def _head_nll(params: Dict, x: jax.Array, safe: jax.Array,
-              cfg: GlmMoeConfig) -> jax.Array:
-    """Per-row negative log-likelihood of x [N, D] against labels [N]; the
-    logits are recomputed in the backward pass, so no [N, vocab] array is
-    kept for it."""
-    def block(xb, lb):
-        logits = _rmsnorm(xb, params["final_norm"],
-                          cfg.norm_eps) @ params["lm_head"]
-        return _token_nll(logits, lb, None)
-
-    return jax.checkpoint(block)(x, safe)
-
-
 def loss_fn(params: Dict, batch, cfg: GlmMoeConfig, *,
             dp_axis: Optional[str] = None) -> jax.Array:
     """Next-token cross-entropy.  batch = (tokens, labels), both [B, S];
     labels are the shifted targets, -100 where a position has none.
-
-    dp_axis: as in models.bert.loss_fn — the value is the global
-    token-weighted mean, and the gradient rides the local sum with the n_dp
-    factor that cancels the trainer's uniform /n_dp."""
+    dp_axis: `decoder.next_token_loss`."""
     tokens, labels = batch
     valid = (labels >= 0).reshape(-1)
     x = hidden(params, tokens, cfg)
-    nll = _head_nll(params, x.reshape(-1, x.shape[-1]),
-                    jnp.where(valid, labels.reshape(-1), 0), cfg)
-    local_sum = jnp.sum(jnp.where(valid, nll, 0.0))
-    count = jnp.sum(valid)
-    if dp_axis is None:
-        return local_sum / jnp.maximum(count, 1)
-    total = lax.psum(local_sum, dp_axis)
-    denom = lax.stop_gradient(
-        jnp.maximum(lax.psum(count, dp_axis), 1).astype(jnp.float32))
-    n_dp = lax.axis_size(dp_axis)
-    return lax.stop_gradient(total / denom) + (
-        n_dp * (local_sum - lax.stop_gradient(local_sum)) / denom)
+    nll = head_nll(params["final_norm"], params["lm_head"],
+                   x.reshape(-1, x.shape[-1]),
+                   jnp.where(valid, labels.reshape(-1), 0), cfg.norm_eps)
+    return next_token_loss(nll, valid, dp_axis=dp_axis)
 
 
 def routing_stats(params: Dict, batch, cfg: GlmMoeConfig) -> Dict:

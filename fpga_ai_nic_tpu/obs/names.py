@@ -75,6 +75,9 @@ SCOPES: Dict[str, str] = {
     "ainic.gather": "owned shard -> replicated parameters",
     # inside ainic.fwd_bwd, where the model has them (models/glm_moe.py)
     "ainic.mla": "latent attention: compressed q and k/v, rotary key, heads",
+    # likewise (models/lfm2_moe.py)
+    "ainic.conv": "gated short convolution: in-projection, gates, taps, out",
+    "ainic.gqa": "grouped-query attention: q/k/v, head norms, RoPE, output",
     # inside a model's attention (ops/ring_attention.flash_attention)
     "ainic.attn.fwd": "the XLA route's forward: out and lse, block by block",
     "ainic.attn.bwd": "its backward: p from lse again, dQ, one dK and dV",

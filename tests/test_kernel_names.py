@@ -258,7 +258,8 @@ def lowered_step(request):
 # the scopes a model brings into ainic.fwd_bwd; the others are the step's own
 MODEL_SCOPES = sorted(s for s in names.SCOPES
                       if s.startswith(("ainic.mla", "ainic.moe.",
-                                       "ainic.attn.")))
+                                       "ainic.attn.", "ainic.conv",
+                                       "ainic.gqa")))
 
 
 @pytest.mark.parametrize("scope", sorted(set(names.SCOPES)
@@ -289,18 +290,58 @@ def lowered_glm_step():
     return tr.step_fn.lower(state, batch).as_text(debug_info=True)
 
 
-def test_the_model_scopes_are_the_six_the_table_lists():
-    assert MODEL_SCOPES == ["ainic.attn.bwd", "ainic.attn.fwd", "ainic.mla",
-                            "ainic.moe.experts", "ainic.moe.route",
-                            "ainic.moe.shared"]
+@pytest.fixture(scope="module")
+def lowered_lfm2_step():
+    """DPTrainer's step for a tiny models/lfm2_moe.py, lowered as text."""
+    from fpga_ai_nic_tpu.models import lfm2_moe
+    from fpga_ai_nic_tpu.parallel import DPTrainer, make_mesh
+
+    mcfg = lfm2_moe.Lfm2MoeConfig.tiny(held=(0, 1, 2))
+    cfg = TrainConfig(
+        global_batch=2, mesh=MeshConfig(dp=2),
+        collective=CollectiveConfig(impl="ring", compression=BFPConfig(),
+                                    fused_optimizer=True),
+        optimizer=OptimizerConfig(kind="adamw", learning_rate=1e-3))
+    tr = DPTrainer(lambda p, b: lfm2_moe.loss_fn(p, b, mcfg, dp_axis="dp"),
+                   make_mesh(cfg.mesh, devices=jax.devices()[:2]), cfg)
+    state = tr.init_state(lfm2_moe.init(jax.random.PRNGKey(0), mcfg))
+    batch = tr.shard_batch((np.zeros((2, 16), np.int32),
+                            np.zeros((2, 16), np.int32)))
+    return tr.step_fn.lower(state, batch).as_text(debug_info=True)
 
 
-@pytest.mark.parametrize("scope", MODEL_SCOPES)
+# which model brings which scope into its step
+GLM_SCOPES = ["ainic.attn.bwd", "ainic.attn.fwd", "ainic.mla",
+              "ainic.moe.experts", "ainic.moe.route", "ainic.moe.shared"]
+LFM2_SCOPES = ["ainic.attn.bwd", "ainic.attn.fwd", "ainic.conv", "ainic.gqa",
+               "ainic.moe.experts", "ainic.moe.route"]
+
+
+def test_the_model_scopes_are_the_eight_the_table_lists():
+    assert MODEL_SCOPES == sorted(set(GLM_SCOPES) | set(LFM2_SCOPES))
+    assert len(MODEL_SCOPES) == 8
+
+
+@pytest.mark.parametrize("scope", GLM_SCOPES)
 def test_lowered_glm_step_holds_the_model_scope(lowered_glm_step, scope):
     """As the step's own: the scanned layer's body keeps its own locations
     ("ainic.moe.route/dot_general"), inside ainic.fwd_bwd's."""
     assert re.search(r"[/\"]%s/" % re.escape(scope), lowered_glm_step)
     assert re.search(r"[/\"]ainic\.fwd_bwd/", lowered_glm_step)
+
+
+@pytest.mark.parametrize("scope", LFM2_SCOPES)
+def test_lowered_lfm2_step_holds_the_model_scope(lowered_lfm2_step, scope):
+    assert re.search(r"[/\"]%s/" % re.escape(scope), lowered_lfm2_step)
+    assert re.search(r"[/\"]ainic\.fwd_bwd/", lowered_lfm2_step)
+
+
+@pytest.mark.parametrize("scope", sorted(set(MODEL_SCOPES)
+                                         - set(LFM2_SCOPES)))
+def test_lowered_lfm2_step_holds_no_scope_of_another_model(
+        lowered_lfm2_step, scope):
+    """No latent attention and no shared expert in this model."""
+    assert not re.search(r"[/\"]%s/" % re.escape(scope), lowered_lfm2_step)
 
 
 # -- the benchmark's rules read the names back -------------------------------
@@ -402,6 +443,17 @@ def test_a_rule_reads_the_external_kernels_from_the_table(layer):
     """The class of a kernel the compiler makes is told by the table's
     pattern, letter for letter: a name changed here or there fails."""
     assert names.external_kernel_regex(layer) in _rules_of(layer)
+
+
+@pytest.mark.parametrize("rule_file", ["075-lfm2-moe.json",
+                                       "08-glm-moe.json"])
+def test_each_expert_model_s_rule_file_holds_the_table_s_pattern(rule_file):
+    """075-lfm2-moe.json is asked before 08-glm-moe.json, so it repeats the
+    pattern; both hold it letter for letter."""
+    with open(os.path.join(ROOT, "benchmark", "op_classes", rule_file)) as f:
+        mine = [r["regex"] for r in json.load(f)["rules"]
+                if r["class"] == "moe"]
+    assert names.external_kernel_regex("moe") in mine
 
 
 @pytest.mark.parametrize("name", sorted(names.EXTERNAL_KERNELS))

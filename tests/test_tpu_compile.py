@@ -419,6 +419,74 @@ def test_dropless_expert_layer_compiles_to_grouped_kernels(chip):
     assert made == set(names.EXTERNAL_KERNELS)
 
 
+# -- LFM2's mixers and its expert layer at the benchmark cell's sizes ---------
+
+def _lfm2_layer(chip, mixer, ffn):
+    """(cfg, one layer's leaves as shapes, x [1, 8192, 2048] bf16)."""
+    from fpga_ai_nic_tpu.models import lfm2_moe
+    cfg = lfm2_moe.Lfm2MoeConfig(vocab=8192, held=tuple(range(8)),
+                                 layer_types=(mixer,), n_dense_layers=0)
+    lyr = {k: sds(shape, jnp.float32 if k in ("wr", "expert_bias")
+                  else jnp.bfloat16, chip.one)
+           for k, shape in lfm2_moe._layer_shapes(cfg, mixer, ffn).items()}
+    return cfg, lyr, sds((1, 8192, cfg.dim), jnp.bfloat16, chip.one)
+
+
+@pytest.mark.parametrize("mixer", ["conv", "full_attention"])
+def test_lfm2_mixers_compile_without_a_kernel_of_their_own(chip, mixer):
+    """The gated short convolution (W_in 2048 x 6144, three taps) and
+    grouped-query attention (32 heads over 8 at width 64, the route's blocks
+    of 512) at one 8,192-token sequence, forward and backward: XLA's own
+    fusions, no Mosaic kernel, and attention's score block in fast memory
+    (`S(1)`) as the other cells' is."""
+    from fpga_ai_nic_tpu.models import lfm2_moe
+    cfg, lyr, x = _lfm2_layer(chip, mixer, "dense")
+    pos = jnp.arange(8192, dtype=jnp.int32)
+
+    def grads(lyr, x):
+        def f(lyr, x):
+            y = (lfm2_moe.conv_mixer(lyr, x) if mixer == "conv"
+                 else lfm2_moe.gqa(lyr, x, pos, cfg))
+            return y.astype(jnp.float32).sum()
+        return jax.grad(f, argnums=(0, 1))(lyr, x)
+
+    text = compiled_text(grads, lyr, x)
+    assert "tpu_custom_call" not in text
+    if mixer == "conv":
+        assert re.search(r"bf16\[1,8192,6144\]", text)
+    else:
+        # one sequence a group: the compiler drops the group's unit axis
+        assert re.search(r"f32\[(?:1,)?32,512,512\]\{[^}]*S\(1\)\}", text)
+        assert re.search(r"bf16\[2048,3072\]", text)
+
+
+def test_lfm2_expert_layer_compiles_to_grouped_kernels(chip):
+    """`ops.moe.held_experts_ffn` as LFM2 calls it — a selection bias, no
+    shared expert — at 32,768 tokens: nine grouped products over 131,072
+    sorted rows, every Mosaic kernel one the table lists."""
+    from fpga_ai_nic_tpu.ops import moe
+    cfg, lyr, _ = _lfm2_layer(chip, "conv", "moe")
+    params = {k: lyr[k] for k in ("wr", "expert_bias", "w1", "w3", "w2")}
+
+    def grads(params, x):
+        return jax.grad(lambda p, y: moe.held_experts_ffn(
+            p, y, num_experts=64, top_k=4, held=cfg.held,
+            bias=jax.lax.stop_gradient(p["expert_bias"])
+        ).astype(jnp.float32).sum(), argnums=(0, 1))(params, x)
+
+    text = compiled_text(grads, params,
+                         sds((4, 8192, cfg.dim), jnp.bfloat16, chip.one))
+    products = re.findall(
+        r"^\s*(?:ROOT )?%ragged-dot-none[.\d]* = (\w+\[[\d,]+\])", text, re.M)
+    assert sorted(products) == sorted(
+        ["bf16[131072,1536]"] * 3 + ["bf16[131072,2048]"] * 3
+        + ["bf16[8,2048,1536]"] * 2 + ["bf16[8,1536,2048]"])
+    made = set(re.findall(
+        r"^\s*(?:ROOT )?%([\w-]+?)[.\d]* = [^\n]*tpu_custom_call", text,
+        re.M))
+    assert made == set(obs_names.EXTERNAL_KERNELS)
+
+
 # -- the whole step (about a minute each: not tier-1) ------------------------
 
 @pytest.mark.slow
