@@ -60,14 +60,14 @@ THROUGHPUT = "tokens_per_s_per_chip"
 # First step against the float32 reference below (the readings: PERF.md,
 # Findings, PR 35; all through the harness at the cell's size on the chip).
 # Loss: a next-token loss of 9.51 over 8,192 classes from bf16 logits;
-# 5.5e-6 to 3.2e-5 over the seeds.  The precision hardly moves it (float8
+# 5.5e-6 to 6.7e-5 over seventeen seeds.  The precision hardly moves it (float8
 # operands in the reference: 1.2e-4), so it has the limit of the harness's
 # accepted transformer cells — and NO UPPER READING here: neither control
 # reaches it (the planted fault below reads 3.2e-5), so in this cell the
 # gradient's limit alone decides, as in the glm_moe family.
 LOSS_RTOL = 1e-2
 # Gradient, relative L2 over the flat vector: 4.66e-2 to 5.03e-2 on the chip
-# over the seeds.  As in the glm_moe family it has two parts: bf16 products
+# over seventeen seeds.  As in the glm_moe family it has two parts: bf16 products
 # and a bf16 residual through five layers at 8,192 positions, and the
 # selections — the top 4 of 64 sigmoid scores are decided by small gaps, the
 # bf16 residual moves a router's logit a little, and 5.7% of the tokens pick

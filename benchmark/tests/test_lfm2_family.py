@@ -177,11 +177,8 @@ def test_conv_readers_by_hand():
     run = _run({"conv": 125.58})
     assert ms.read(run) == 125.58
     assert round(pct.read(run), 1) == 40.0
+    # a trace without the class, or no trace: nothing, and no raise
     assert pct.read(_run({})) is None and ms.read(_run({})) is None
-    # a program without the class, or a family without the count (the
-    # parent of PR 35 under this benchmark): nothing, and no raise
-    run.family = types.SimpleNamespace()
-    assert pct.read(run) is None
     run.trace = None
     assert pct.read(run) is None and ms.read(run) is None
 

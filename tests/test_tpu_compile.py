@@ -379,6 +379,22 @@ def test_gather_slot_window_past_the_budget_is_refused_by_name():
 
 # -- the dropless expert layer at GLM-4.7-Flash's widths ----------------------
 
+def assert_nine_grouped_products(text, rows):
+    """The expert layer's compiled gradient holds nine `ragged-dot-none`
+    products over `rows` sorted rows (three forward, six backward), and
+    every Mosaic kernel in it is the compiler's own, under a name the table
+    lists: the benchmark's rule reads the class from there."""
+    products = re.findall(
+        r"^\s*(?:ROOT )?%ragged-dot-none[.\d]* = (\w+\[[\d,]+\])", text, re.M)
+    assert sorted(products) == sorted(
+        [f"bf16[{rows},1536]"] * 3 + [f"bf16[{rows},2048]"] * 3
+        + ["bf16[8,2048,1536]"] * 2 + ["bf16[8,1536,2048]"])
+    made = set(re.findall(
+        r"^\s*(?:ROOT )?%([\w-]+?)[.\d]* = [^\n]*tpu_custom_call", text,
+        re.M))
+    assert made == set(obs_names.EXTERNAL_KERNELS)
+
+
 def test_dropless_expert_layer_compiles_to_grouped_kernels(chip):
     """`ops.moe.held_experts_ffn`, forward and backward, at the benchmark
     cell's sizes: 8,192 tokens, top-4 of 64 sigmoid-routed experts, the 8
@@ -404,19 +420,8 @@ def test_dropless_expert_layer_compiles_to_grouped_kernels(chip):
         ).astype(jnp.float32).sum(), argnums=(0, 1))(params, x)
 
     text = compiled_text(grads, params, sds((2, T // 2, D), bf, chip.one))
-    products = re.findall(r"^\s*(?:ROOT )?%ragged-dot-none[.\d]* = (\w+\[[\d,]+\])",
-                          text, re.M)
-    assert sorted(products) == sorted(
-        ["bf16[32768,1536]"] * 3 + ["bf16[32768,2048]"] * 3
-        + ["bf16[8,2048,1536]"] * 2 + ["bf16[8,1536,2048]"])
+    assert_nine_grouped_products(text, rows=32768)
     assert not re.search(r"scatter\(\w+\[32768,2048\]", text)
-    # every Mosaic kernel in it is the compiler's own, under a name the
-    # table lists: the benchmark's rule reads the class from there
-    from fpga_ai_nic_tpu.obs import names
-    made = set(re.findall(
-        r"^\s*(?:ROOT )?%([\w-]+?)[.\d]* = [^\n]*tpu_custom_call", text,
-        re.M))
-    assert made == set(names.EXTERNAL_KERNELS)
 
 
 # -- LFM2's mixers and its expert layer at the benchmark cell's sizes ---------
@@ -474,17 +479,10 @@ def test_lfm2_expert_layer_compiles_to_grouped_kernels(chip):
             bias=jax.lax.stop_gradient(p["expert_bias"])
         ).astype(jnp.float32).sum(), argnums=(0, 1))(params, x)
 
-    text = compiled_text(grads, params,
-                         sds((4, 8192, cfg.dim), jnp.bfloat16, chip.one))
-    products = re.findall(
-        r"^\s*(?:ROOT )?%ragged-dot-none[.\d]* = (\w+\[[\d,]+\])", text, re.M)
-    assert sorted(products) == sorted(
-        ["bf16[131072,1536]"] * 3 + ["bf16[131072,2048]"] * 3
-        + ["bf16[8,2048,1536]"] * 2 + ["bf16[8,1536,2048]"])
-    made = set(re.findall(
-        r"^\s*(?:ROOT )?%([\w-]+?)[.\d]* = [^\n]*tpu_custom_call", text,
-        re.M))
-    assert made == set(obs_names.EXTERNAL_KERNELS)
+    assert_nine_grouped_products(
+        compiled_text(grads, params,
+                      sds((4, 8192, cfg.dim), jnp.bfloat16, chip.one)),
+        rows=131072)
 
 
 # -- the whole step (about a minute each: not tier-1) ------------------------
