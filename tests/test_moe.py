@@ -513,3 +513,251 @@ def test_held_experts_must_be_distinct_ids_of_the_layer():
         moe.dispatch_plan(jnp.zeros((4, 2), jnp.int32), 8, held=(1, 1))
     with pytest.raises(ValueError, match="held experts"):
         moe.dispatch_plan(jnp.zeros((4, 2), jnp.int32), 8, held=(8,))
+
+
+# -- the compact program over the first C sorted rows, and the full one ------
+
+COMPACT_HELD = (5, 2)           # 2 of 8 experts: C = 512 of 1,024 assignments
+
+# (experts of the layer, held, top_k, a selection bias, a shared expert):
+# 512 tokens each, so C = 512 of 2,048 or of 1,024 assignments
+COMPACT_LAYERS = {
+    "8-of-64": (64, (3, 60, 17, 8, 33, 41, 5, 26), 4, False, False),
+    "2-of-8": (8, COMPACT_HELD, 2, False, False),
+    "2-of-8-bias": (8, COMPACT_HELD, 2, True, False),
+    "2-of-8-shared": (8, COMPACT_HELD, 2, False, True),
+    "8-of-64-bias-shared": (64, tuple(range(8)), 4, True, True),
+}
+
+
+def _layer_case(rng, name, dtype):
+    n, held, top_k, bias, shared = COMPACT_LAYERS[name]
+    params = _sigmoid_params(rng, n=n, held=held, shared=shared)
+    params = {k: v.astype(jnp.float32 if k == "wr" else dtype)
+              for k, v in params.items()}
+    x = jnp.asarray(rng.standard_normal((2, 256, D)), jnp.float32)
+    kwargs = dict(num_experts=n, top_k=top_k, held=held, scale=1.8)
+    if bias:            # steers the selection towards two experts held
+        kwargs["bias"] = jnp.zeros((n,)).at[jnp.asarray(held[:2])].set(0.05)
+    return params, x.astype(dtype), kwargs
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("name", sorted(COMPACT_LAYERS))
+def test_compact_program_equals_the_full_program(rng, monkeypatch, name,
+                                                 dtype):
+    """The layer as the models call it, on a routing that fits: output and
+    the gradients of every leaf — `wr`'s through the gates — and of x under
+    the compact program against the full one (`SLACK` so large that C is
+    T*k: the program of before, alone): the same products on the same
+    rows, the sums over a token's slots rounded in float32 both ways."""
+    params, x, kwargs = _layer_case(rng, name, dtype)
+
+    def run():
+        def loss(params, x):
+            y, counts = moe.held_experts_ffn(params, x, with_counts=True,
+                                             **kwargs)
+            return jnp.sum(jnp.sin(y.astype(jnp.float32))), (y, counts)
+        with jax.default_matmul_precision("highest"):
+            return jax.jit(jax.grad(loss, argnums=(0, 1), has_aux=True))(
+                params, x)
+
+    got, (y_got, counts) = run()
+    assert int(counts["capacity"]) == 512 < x.shape[0] * x.shape[1] * \
+        kwargs["top_k"]
+    assert float(counts["fit"]) == 1.0 and int(counts["dropped"]) == 0
+    assert 0 < int(counts["rows"].sum()) < 512
+    monkeypatch.setattr(moe, "SLACK", 1e9)
+    want, (y_want, full) = run()
+    assert int(full["capacity"]) == x.shape[0] * x.shape[1] * kwargs["top_k"]
+    tol = dict(rtol=1e-5, atol=2e-6) if dtype == jnp.float32 else dict(
+        rtol=2e-2, atol=2e-2)
+    assert y_got.dtype == dtype
+    np.testing.assert_allclose(np.asarray(y_got, np.float32),
+                               np.asarray(y_want, np.float32), **tol)
+    assert float(jnp.max(jnp.abs(y_want.astype(jnp.float32)))) > 0.1
+    assert sorted(got[0]) == sorted(params)
+    for leaf in sorted(params):
+        g, w = got[0][leaf], want[0][leaf]
+        assert g.dtype == w.dtype == params[leaf].dtype, leaf
+        np.testing.assert_allclose(np.asarray(g, np.float32),
+                                   np.asarray(w, np.float32),
+                                   err_msg=leaf, **tol)
+        assert float(jnp.max(jnp.abs(w.astype(jnp.float32)))) > 1e-3, leaf
+    np.testing.assert_allclose(np.asarray(got[1], np.float32),
+                               np.asarray(want[1], np.float32),
+                               err_msg="x", **tol)
+
+
+def test_compact_program_matches_the_per_token_reference(rng):
+    params = _sigmoid_params(rng, held=COMPACT_HELD)
+    x = jnp.asarray(rng.standard_normal((2, 256, D)), jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        y, counts = jax.jit(lambda p, x: moe.held_experts_ffn(
+            p, x, num_experts=8, top_k=2, held=COMPACT_HELD, scale=1.8,
+            with_counts=True))(params, x)
+    assert float(counts["fit"]) == 1.0 and int(counts["capacity"]) == 512
+    assert int(counts["dropped"]) == 0
+    np.testing.assert_allclose(
+        np.asarray(y), _ref_sigmoid_moe(params, x, 2, COMPACT_HELD, 1.8),
+        rtol=2e-4, atol=2e-5)
+
+
+def _to_the_held_experts(params, n=8):
+    """The router of `params` replaced by one that sends every token of an
+    all-positive batch to experts 5 and 2, in that order."""
+    wr = np.full((D, n), -1.0, np.float32)
+    wr[:, 5], wr[:, 2] = 1.0, 0.5
+    return dict(params, wr=jnp.asarray(wr))
+
+
+def test_a_router_that_overflows_the_capacity_takes_the_full_program(rng):
+    """Every token selects held expert 5 and held expert 2: 1,024 rows on
+    the experts held against C = 512.  The conditional takes the full
+    program: nothing is dropped, and result and gradients are those of a
+    chip that holds all eight experts (`held=None`: no conditional, the
+    program of before) with the same two matrices, and the per-token
+    reference's."""
+    share = _to_the_held_experts(_sigmoid_params(rng, held=COMPACT_HELD))
+    whole = _to_the_held_experts(_sigmoid_params(rng))
+    for leaf in ("w1", "w3", "w2"):
+        whole[leaf] = whole[leaf].at[jnp.asarray(COMPACT_HELD)].set(
+            share[leaf])
+    x = jnp.abs(jnp.asarray(rng.standard_normal((1, 512, D)), jnp.float32))
+
+    def grads(params, held):
+        def loss(params, x):
+            y, counts = moe.held_experts_ffn(
+                params, x, num_experts=8, top_k=2, held=held, scale=1.8,
+                with_counts=True)
+            return jnp.sum(jnp.sin(y)), (y, counts)
+        with jax.default_matmul_precision("highest"):
+            return jax.jit(jax.grad(loss, argnums=(0, 1), has_aux=True))(
+                params, x)
+
+    (g_share, gx_share), (y_share, counts) = grads(share, COMPACT_HELD)
+    (g_whole, gx_whole), (y_whole, all_held) = grads(whole, None)
+    assert np.asarray(counts["rows"]).tolist() == [512, 512]
+    assert int(counts["capacity"]) == 512 and float(counts["fit"]) == 0.0
+    assert int(counts["dropped"]) == 0
+    assert int(all_held["capacity"]) == 1024 and float(all_held["fit"]) == 1.0
+    np.testing.assert_allclose(np.asarray(y_share), np.asarray(y_whole),
+                               rtol=1e-5, atol=2e-6)
+    np.testing.assert_allclose(np.asarray(gx_share), np.asarray(gx_whole),
+                               rtol=1e-5, atol=2e-6)
+    np.testing.assert_allclose(np.asarray(g_share["wr"]),
+                               np.asarray(g_whole["wr"]),
+                               rtol=1e-5, atol=2e-6)
+    for leaf in ("w1", "w3", "w2"):
+        np.testing.assert_allclose(
+            np.asarray(g_share[leaf]),
+            np.asarray(g_whole[leaf][jnp.asarray(COMPACT_HELD)]),
+            rtol=1e-5, atol=2e-6, err_msg=leaf)
+    np.testing.assert_allclose(
+        np.asarray(y_share), _ref_sigmoid_moe(share, x, 2, COMPACT_HELD, 1.8),
+        rtol=2e-4, atol=2e-5)
+    assert float(jnp.min(jnp.sum(jnp.abs(y_share), axis=-1))) > 0
+
+
+def _primitives(jaxpr, found=None):
+    found = set() if found is None else found
+    for eqn in jaxpr.eqns:
+        found.add(eqn.primitive.name)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _primitives(sub, found)
+    return found
+
+
+@pytest.mark.parametrize("held,conditional", [(None, False),
+                                              (tuple(range(8)), False),
+                                              (COMPACT_HELD, True)],
+                         ids=["held-none", "all-eight-held", "share"])
+def test_all_experts_held_traces_no_conditional(rng, held, conditional):
+    """C is T*k where every expert is held: one program, as before."""
+    params = _sigmoid_params(rng, held=held)
+    x = jnp.zeros((1, 512, D), jnp.float32)
+
+    def grads(params, x):
+        return jax.grad(lambda p, x: jnp.sum(moe.held_experts_ffn(
+            p, x, num_experts=8, top_k=2, held=held)), argnums=(0, 1))(
+                params, x)
+
+    assert ("cond" in _primitives(jax.make_jaxpr(grads)(params, x).jaxpr)
+            ) == conditional
+
+
+def test_both_programs_inside_a_checkpointed_scan(rng):
+    """Two expert layers as the models run them — one `lax.scan` body under
+    `jax.checkpoint`, differentiated — the first with a router that fits,
+    the second with one that overflows C, against the same two layers
+    written out with the full program alone."""
+    layers = [_sigmoid_params(rng, held=COMPACT_HELD) for _ in range(2)]
+    layers[1] = _to_the_held_experts(layers[1])
+    stack = jax.tree_util.tree_map(lambda *a: jnp.stack(a), *layers)
+    x = jnp.abs(jnp.asarray(rng.standard_normal((1, 512, D)), jnp.float32))
+
+    def layer(x, lyr):
+        y, counts = moe.held_experts_ffn(
+            lyr, x, num_experts=8, top_k=2, held=COMPACT_HELD, scale=1.8,
+            with_counts=True)
+        return jnp.abs(x + y), (counts["fit"], counts["dropped"])
+
+    def scanned(stack, x):
+        x, counts = jax.lax.scan(jax.checkpoint(layer), x, stack)
+        return jnp.sum(jnp.sin(x)), counts
+
+    def unrolled(layers, x):
+        for lyr in layers:
+            x, _ = layer(x, lyr)
+        return jnp.sum(jnp.sin(x))
+
+    with jax.default_matmul_precision("highest"):
+        (got_stack, got_x), (fit, dropped) = jax.jit(jax.grad(
+            scanned, argnums=(0, 1), has_aux=True))(stack, x)
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(moe, "SLACK", 1e9)
+        with jax.default_matmul_precision("highest"):
+            want_layers, want_x = jax.grad(unrolled, argnums=(0, 1))(layers,
+                                                                    x)
+    assert np.asarray(fit).tolist() == [1.0, 0.0]
+    assert np.asarray(dropped).tolist() == [0, 0]
+    np.testing.assert_allclose(np.asarray(got_x), np.asarray(want_x),
+                               rtol=1e-4, atol=1e-5)
+    for i, want in enumerate(want_layers):
+        for name, leaf in want.items():
+            np.testing.assert_allclose(
+                np.asarray(got_stack[name][i]), np.asarray(leaf),
+                rtol=1e-4, atol=1e-5, err_msg=f"{name}[{i}]")
+
+
+@pytest.mark.parametrize("assignments,n_held,n,rows", [
+    (131072, 8, 64, 32768),     # lfm2-24b-ep8share-s8192: 32,768 tokens x 4
+    (32768, 8, 64, 8192),       # glm47-flash-ep8share-s4096: 8,192 x 4
+    (32768, 64, 64, 32768),     # every expert held: all rows
+    (1024, 2, 8, 512), (72, 2, 8, 72), (3000, 1, 8, 1024),
+    (1000, 3, 8, 1000), (4096, 8, 64, 1024), (6000, 8, 64, 1536)])
+def test_compact_capacity_is_a_function_of_shapes(assignments, n_held, n,
+                                                  rows):
+    """A/4 at 8 of 64 held, in whole blocks of 512, never above A."""
+    got = moe.compact_capacity(assignments, n_held, n)
+    assert got == rows <= assignments
+    assert got % 512 == 0 or got == assignments
+    assert moe.SLACK == 2.0
+
+
+def test_routing_counts_tell_which_program_ran(rng):
+    experts = jnp.asarray(rng.integers(0, 8, (512, 2)), jnp.int32)
+    fits = moe.routing_counts(moe.dispatch_plan(experts, 8, COMPACT_HELD),
+                              experts)
+    assert fits["fit"].dtype == jnp.float32 and float(fits["fit"]) == 1.0
+    assert int(fits["capacity"]) == 512 > int(fits["rows"].sum())
+    experts = jnp.tile(jnp.asarray([[5, 2]], jnp.int32), (512, 1))
+    over = moe.routing_counts(moe.dispatch_plan(experts, 8, COMPACT_HELD),
+                              experts)
+    assert float(over["fit"]) == 0.0 and int(over["capacity"]) == 512
+    assert int(over["dropped"]) == 0
+    exact = experts.at[256:].set(jnp.asarray([0, 1], jnp.int32))
+    edge = moe.routing_counts(moe.dispatch_plan(exact, 8, COMPACT_HELD),
+                              exact)
+    assert int(edge["rows"].sum()) == 512 and float(edge["fit"]) == 1.0

@@ -379,16 +379,64 @@ def test_gather_slot_window_past_the_budget_is_refused_by_name():
 
 # -- the dropless expert layer at GLM-4.7-Flash's widths ----------------------
 
-def assert_nine_grouped_products(text, rows):
-    """The expert layer's compiled gradient holds nine `ragged-dot-none`
-    products over `rows` sorted rows (three forward, six backward), and
-    every Mosaic kernel in it is the compiler's own, under a name the table
-    lists: the benchmark's rule reads the class from there."""
-    products = re.findall(
-        r"^\s*(?:ROOT )?%ragged-dot-none[.\d]* = (\w+\[[\d,]+\])", text, re.M)
-    assert sorted(products) == sorted(
-        [f"bf16[{rows},1536]"] * 3 + [f"bf16[{rows},2048]"] * 3
+def grouped_products(text):
+    """The result type of every `ragged-dot-none` product, sorted."""
+    return sorted(re.findall(
+        r"^\s*(?:ROOT )?%ragged-dot-none[.\d]* = (\w+\[[\d,]+\])", text, re.M))
+
+
+def conditional_branches(text):
+    """[(full, compact)] of every `conditional` of a compiled module: the
+    text of each branch's computation with all it calls.  `lax.cond` puts
+    the false branch first."""
+    bodies = {m.group(1): m.group(2) for m in re.finditer(
+        r"^(?:ENTRY )?%([\w.\-]+) \([^\n]*\{\n(.*?)^\}", text, re.M | re.S)}
+
+    def reach(name, seen):
+        seen.add(name)
+        called = [n for n in re.findall(r"%([\w.\-]+)", bodies[name])
+                  if n in bodies]
+        return bodies[name] + "".join(
+            reach(n, seen) for n in called if n not in seen)
+
+    return [tuple(reach(name, set()) for name in m.groups())
+            for m in re.finditer(
+                r" conditional\([^\n]*branch_computations="
+                r"\{%([\w.\-]+), %([\w.\-]+)\}", text)]
+
+
+def products_of(rows):
+    """(forward, backward): the grouped products of a layer over `rows`
+    sorted rows — three forward, and nine in the backward, which recomputes
+    its forward (`_compact_or_full` keeps only its inputs): those three, one
+    more by the experts' width, two by the model's, three of the matrices'
+    shapes."""
+    forward = [f"bf16[{rows},1536]"] * 2 + [f"bf16[{rows},2048]"]
+    return forward, (
+        forward + [f"bf16[{rows},1536]"] + [f"bf16[{rows},2048]"] * 2
         + ["bf16[8,2048,1536]"] * 2 + ["bf16[8,1536,2048]"])
+
+
+def assert_compact_and_full_programs(text, capacity, assignments):
+    """The expert layer's compiled value and gradient hold ONE conditional
+    forward and ONE backward.  Each has the full program over `assignments`
+    sorted rows and the compact one over `capacity`: 3 `ragged-dot-none`
+    products a branch forward, 9 a branch backward (24 in all, of which a
+    step runs 12); the compact branches hold no array of `assignments`
+    rows by the model's or the experts' width, no branch scatters rows,
+    and every Mosaic kernel is the compiler's own, under a name the table
+    lists: the benchmark's rule reads the class from there."""
+    branches = conditional_branches(text)
+    assert len(branches) == len(re.findall(r" conditional\(", text)) == 2
+    for (full, compact), want in zip(branches, (0, 1)):
+        assert grouped_products(full) == sorted(
+            products_of(assignments)[want])
+        assert grouped_products(compact) == sorted(
+            products_of(capacity)[want])
+        wide = rf"\[{assignments},(?:2048|1536)\]"
+        assert re.search(wide, full) and not re.search(wide, compact)
+    assert len(grouped_products(text)) == 24
+    assert not re.search(r"scatter\(\w+\[\d+,2048\]", text)
     made = set(re.findall(
         r"^\s*(?:ROOT )?%([\w-]+?)[.\d]* = [^\n]*tpu_custom_call", text,
         re.M))
@@ -400,9 +448,11 @@ def test_dropless_expert_layer_compiles_to_grouped_kernels(chip):
     cell's sizes: 8,192 tokens, top-4 of 64 sigmoid-routed experts, the 8
     held here of width 2048 x 1536, a shared expert.  On the v5e
     `lax.ragged_dot` becomes the compiler's own grouped-matmul kernels
-    (`ragged-dot-*` custom calls): nine products (three forward, six
-    backward) over 32,768 sorted rows, no [experts, capacity, width]
-    buffer, and no scatter of 32,768 rows back onto the tokens."""
+    (`ragged-dot-*` custom calls): twelve products (three forward; the
+    same three and six more backward) over the first 8,192 sorted rows,
+    twelve over all 32,768 for a batch that overflows them, no [experts,
+    capacity, width] buffer, no scatter of rows back onto the tokens, and
+    in the compact program no array of 32,768 rows by 2,048 or 1,536."""
     from fpga_ai_nic_tpu.ops import moe
     T, D, F, H, E = 8192, 2048, 1536, 8, 64
     bf = jnp.bfloat16
@@ -415,13 +465,14 @@ def test_dropless_expert_layer_compiles_to_grouped_kernels(chip):
               "sw2": sds((F, D), bf, chip.one)}
 
     def grads(params, x):
-        return jax.grad(lambda p, y: moe.held_experts_ffn(
+        return jax.value_and_grad(lambda p, y: moe.held_experts_ffn(
             p, y, num_experts=E, top_k=4, held=tuple(range(H)), scale=1.8
         ).astype(jnp.float32).sum(), argnums=(0, 1))(params, x)
 
-    text = compiled_text(grads, params, sds((2, T // 2, D), bf, chip.one))
-    assert_nine_grouped_products(text, rows=32768)
-    assert not re.search(r"scatter\(\w+\[32768,2048\]", text)
+    assert moe.compact_capacity(4 * T, H, E) == T
+    assert_compact_and_full_programs(
+        compiled_text(grads, params, sds((2, T // 2, D), bf, chip.one)),
+        capacity=8192, assignments=32768)
 
 
 # -- LFM2's mixers and its expert layer at the benchmark cell's sizes ---------
@@ -467,22 +518,24 @@ def test_lfm2_mixers_compile_without_a_kernel_of_their_own(chip, mixer):
 
 def test_lfm2_expert_layer_compiles_to_grouped_kernels(chip):
     """`ops.moe.held_experts_ffn` as LFM2 calls it — a selection bias, no
-    shared expert — at 32,768 tokens: nine grouped products over 131,072
-    sorted rows, every Mosaic kernel one the table lists."""
+    shared expert — at 32,768 tokens: twelve grouped products over the
+    first 32,768 sorted rows, twelve over all 131,072, no array of 131,072
+    rows by 2,048 or 1,536 in the compact program, every Mosaic kernel one
+    the table lists."""
     from fpga_ai_nic_tpu.ops import moe
     cfg, lyr, _ = _lfm2_layer(chip, "conv", "moe")
     params = {k: lyr[k] for k in ("wr", "expert_bias", "w1", "w3", "w2")}
 
     def grads(params, x):
-        return jax.grad(lambda p, y: moe.held_experts_ffn(
+        return jax.value_and_grad(lambda p, y: moe.held_experts_ffn(
             p, y, num_experts=64, top_k=4, held=cfg.held,
             bias=jax.lax.stop_gradient(p["expert_bias"])
         ).astype(jnp.float32).sum(), argnums=(0, 1))(params, x)
 
-    assert_nine_grouped_products(
+    assert_compact_and_full_programs(
         compiled_text(grads, params,
                       sds((4, 8192, cfg.dim), jnp.bfloat16, chip.one)),
-        rows=131072)
+        capacity=32768, assignments=131072)
 
 
 # -- the whole step (about a minute each: not tier-1) ------------------------
@@ -529,3 +582,60 @@ def test_whole_fused_ring_step_compiles(monkeypatch, chip, dp, padded):
     assert got == (["codec.bfp_decode", "codec.bfp_encode"] if dp == 1 else
                    ["ring.ag_stream"] * (want - 1)
                    + ["ring.rs_update_stream"])
+
+
+# GiB of arguments and of temporaries of the expert cells' compiled steps with
+# the full program alone (commit 768f32f, compiled the same way).  With both
+# programs the LFM2 step holds 6.9942 GiB of temporaries; the GLM step 4.0936,
+# 3.9 MiB (0.09%) more than before: its peak lies outside the expert layer,
+# and what a conditional takes as operands lives until the conditional ends.
+EXPERT_STEP_GIB = {"lfm2-24b-ep8share-s8192": (6.1201, 7.5041),
+                   "glm47-flash-ep8share-s4096": (7.7107, 4.0897)}
+CONDITIONAL_OPERANDS_GIB = 0.005
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("cell_name", sorted(EXPERT_STEP_GIB))
+def test_expert_cells_step_holds_both_dispatch_programs(monkeypatch, chip,
+                                                        cell_name):
+    """A benchmark cell's whole `DPTrainer` step, from shapes alone: every
+    scanned run of expert layers holds one conditional forward and one
+    backward, each with the compact program and the full one — twelve
+    grouped products a program, not fifteen, because the layer's recompute
+    under `jax.checkpoint` needs nothing of the conditional — and the
+    step's arguments are what they were with one program and its
+    temporaries no more, but for the conditionals' operands."""
+    from benchmark import loader, run
+    monkeypatch.setattr(bfp_pallas, "_is_tpu", lambda: True)
+    monkeypatch.setattr(ring_pallas, "_is_tpu", lambda: True)
+    cell = loader.load_cell(loader.load_spec(), cell_name)
+    config, job, family = cell["config"], cell["job"], cell["family"]
+    tr, _, _ = run.build(jax, cell, 1, chip.devices)
+    like = jax.eval_shape(family.program(config, job)[0],
+                          jax.random.PRNGKey(0))
+    rep = NamedSharding(tr.mesh, P())
+    state = jax.tree_util.tree_map(
+        lambda l: sds(l.shape, l.dtype, rep),
+        jax.eval_shape(tr.init_state, like))
+    batch = jax.tree_util.tree_map(
+        lambda l: sds(l.shape, l.dtype, NamedSharding(tr.mesh,
+                                                      tr.batch_spec)),
+        jax.eval_shape(lambda k: family.make_batch(k, config, job),
+                       jax.random.PRNGKey(0)))
+    compiled = tr.step_fn.lower(state, batch).compile()
+    text = compiled.as_text()
+    runs = 2 if config["family"] == "lfm2_moe" else 1
+    tokens = family.items_per_step(config, job)
+    branches = conditional_branches(text)
+    assert len(branches) == len(re.findall(r" conditional\(", text)) \
+        == 2 * runs
+    for full, compact in branches:
+        assert re.search(rf"\[{4 * tokens},(?:2048|1536)\]", full)
+        assert not re.search(rf"\[{4 * tokens},(?:2048|1536)\]", compact)
+    assert grouped_products(text) == sorted(
+        sum(products_of(tokens) + products_of(4 * tokens), []) * runs)
+    mem = compiled.memory_analysis()
+    arguments, temporaries = EXPERT_STEP_GIB[cell_name]
+    assert mem.argument_size_in_bytes / 2 ** 30 <= arguments + 5e-5
+    assert mem.temp_size_in_bytes / 2 ** 30 \
+        <= temporaries + CONDITIONAL_OPERANDS_GIB, mem.temp_size_in_bytes
