@@ -78,6 +78,9 @@ SCOPES: Dict[str, str] = {
     # likewise (models/lfm2_moe.py)
     "ainic.conv": "gated short convolution: in-projection, gates, taps, out",
     "ainic.gqa": "grouped-query attention: q/k/v, head norms, RoPE, output",
+    # likewise (models/nemotron_h.py; its attention is ainic.gqa's, no norms)
+    "ainic.ssm": "Mamba-2 mixer: in-projection, convolution, gate, norm, out",
+    "ainic.ssm.scan": "its state-space recurrence, chunked, in matrix form",
     # inside a model's attention (ops/ring_attention.flash_attention)
     "ainic.attn.fwd": "the XLA route's forward: out and lse, block by block",
     "ainic.attn.bwd": "its backward: p from lse again, dQ, one dK and dV",

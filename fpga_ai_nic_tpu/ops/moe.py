@@ -38,7 +38,8 @@ front, and a chip that holds H of E experts works on those: THE COMPACT
 PROGRAM gathers the first C sorted rows, C = `compact_capacity` = `SLACK`
 times the rows a uniform router sends to the experts held, in blocks of 512
 and never more than T*k — a function of shapes, so it does the same work
-whatever a seed's router sends — runs the three products, SwiGLU's
+whatever a seed's router sends — runs the experts' products (SwiGLU's
+three, or the two of relu(x w1)**2 w2 where the weights hold no `w3`), their
 elementwise part and the gates on `[C, .]`, and brings the C result rows back
 to their tokens.  THE FULL PROGRAM over all T*k sorted rows is the other
 branch of a `lax.cond` on the plan's `fits`, for a layer whose rows on the
@@ -250,6 +251,48 @@ def moe_ffn(params: Dict, x: jax.Array, cfg: MoEConfig, *,
 # -- dropless, sigmoid-routed, some experts held -----------------------------
 
 
+def router_scores(wr: jax.Array, xf: jax.Array) -> jax.Array:
+    """sigmoid(xf @ wr) [T, E] in float32, the matmul at full precision."""
+    return jax.nn.sigmoid(jnp.dot(
+        xf.astype(jnp.float32), wr.astype(jnp.float32),
+        precision=lax.Precision.HIGHEST))
+
+
+# `balanced_bias` moves a bias by u = BALANCE_STEP * BALANCE_SHRINK**i in its
+# i-th iteration (a total travel of 1.0, scores lying in (0, 1)) and gives up
+# after BALANCE_ITERS
+BALANCE_STEP, BALANCE_SHRINK, BALANCE_ITERS = 0.02, 0.98, 1000
+
+
+def balanced_bias(scores: jax.Array, top_k: int, tol: float) -> jax.Array:
+    """A selection bias [E] under which the `top_k` largest of
+    `scores` [T, E] + bias load the experts evenly: the aux-loss-free rule of
+    arXiv:2408.15664, b_e += u * sign(mean load - load_e), iterated from
+    zero on this one batch of scores with a shrinking u, until max load /
+    mean load <= `tol` over ALL E experts (or BALANCE_ITERS); the most even
+    bias seen is returned.  The bias steers the selection only,
+    so no gate changes under it (`sigmoid_route`).  It stands for what a
+    deployed router's bias holds after training; nothing here runs in a
+    step."""
+    tokens, num_experts = scores.shape
+    mean = tokens * top_k / num_experts
+
+    def body(state):
+        bias, i, best, best_ratio = state
+        _, chosen = lax.top_k(scores + bias, top_k)
+        load = jnp.sum(chosen[..., None] == jnp.arange(num_experts),
+                       axis=(0, 1), dtype=jnp.float32)
+        ratio = jnp.max(load) / mean
+        best = jnp.where(ratio < best_ratio, bias, best)
+        move = BALANCE_STEP * BALANCE_SHRINK ** i * jnp.sign(mean - load)
+        return bias + move, i + 1, best, jnp.minimum(ratio, best_ratio)
+
+    zero = jnp.zeros((num_experts,), jnp.float32)
+    return lax.while_loop(
+        lambda s: (s[3] > tol) & (s[1] < BALANCE_ITERS), body,
+        (zero, jnp.float32(0), zero, jnp.float32(jnp.inf)))[2]
+
+
 def sigmoid_route(wr: jax.Array, xf: jax.Array, *, top_k: int,
                   bias: Optional[jax.Array] = None, scale: float = 1.0,
                   norm_topk: bool = True) -> Tuple[jax.Array, jax.Array]:
@@ -262,9 +305,7 @@ def sigmoid_route(wr: jax.Array, xf: jax.Array, *, top_k: int,
     the selected SCORES, divided by their sum where `norm_topk`, times
     `scale`.  The gradient reaches `wr` through the gates."""
     with scope("ainic.moe.route"):
-        scores = jax.nn.sigmoid(jnp.dot(
-            xf.astype(jnp.float32), wr.astype(jnp.float32),
-            precision=lax.Precision.HIGHEST))
+        scores = router_scores(wr, xf)
         _, experts = lax.top_k(scores if bias is None else scores + bias,
                                top_k)
         gates = jnp.take_along_axis(scores, experts, axis=-1)
@@ -378,12 +419,22 @@ def dispatch_plan(experts: jax.Array, num_experts: int,
             "local": local, "head": head, "fits": held_rows <= head.shape[0]}
 
 
-def _swiglu_rows(xs: jax.Array, w: Dict, sizes: jax.Array,
+def _relu2(g: jax.Array) -> jax.Array:
+    """relu(g)**2, squared in float32 and rounded once."""
+    return jnp.square(jax.nn.relu(g.astype(jnp.float32))).astype(g.dtype)
+
+
+def _expert_rows(xs: jax.Array, w: Dict, sizes: jax.Array,
                  live: jax.Array) -> jax.Array:
-    """SwiGLU of every sorted row under its own expert's matrices."""
+    """Every sorted row through its own expert's matrices.  The expert's
+    function is a property of the weights: with a `w3` it is SwiGLU,
+    (silu(x w1) * (x w3)) w2; without, the two-matrix relu(x w1)**2 w2."""
     g = _grouped_dot(xs, w["w1"], sizes, live)
-    u = _grouped_dot(xs, w["w3"], sizes, live)
-    h = jax.nn.silu(g.astype(jnp.float32)).astype(xs.dtype) * u
+    if "w3" in w:
+        u = _grouped_dot(xs, w["w3"], sizes, live)
+        h = jax.nn.silu(g.astype(jnp.float32)).astype(xs.dtype) * u
+    else:
+        h = _relu2(g)
     return _grouped_dot(h, w["w2"], sizes, live)
 
 
@@ -393,7 +444,7 @@ def _full(xf, gates, w, plan):
     T, D = xf.shape
     order, inv = plan["order"], plan["inv"]
     xs = _rows_to_experts(xf, order, inv)                       # [T*k, D]
-    ys = _rows_to_tokens(_swiglu_rows(xs, w, plan["sizes"], plan["live"]),
+    ys = _rows_to_tokens(_expert_rows(xs, w, plan["sizes"], plan["live"]),
                          order, inv).reshape(T, -1, D)
     return jnp.sum(ys.astype(jnp.float32) * gates[..., None],
                    axis=1).astype(xf.dtype)
@@ -460,7 +511,7 @@ def _compact(xf, gates, w, plan):
     """The compact program, for a plan that `fits`: the same sums over the
     first C sorted rows, which hold every row of a held expert."""
     head = plan["head"]
-    ys = _swiglu_rows(xf[head // gates.shape[1]], w, plan["sizes"],
+    ys = _expert_rows(xf[head // gates.shape[1]], w, plan["sizes"],
                       plan["live"][:head.shape[0]])             # [C, D]
     return _sum_to_tokens(ys, plan, gates, True, xf.dtype)
 
@@ -475,7 +526,7 @@ def _compact_bwd(xf, gates, w, plan, gy):
     head = plan["head"]
     count, token = head.shape[0], head // gates.shape[1]
     ys, pull = jax.vjp(
-        lambda xs, w: _swiglu_rows(xs, w, plan["sizes"],
+        lambda xs, w: _expert_rows(xs, w, plan["sizes"],
                                    plan["live"][:count]), xf[token], w)
     d_rows = gy[token].astype(jnp.float32)                      # [C, D]
     d_gs = jnp.sum(d_rows * ys.astype(jnp.float32), axis=-1)
@@ -513,17 +564,18 @@ _compact_or_full.defvjp(_compact_or_full_fwd, _compact_or_full_bwd)
 
 def dropless_experts(params: Dict, xf: jax.Array, gates: jax.Array,
                      plan: Dict[str, jax.Array]) -> jax.Array:
-    """sum over the selected experts HELD HERE of gate * SwiGLU_e(x), for
+    """sum over the selected experts HELD HERE of gate * E_e(x), for
     xf [T, D] and gates [T, k]: w1, w3 [H, D, F] and w2 [H, F, D] are the
-    held experts' weights in the order of `held`, `plan` the
-    `dispatch_plan` of the selection.  Every assignment to a held expert is
+    held experts' weights in the order of `held` (E_e is SwiGLU; without a
+    `w3`, relu(x w1)**2 w2: `_expert_rows`), `plan` the `dispatch_plan` of
+    the selection.  Every assignment to a held expert is
     computed, whatever the routing: the rows are sorted by expert and one
     grouped product a matrix runs over as many rows as were routed.  The
     work around the products is done on the first C sorted rows
     (`compact_capacity`) where those hold every row of a held expert, and
     on all T*k otherwise; where C is T*k there is one program and no
     conditional."""
-    w = {k: params[k] for k in ("w1", "w3", "w2")}
+    w = {k: params[k] for k in ("w1", "w3", "w2") if k in params}
     plan = {k: plan[k] for k in ("order", "inv", "sizes", "live", "head",
                                  "fits")}
     with scope("ainic.moe.experts"):
@@ -540,10 +592,13 @@ def swiglu(x: jax.Array, w1: jax.Array, w3: jax.Array,
 
 
 def shared_expert(params: Dict, xf: jax.Array) -> jax.Array:
-    """The SwiGLU every token passes through, beside the routed ones:
-    sw1, sw3 [D, F], sw2 [F, D]."""
+    """The expert every token passes through, beside the routed ones:
+    sw1, sw3 [D, F], sw2 [F, D], SwiGLU; without an `sw3`,
+    relu(x sw1)**2 sw2, as the routed experts (`_expert_rows`)."""
     with scope("ainic.moe.shared"):
-        return swiglu(xf, params["sw1"], params["sw3"], params["sw2"])
+        if "sw3" in params:
+            return swiglu(xf, params["sw1"], params["sw3"], params["sw2"])
+        return _relu2(xf @ params["sw1"]) @ params["sw2"]
 
 
 def routing_counts(plan: Dict, experts: jax.Array) -> Dict[str, jax.Array]:
@@ -575,8 +630,10 @@ def held_experts_ffn(params: Dict, x: jax.Array, *, num_experts: int,
     `held` of its `num_experts` experts (None: all): x [B, S, D] ->
     sum_{selected & held} gate_i E_i(x) + E_shared(x) (the shared expert
     where `params` has one).  `params`: wr [D, num_experts] float32, w1/w3/w2
-    of the experts held, optionally sw1/sw3/sw2.  No auxiliary loss.  With
-    `with_counts`, also `routing_counts` of this pass."""
+    of the experts held, optionally sw1/sw3/sw2; an expert without its
+    third matrix is the two-matrix relu**2 one (`_expert_rows`).  No
+    auxiliary loss.  With `with_counts`, also `routing_counts` of this
+    pass."""
     B, S, D = x.shape
     xf = x.reshape(B * S, D)
     gates, experts = sigmoid_route(params["wr"], xf, top_k=top_k, bias=bias,

@@ -259,7 +259,7 @@ def lowered_step(request):
 MODEL_SCOPES = sorted(s for s in names.SCOPES
                       if s.startswith(("ainic.mla", "ainic.moe.",
                                        "ainic.attn.", "ainic.conv",
-                                       "ainic.gqa")))
+                                       "ainic.gqa", "ainic.ssm")))
 
 
 @pytest.mark.parametrize("scope", sorted(set(names.SCOPES)
@@ -310,16 +310,40 @@ def lowered_lfm2_step():
     return tr.step_fn.lower(state, batch).as_text(debug_info=True)
 
 
+@pytest.fixture(scope="module")
+def lowered_nemotron_step():
+    """DPTrainer's step for a tiny models/nemotron_h.py, lowered as text."""
+    from fpga_ai_nic_tpu.models import nemotron_h
+    from fpga_ai_nic_tpu.parallel import DPTrainer, make_mesh
+
+    mcfg = nemotron_h.NemotronHConfig.tiny(held=(0, 1, 2))
+    cfg = TrainConfig(
+        global_batch=2, mesh=MeshConfig(dp=2),
+        collective=CollectiveConfig(impl="ring", compression=BFPConfig(),
+                                    fused_optimizer=True),
+        optimizer=OptimizerConfig(kind="adamw", learning_rate=1e-3))
+    tr = DPTrainer(lambda p, b: nemotron_h.loss_fn(p, b, mcfg, dp_axis="dp"),
+                   make_mesh(cfg.mesh, devices=jax.devices()[:2]), cfg)
+    state = tr.init_state(nemotron_h.init(jax.random.PRNGKey(0), mcfg))
+    batch = tr.shard_batch((np.zeros((2, 16), np.int32),
+                            np.zeros((2, 16), np.int32)))
+    return tr.step_fn.lower(state, batch).as_text(debug_info=True)
+
+
 # which model brings which scope into its step
 GLM_SCOPES = ["ainic.attn.bwd", "ainic.attn.fwd", "ainic.mla",
               "ainic.moe.experts", "ainic.moe.route", "ainic.moe.shared"]
 LFM2_SCOPES = ["ainic.attn.bwd", "ainic.attn.fwd", "ainic.conv", "ainic.gqa",
                "ainic.moe.experts", "ainic.moe.route"]
+NEMOTRON_SCOPES = ["ainic.attn.bwd", "ainic.attn.fwd", "ainic.gqa",
+                   "ainic.moe.experts", "ainic.moe.route", "ainic.moe.shared",
+                   "ainic.ssm", "ainic.ssm.scan"]
 
 
-def test_the_model_scopes_are_the_eight_the_table_lists():
-    assert MODEL_SCOPES == sorted(set(GLM_SCOPES) | set(LFM2_SCOPES))
-    assert len(MODEL_SCOPES) == 8
+def test_the_model_scopes_are_the_ten_the_table_lists():
+    assert MODEL_SCOPES == sorted(set(GLM_SCOPES) | set(LFM2_SCOPES)
+                                  | set(NEMOTRON_SCOPES))
+    assert len(MODEL_SCOPES) == 10
 
 
 @pytest.mark.parametrize("scope", GLM_SCOPES)
@@ -342,6 +366,31 @@ def test_lowered_lfm2_step_holds_no_scope_of_another_model(
         lowered_lfm2_step, scope):
     """No latent attention and no shared expert in this model."""
     assert not re.search(r"[/\"]%s/" % re.escape(scope), lowered_lfm2_step)
+
+
+@pytest.mark.parametrize("scope", NEMOTRON_SCOPES)
+def test_lowered_nemotron_step_holds_the_model_scope(lowered_nemotron_step,
+                                                     scope):
+    """The scan's scope lies inside the mixer's: .../ainic.ssm/ainic.ssm.scan/"""
+    assert re.search(r"[/\"]%s/" % re.escape(scope), lowered_nemotron_step)
+    assert re.search(r"[/\"]ainic\.fwd_bwd/", lowered_nemotron_step)
+    assert re.search(r"ainic\.ssm/ainic\.ssm\.scan/", lowered_nemotron_step)
+
+
+@pytest.mark.parametrize("scope", sorted(set(MODEL_SCOPES)
+                                         - set(NEMOTRON_SCOPES)))
+def test_lowered_nemotron_step_holds_no_scope_of_another_model(
+        lowered_nemotron_step, scope):
+    """No latent attention and no short convolution in this model."""
+    assert not re.search(r"[/\"]%s/" % re.escape(scope),
+                         lowered_nemotron_step)
+
+
+@pytest.mark.parametrize("scope", ["ainic.ssm", "ainic.ssm.scan"])
+def test_the_older_models_hold_no_state_space_scope(lowered_glm_step,
+                                                    lowered_lfm2_step, scope):
+    for text in (lowered_glm_step, lowered_lfm2_step):
+        assert not re.search(r"[/\"]%s/" % re.escape(scope), text)
 
 
 # -- the benchmark's rules read the names back -------------------------------
@@ -446,10 +495,12 @@ def test_a_rule_reads_the_external_kernels_from_the_table(layer):
 
 
 @pytest.mark.parametrize("rule_file", ["075-lfm2-moe.json",
+                                       "076-nemotron-h.json",
                                        "08-glm-moe.json"])
 def test_each_expert_model_s_rule_file_holds_the_table_s_pattern(rule_file):
-    """075-lfm2-moe.json is asked before 08-glm-moe.json, so it repeats the
-    pattern; both hold it letter for letter."""
+    """075-lfm2-moe.json and 076-nemotron-h.json are asked before
+    08-glm-moe.json, so they repeat the pattern; all hold it letter for
+    letter."""
     with open(os.path.join(ROOT, "benchmark", "op_classes", rule_file)) as f:
         mine = [r["regex"] for r in json.load(f)["rules"]
                 if r["class"] == "moe"]

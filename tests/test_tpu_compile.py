@@ -538,6 +538,90 @@ def test_lfm2_expert_layer_compiles_to_grouped_kernels(chip):
         capacity=32768, assignments=131072)
 
 
+# -- Nemotron-H's mixer and its expert layer at the benchmark cell's sizes ----
+
+def _nemotron_block(chip, kind):
+    """(cfg, one block's leaves as shapes, x [1, 8192, 2688] bf16)."""
+    from fpga_ai_nic_tpu.models import nemotron_h
+    cfg = nemotron_h.NemotronHConfig(vocab=16384, held=tuple(range(8)),
+                                     pattern=kind)
+    like = jax.eval_shape(lambda: nemotron_h.init(jax.random.PRNGKey(0), cfg))
+    lyr = {k: sds(v.shape[1:], v.dtype, chip.one)
+           for k, v in like["blocks"][0].items()}
+    return cfg, lyr, sds((1, 8192, cfg.dim), jnp.bfloat16, chip.one)
+
+
+def test_nemotron_mixer_compiles_clear_of_the_other_cells_rules(chip):
+    """The Mamba-2 mixer (W_in 2688 x 10304, 64 heads of 64 over 8 groups,
+    state 128, chunks of 128) at one 8,192-token sequence, forward and
+    backward: XLA's own fusions, no Mosaic kernel; the decay arrays are
+    float32 [64 chunks, 64 heads, 128, 128]; and NO array of it has the
+    shape 075-lfm2-moe.json reads as attention ([.., 8 or 32, n, 64 or
+    512]) — inside a chunk the positions come last, so every array of the
+    scan ends in 128 — nor 07's or 075's vocabulary-wide ones."""
+    from fpga_ai_nic_tpu.models import nemotron_h
+    cfg, lyr, x = _nemotron_block(chip, "M")
+
+    def grads(lyr, x):
+        return jax.grad(lambda l, y: nemotron_h.mamba_mixer(
+            l, y, cfg).astype(jnp.float32).sum(), argnums=(0, 1))(lyr, x)
+
+    text = compiled_text(grads, lyr, x)
+    assert "tpu_custom_call" not in text
+    assert re.search(r"f32\[64,64,128,128\]", text)
+    assert re.search(r"bf16\[(?:1,)?2688,10304\]", text)
+    with open(os.path.join(os.path.dirname(__file__), os.pardir, "benchmark",
+                           "op_classes", "075-lfm2-moe.json")) as f:
+        theirs = [r["regex"] for r in json.load(f)["rules"]
+                  if r["class"] in ("attention", "head", "gqa", "conv")]
+    shapes = set(re.findall(r"\w+\[[\d,]+\]", text))
+    for regex in theirs:
+        # the rule's shape part alone, on every array the mixer makes
+        shape_part = regex[regex.rindex("[^\\n]*") + len("[^\\n]*"):]
+        hit = [s for s in shapes if re.search(shape_part, s)]
+        assert not hit, (regex, hit)
+
+
+def test_nemotron_expert_layer_compiles_to_two_products_an_expert(chip):
+    """`ops.moe.held_experts_ffn` as Nemotron-H calls it — relu2 experts of
+    two matrices, a selection bias, a shared expert — at 8,192 tokens, top-6
+    of 128: the compact program over C = 6,144 sorted rows and the full one
+    over 49,152 in one conditional forward and one backward, TWO grouped
+    products a program forward (three for SwiGLU) and six backward, every
+    Mosaic kernel one the table lists."""
+    from fpga_ai_nic_tpu.ops import moe
+    cfg, lyr, x = _nemotron_block(chip, "E")
+    params = {k: v for k, v in lyr.items() if k != "norm"}
+
+    def grads(params, x):
+        return jax.value_and_grad(lambda p, y: moe.held_experts_ffn(
+            p, y, num_experts=128, top_k=6, held=cfg.held, scale=2.5,
+            bias=jax.lax.stop_gradient(p["expert_bias"])
+        ).astype(jnp.float32).sum(), argnums=(0, 1))(params, x)
+
+    text = compiled_text(grads, params, x)
+    branches = conditional_branches(text)
+    assert len(branches) == len(re.findall(r" conditional\(", text)) == 2
+
+    def products(rows):
+        forward = [f"bf16[{rows},1856]", f"bf16[{rows},2688]"]
+        return forward, (forward + [f"bf16[{rows},1856]",
+                                    f"bf16[{rows},2688]",
+                                    "bf16[8,2688,1856]", "bf16[8,1856,2688]"])
+
+    # the forward conditional and the backward one, in either order
+    branches.sort(key=lambda pair: len(grouped_products(pair[0])))
+    for (full, compact), want in zip(branches, (0, 1)):
+        assert grouped_products(full) == sorted(products(49152)[want])
+        assert grouped_products(compact) == sorted(products(6144)[want])
+        assert not re.search(r"\[49152,(?:2688|1856)\]", compact)
+    assert len(grouped_products(text)) == 16
+    made = set(re.findall(
+        r"^\s*(?:ROOT )?%([\w-]+?)[.\d]* = [^\n]*tpu_custom_call", text,
+        re.M))
+    assert made == set(obs_names.EXTERNAL_KERNELS)
+
+
 # -- the whole step (about a minute each: not tier-1) ------------------------
 
 @pytest.mark.slow
