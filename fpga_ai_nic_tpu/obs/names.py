@@ -9,8 +9,7 @@ the HLO instruction, and ``metadata=`` rides into the instruction's
 keeps in the event's name.  The benchmark's rules
 (``benchmark/op_classes/05-named-kernels.json``) and PERF.md section 3 agree
 with this table and with nothing else.  Names are compile-time only: nothing
-is added to a step.  `EXTERNAL_KERNELS` lists the kernels the compiler makes
-itself, under the names it gives them.
+is added to a step.
 """
 
 from typing import Dict, Tuple
@@ -49,20 +48,12 @@ KERNELS: Dict[str, Tuple[str, str]] = {
                      "(ops/flash_pallas.py)"),
     "attention.paged": (
         "attention", "paged decode attention (ops/paged_attend_pallas.py)"),
-}
-
-# Mosaic kernels the TPU compiler makes of an XLA operation by itself.  No
-# call site of ours can hand them a name or metadata, so the table holds the
-# instruction names the compiler gives them ("%<name>.N = ... custom-call"):
-# name -> (layer, what it is).  tests/test_tpu_compile.py compiles the expert
-# layer for the chip and refuses a custom call that is neither in KERNELS
-# nor here; benchmark/op_classes/08-glm-moe.json classes them by
-# `external_kernel_regex()`, which tests/test_kernel_names.py holds it to.
-EXTERNAL_KERNELS: Dict[str, Tuple[str, str]] = {
-    "ragged-dot-none": (
-        "moe", "lax.ragged_dot's grouped product (ops/moe._grouped_dot)"),
-    "ragged-dot-metadata": (
-        "moe", "the grouped product's tiles, from the rows of each group"),
+    "moe.gmm": (
+        "moe", "grouped product of rows sorted by expert, forward and the "
+               "rows' cotangent (ops/grouped_pallas.py)"),
+    "moe.tgmm": (
+        "moe", "grouped product's weight cotangent, one matrix an expert "
+               "(ops/grouped_pallas.py)"),
 }
 
 # the phases of DPTrainer's step, as jax.named_scope: metadata on the
@@ -88,13 +79,6 @@ SCOPES: Dict[str, str] = {
     "ainic.moe.experts": "dropless grouped product over the experts held",
     "ainic.moe.shared": "the shared expert, on every token",
 }
-
-
-def external_kernel_regex(layer: str) -> str:
-    """The pattern that matches, at its start, the instruction text of the
-    compiler's own kernels of `layer`."""
-    own = sorted(n for n, (l, _) in EXTERNAL_KERNELS.items() if l == layer)
-    return r"^%(?:" + "|".join(own) + r")[.\d]* = "
 
 
 def kernel(name: str, **extra: object) -> dict:

@@ -27,8 +27,9 @@ the sigmoid-routed expert models (`sigmoid_route`, `dropless_experts`,
 `held_experts_ffn`): scores are a sigmoid over every expert of the layer,
 the top-k of score + bias are selected, the gates are the selected scores
 normalised over the selection and scaled; assignments are sorted by expert
-and multiplied with `lax.ragged_dot`, whose cost follows the rows routed, so
-no expert has a capacity and no assignment is dropped.  `held` names the
+and multiplied by a grouped product (`ops/grouped_pallas.py`) whose cost
+follows the rows routed, so no expert has a capacity and no assignment is
+dropped.  `held` names the
 experts this chip holds of an expert-parallel layer: the router still scores
 all of them and the gates are normalised over all that were selected, the
 chip computes the part of the result its own experts give, and what the
@@ -62,6 +63,7 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from ..obs.names import scope
+from .grouped_pallas import grouped_dot
 
 
 @dataclass(frozen=True)
@@ -373,17 +375,6 @@ def _rows_to_tokens_bwd(res, g):
 _rows_to_tokens.defvjp(_rows_to_tokens_fwd, _rows_to_tokens_bwd)
 
 
-def _grouped_dot(x: jax.Array, w: jax.Array, sizes: jax.Array,
-                 live: jax.Array) -> jax.Array:
-    """Rows of x [A, K], sorted by group, times their group's w [G, K, N].
-    Rows past sum(sizes) belong to no group held here: `lax.ragged_dot`
-    leaves them undefined, so they are zeroed going in and coming out —
-    which also zeroes both cotangents of such a row."""
-    x = jnp.where(live, x, jnp.zeros((), x.dtype))
-    y = lax.ragged_dot(x, w, sizes)
-    return jnp.where(live, y, jnp.zeros((), y.dtype))
-
-
 def _held_lookup(num_experts: int, held: Optional[Sequence[int]]):
     """(local index of every expert, H where it is absent; H)."""
     held = tuple(range(num_experts)) if held is None else tuple(held)
@@ -424,18 +415,18 @@ def _relu2(g: jax.Array) -> jax.Array:
     return jnp.square(jax.nn.relu(g.astype(jnp.float32))).astype(g.dtype)
 
 
-def _expert_rows(xs: jax.Array, w: Dict, sizes: jax.Array,
-                 live: jax.Array) -> jax.Array:
-    """Every sorted row through its own expert's matrices.  The expert's
+def _expert_rows(xs: jax.Array, w: Dict, sizes: jax.Array) -> jax.Array:
+    """Every sorted row through its own expert's matrices, rows past
+    sum(sizes) zero (`grouped_pallas.grouped_dot`).  The expert's
     function is a property of the weights: with a `w3` it is SwiGLU,
     (silu(x w1) * (x w3)) w2; without, the two-matrix relu(x w1)**2 w2."""
-    g = _grouped_dot(xs, w["w1"], sizes, live)
+    g = grouped_dot(xs, w["w1"], sizes)
     if "w3" in w:
-        u = _grouped_dot(xs, w["w3"], sizes, live)
+        u = grouped_dot(xs, w["w3"], sizes)
         h = jax.nn.silu(g.astype(jnp.float32)).astype(xs.dtype) * u
     else:
         h = _relu2(g)
-    return _grouped_dot(h, w["w2"], sizes, live)
+    return grouped_dot(h, w["w2"], sizes)
 
 
 def _full(xf, gates, w, plan):
@@ -444,7 +435,7 @@ def _full(xf, gates, w, plan):
     T, D = xf.shape
     order, inv = plan["order"], plan["inv"]
     xs = _rows_to_experts(xf, order, inv)                       # [T*k, D]
-    ys = _rows_to_tokens(_expert_rows(xs, w, plan["sizes"], plan["live"]),
+    ys = _rows_to_tokens(_expert_rows(xs, w, plan["sizes"]),
                          order, inv).reshape(T, -1, D)
     return jnp.sum(ys.astype(jnp.float32) * gates[..., None],
                    axis=1).astype(xf.dtype)
@@ -511,8 +502,8 @@ def _compact(xf, gates, w, plan):
     """The compact program, for a plan that `fits`: the same sums over the
     first C sorted rows, which hold every row of a held expert."""
     head = plan["head"]
-    ys = _expert_rows(xf[head // gates.shape[1]], w, plan["sizes"],
-                      plan["live"][:head.shape[0]])             # [C, D]
+    ys = _expert_rows(xf[head // gates.shape[1]], w,
+                      plan["sizes"])                            # [C, D]
     return _sum_to_tokens(ys, plan, gates, True, xf.dtype)
 
 
@@ -525,9 +516,8 @@ def _compact_bwd(xf, gates, w, plan, gy):
     rows."""
     head = plan["head"]
     count, token = head.shape[0], head // gates.shape[1]
-    ys, pull = jax.vjp(
-        lambda xs, w: _expert_rows(xs, w, plan["sizes"],
-                                   plan["live"][:count]), xf[token], w)
+    ys, pull = jax.vjp(lambda xs, w: _expert_rows(xs, w, plan["sizes"]),
+                       xf[token], w)
     d_rows = gy[token].astype(jnp.float32)                      # [C, D]
     d_gs = jnp.sum(d_rows * ys.astype(jnp.float32), axis=-1)
     d_xs, d_w = pull((d_rows * gates.reshape(-1)[head][:, None]

@@ -25,7 +25,7 @@ from benchmark import kernel_events, trace_reduce
 from fpga_ai_nic_tpu import optim
 from fpga_ai_nic_tpu.compress import int8
 from fpga_ai_nic_tpu.obs import names
-from fpga_ai_nic_tpu.ops import (bfp_pallas, flash_pallas,
+from fpga_ai_nic_tpu.ops import (bfp_pallas, flash_pallas, grouped_pallas,
                                  paged_attend_pallas, ring_pallas)
 from fpga_ai_nic_tpu.utils.config import (
     BFPConfig, CollectiveConfig, MeshConfig, MLPConfig, OptimizerConfig,
@@ -44,7 +44,8 @@ def test_name_survives_sanitising(name):
     an identifier, and the metadata keeps the name as the table has it."""
     layer, what = names.KERNELS[name]
     assert re.fullmatch(r"[a-z]+\.[a-z0-9_]+", name)
-    assert layer == name.split(".")[0] in ("ring", "codec", "attention")
+    assert layer == name.split(".")[0] in ("ring", "codec", "attention",
+                                           "moe")
     assert what and "\n" not in what
     kw = names.kernel(name)
     assert kw["name"] == name.replace(".", "_") and kw["name"].isidentifier()
@@ -112,7 +113,7 @@ def test_every_pallas_call_in_the_package_takes_its_name_from_the_table():
     calls = _pallas_calls()
     unnamed = [f"{f}:{line}" for f, line, splat in calls if splat is None]
     assert not unnamed, f"pl.pallas_call without **kernel(...): {unnamed}"
-    assert len(calls) == 12
+    assert len(calls) == 14
     used = [n for _, _, splat in calls for n in _names_of(splat)]
     assert sorted(used) == sorted(names.KERNELS)      # each once, none spare
 
@@ -171,7 +172,17 @@ def _flash_grad(q, k, v):
             q, k, v)
 
 
+def _grouped_grad(x, w):
+    """The grouped product forward and both cotangents: `moe.gmm` twice
+    (the rows' cotangent by the transposed weights), `moe.tgmm` once."""
+    sizes = jnp.array([20, 0, 12], jnp.int32)
+    y, pull = jax.vjp(lambda x, w: grouped_pallas.grouped_dot(x, w, sizes),
+                      x, w)
+    return pull(y)
+
+
 _QKV = [jax.ShapeDtypeStruct((1, 2, 128, 64), jnp.bfloat16)] * 3
+_GROUPED = [_f32(48, 128), _f32(3, 128, 256)]
 SITES = {
     "ring.rs": lambda: _loopback_rs(False),
     "ring.rs_update": lambda: _loopback_rs(False, "sgd"),
@@ -197,6 +208,8 @@ SITES = {
     "attention.flash_fwd": lambda: (_flash_grad, _QKV),
     "attention.flash_dq": lambda: (_flash_grad, _QKV),
     "attention.flash_dkv": lambda: (_flash_grad, _QKV),
+    "moe.gmm": lambda: (_grouped_grad, _GROUPED),
+    "moe.tgmm": lambda: (_grouped_grad, _GROUPED),
     "attention.paged": lambda: (
         lambda q, pk, pv, pt, pos: paged_attend_pallas.paged_gather_attend(
             q, pk, pv, pt, pos, page_size=16, interpret=True),
@@ -460,62 +473,86 @@ def test_event_names_classify_with_both_rule_files(event, cls, kernel):
     assert kernel_events.kernel_name(event) == kernel
 
 
+NAMED_RULE_FILES = ["05-named-kernels.json", "06-moe-kernels.json"]
+
+
+def _rule_file(name):
+    with open(os.path.join(ROOT, "benchmark", "op_classes", name)) as f:
+        return json.load(f)["rules"]
+
+
 def test_named_rules_sort_first_and_give_no_new_class():
     d = os.path.join(ROOT, "benchmark", "op_classes")
     files = sorted(f for f in os.listdir(d) if f.endswith(".json"))
     # named kernels first, the fallback last; a class told by what an XLA
     # operation touches (07-head.json, PR 26) sorts between the two
-    assert files[0] == "05-named-kernels.json"
+    assert files[:2] == NAMED_RULE_FILES
     assert files[-1] == "10-kernels.json"
-    with open(os.path.join(d, files[0])) as f:
-        named = json.load(f)["rules"]
-    assert [r["class"] for r in named] == ["ring", "codec", "attention"]
+    named = [r for f in NAMED_RULE_FILES for r in _rule_file(f)]
+    assert [r["class"] for r in named] == ["ring", "codec", "attention",
+                                          "moe"]
     layers = {layer for layer, _ in names.KERNELS.values()}
     assert {r["class"] for r in named} == layers
 
 
-# -- the compiler's own kernels: the table owns their names too --------------
+# -- the expert layer's kernels: 06-moe-kernels.json -------------------------
 
-def _rules_of(cls):
-    rules = []
-    d = os.path.join(ROOT, "benchmark", "op_classes")
-    for f in sorted(os.listdir(d)):
-        with open(os.path.join(d, f)) as fh:
-            rules += [r["regex"] for r in json.load(fh)["rules"]
-                      if r["class"] == cls]
-    return rules
+MOE_EVENTS = [
+    _event("moe_gmm.3", "bf16[6144,1856]{1,0:T(8,128)(2,1)}",
+           "s32[32]{0} %a, s32[32]{0} %b, s32[9]{0} %c, bf16[6144,2688]{1,0} "
+           "%x, bf16[8,2688,1856]{2,1,0} %w", {"ainic_kernel": "moe.gmm"}),
+    _event("moe_gmm.17", "bf16[32768,2048]{1,0:T(8,128)(2,1)}",
+           "bf16[32768,1536]{1,0} %dy, bf16[8,2048,1536]{2,1,0} %w",
+           {"ainic_kernel": "moe.gmm", "rhs": "transposed"}),
+    _event("moe_tgmm.2", "bf16[8,2048,1536]{2,1,0:T(8,128)(2,1)}",
+           "bf16[8192,2048]{1,0} %x, bf16[8192,1536]{1,0} %dy",
+           {"ainic_kernel": "moe.tgmm"}),
+]
 
 
-@pytest.mark.parametrize("layer", sorted(
-    {layer for layer, _ in names.EXTERNAL_KERNELS.values()}))
-def test_a_rule_reads_the_external_kernels_from_the_table(layer):
-    """The class of a kernel the compiler makes is told by the table's
-    pattern, letter for letter: a name changed here or there fails."""
-    assert names.external_kernel_regex(layer) in _rules_of(layer)
+@pytest.mark.parametrize("event", MOE_EVENTS, ids=["gmm", "gmm-t", "tgmm"])
+def test_moe_rule_reads_the_layer_out_of_the_name(event):
+    """The expert cells' rule files skip every tpu_custom_call, so 06 is
+    what classes these; its pattern meets a `moe.*` name and nothing
+    else's, and the readers by name find the kernel."""
+    mine = [dict(r, regex=re.compile(r["regex"]))
+            for r in _rule_file("06-moe-kernels.json")]
+    assert trace_reduce.classify(event, mine) == "moe"
+    assert trace_reduce.classify(event, trace_reduce.load_rules()) == "moe"
+    assert kernel_events.kernel_name(event).startswith("moe.")
+
+
+@pytest.mark.parametrize("event", [e for e, *_ in EVENTS],
+                         ids=[f"{i}" for i in range(len(EVENTS))])
+def test_moe_rule_meets_no_other_layer_s_event(event):
+    mine = [dict(r, regex=re.compile(r["regex"]))
+            for r in _rule_file("06-moe-kernels.json")]
+    assert trace_reduce.classify(event, mine) == trace_reduce.DEFAULT_CLASS
+
+
+# -- what the compiler made of `lax.ragged_dot` before: still read as moe ----
+
+RAGGED = ["ragged-dot-metadata", "ragged-dot-none"]
 
 
 @pytest.mark.parametrize("rule_file", ["075-lfm2-moe.json",
                                        "076-nemotron-h.json",
                                        "08-glm-moe.json"])
 def test_each_expert_model_s_rule_file_holds_the_table_s_pattern(rule_file):
-    """075-lfm2-moe.json and 076-nemotron-h.json are asked before
-    08-glm-moe.json, so they repeat the pattern; all hold it letter for
-    letter."""
-    with open(os.path.join(ROOT, "benchmark", "op_classes", rule_file)) as f:
-        mine = [r["regex"] for r in json.load(f)["rules"]
-                if r["class"] == "moe"]
-    assert names.external_kernel_regex("moe") in mine
+    """Traces recorded before the grouped products had names hold the
+    compiler's own kernels; each expert model's rule file still reads them
+    as `moe`, letter for letter, so those traces read as they did."""
+    mine = [r["regex"] for r in _rule_file(rule_file) if r["class"] == "moe"]
+    assert r"^%(?:" + "|".join(RAGGED) + r")[.\d]* = " in mine
 
 
-@pytest.mark.parametrize("name", sorted(names.EXTERNAL_KERNELS))
+@pytest.mark.parametrize("name", RAGGED)
 def test_external_kernel_events_take_the_table_s_layer(name):
-    layer, _ = names.EXTERNAL_KERNELS[name]
-    assert name not in names.KERNELS
     rules = trace_reduce.load_rules()
     for instr in (name, name + ".12"):
         event = _event(instr, "bf16[32768,1536]{1,0:T(8,128)(2,1)}",
                        "s32[1]{0:T(128)} %gte.5")
-        assert trace_reduce.classify(event, rules) == layer
+        assert trace_reduce.classify(event, rules) == "moe"
         assert kernel_events.kernel_name(event) is None
 
 

@@ -661,9 +661,13 @@ def test_a_router_that_overflows_the_capacity_takes_the_full_program(rng):
 
 
 def _primitives(jaxpr, found=None):
+    """The primitives of a program, a Pallas kernel's body left out: its
+    `pl.when`s are the kernel's own, not the program's conditionals."""
     found = set() if found is None else found
     for eqn in jaxpr.eqns:
         found.add(eqn.primitive.name)
+        if eqn.primitive.name == "pallas_call":
+            continue
         for sub in jax.core.jaxprs_in_params(eqn.params):
             _primitives(sub, found)
     return found

@@ -24,7 +24,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from fpga_ai_nic_tpu import optim
 from fpga_ai_nic_tpu.compress import int8
 from fpga_ai_nic_tpu.obs import names as obs_names
-from fpga_ai_nic_tpu.ops import (bfp_pallas, flash_pallas,
+from fpga_ai_nic_tpu.ops import (bfp_pallas, flash_pallas, grouped_pallas,
                                  paged_attend_pallas, ring_pallas)
 from fpga_ai_nic_tpu.utils.config import (BFPConfig, OptimizerConfig,
                                           OptimizerSpec)
@@ -257,6 +257,14 @@ NAMED_SITES = {
         lambda q, s: int8.int8_decode_pallas(q, s, interpret=False),
         sds((N_CODEC,), jnp.int8, c.one),
         sds((N_CODEC // 16,), jnp.bfloat16, c.one)),
+    "moe.gmm": lambda c: (
+        lambda x, w, s: grouped_pallas._gmm(x, w, s, True, False),
+        sds((512, 256), jnp.bfloat16, c.one),
+        sds((4, 384, 256), jnp.bfloat16, c.one), sds((4,), jnp.int32, c.one)),
+    "moe.tgmm": lambda c: (
+        lambda x, dy, s: grouped_pallas._tgmm(x, dy, s, False),
+        sds((512, 256), jnp.bfloat16, c.one),
+        sds((512, 384), jnp.bfloat16, c.one), sds((4,), jnp.int32, c.one)),
     "attention.paged": lambda c: (
         lambda q, pk, pv, pt, pos: paged_attend_pallas.paged_gather_attend(
             q, pk, pv, pt, pos, page_size=128, interpret=False),
@@ -380,17 +388,40 @@ def test_gather_slot_window_past_the_budget_is_refused_by_name():
 # -- the dropless expert layer at GLM-4.7-Flash's widths ----------------------
 
 def grouped_products(text):
-    """The result type of every `ragged-dot-none` product, sorted."""
+    """The result type of every grouped product (`moe.gmm`, `moe.tgmm`:
+    one kernel a product), sorted."""
     return sorted(re.findall(
-        r"^\s*(?:ROOT )?%ragged-dot-none[.\d]* = (\w+\[[\d,]+\])", text, re.M))
+        r"^\s*(?:ROOT )?%moe_t?gmm[.\d]* = (\w+\[[\d,]+\])", text, re.M))
+
+
+def assert_kernels_are_the_tables(text):
+    """Every Mosaic kernel of the compiled text is one of the expert
+    layer's named kernels (the benchmark's 06-moe-kernels.json reads the
+    class from its name), and none is the compiler's own grouped kernel."""
+    made = set(re.findall(
+        r"^\s*(?:ROOT )?%([\w-]+?)[.\d]* = [^\n]*tpu_custom_call", text,
+        re.M))
+    assert made == {"moe_gmm", "moe_tgmm"}
+    assert {meta["ainic_kernel"] for _, meta in named_calls(text)} == {
+        "moe.gmm", "moe.tgmm"}
+    assert "ragged-dot" not in text
+
+
+@pytest.fixture
+def kernels_for_the_chip(monkeypatch):
+    """`grouped_pallas` asks `_is_tpu` whether to interpret; a compile for
+    the described chip wants the kernels themselves."""
+    monkeypatch.setattr(grouped_pallas, "_is_tpu", lambda: True)
 
 
 def conditional_branches(text):
     """[(full, compact)] of every `conditional` of a compiled module: the
     text of each branch's computation with all it calls.  `lax.cond` puts
-    the false branch first."""
+    the false branch first.  A computation ends at a line that is `}`
+    alone: a named kernel's metadata breaks its line before its own `}}`."""
     bodies = {m.group(1): m.group(2) for m in re.finditer(
-        r"^(?:ENTRY )?%([\w.\-]+) \([^\n]*\{\n(.*?)^\}", text, re.M | re.S)}
+        r"^(?:ENTRY )?%([\w.\-]+) \([^\n]*\{\n(.*?)^\}$", text,
+        re.M | re.S)}
 
     def reach(name, seen):
         seen.add(name)
@@ -420,12 +451,11 @@ def products_of(rows):
 def assert_compact_and_full_programs(text, capacity, assignments):
     """The expert layer's compiled value and gradient hold ONE conditional
     forward and ONE backward.  Each has the full program over `assignments`
-    sorted rows and the compact one over `capacity`: 3 `ragged-dot-none`
-    products a branch forward, 9 a branch backward (24 in all, of which a
-    step runs 12); the compact branches hold no array of `assignments`
-    rows by the model's or the experts' width, no branch scatters rows,
-    and every Mosaic kernel is the compiler's own, under a name the table
-    lists: the benchmark's rule reads the class from there."""
+    sorted rows and the compact one over `capacity`: 3 grouped products a
+    branch forward, 9 a branch backward (24 in all, of which a step runs
+    12); the compact branches hold no array of `assignments` rows by the
+    model's or the experts' width, no branch scatters rows, and every
+    Mosaic kernel is one the table names."""
     branches = conditional_branches(text)
     assert len(branches) == len(re.findall(r" conditional\(", text)) == 2
     for (full, compact), want in zip(branches, (0, 1)):
@@ -437,18 +467,15 @@ def assert_compact_and_full_programs(text, capacity, assignments):
         assert re.search(wide, full) and not re.search(wide, compact)
     assert len(grouped_products(text)) == 24
     assert not re.search(r"scatter\(\w+\[\d+,2048\]", text)
-    made = set(re.findall(
-        r"^\s*(?:ROOT )?%([\w-]+?)[.\d]* = [^\n]*tpu_custom_call", text,
-        re.M))
-    assert made == set(obs_names.EXTERNAL_KERNELS)
+    assert_kernels_are_the_tables(text)
 
 
-def test_dropless_expert_layer_compiles_to_grouped_kernels(chip):
+def test_dropless_expert_layer_compiles_to_grouped_kernels(
+        chip, kernels_for_the_chip):
     """`ops.moe.held_experts_ffn`, forward and backward, at the benchmark
     cell's sizes: 8,192 tokens, top-4 of 64 sigmoid-routed experts, the 8
-    held here of width 2048 x 1536, a shared expert.  On the v5e
-    `lax.ragged_dot` becomes the compiler's own grouped-matmul kernels
-    (`ragged-dot-*` custom calls): twelve products (three forward; the
+    held here of width 2048 x 1536, a shared expert.  The grouped products
+    are the named kernels of `ops/grouped_pallas.py`: twelve (three forward; the
     same three and six more backward) over the first 8,192 sorted rows,
     twelve over all 32,768 for a batch that overflows them, no [experts,
     capacity, width] buffer, no scatter of rows back onto the tokens, and
@@ -516,7 +543,8 @@ def test_lfm2_mixers_compile_without_a_kernel_of_their_own(chip, mixer):
         assert re.search(r"bf16\[2048,3072\]", text)
 
 
-def test_lfm2_expert_layer_compiles_to_grouped_kernels(chip):
+def test_lfm2_expert_layer_compiles_to_grouped_kernels(
+        chip, kernels_for_the_chip):
     """`ops.moe.held_experts_ffn` as LFM2 calls it — a selection bias, no
     shared expert — at 32,768 tokens: twelve grouped products over the
     first 32,768 sorted rows, twelve over all 131,072, no array of 131,072
@@ -582,7 +610,8 @@ def test_nemotron_mixer_compiles_clear_of_the_other_cells_rules(chip):
         assert not hit, (regex, hit)
 
 
-def test_nemotron_expert_layer_compiles_to_two_products_an_expert(chip):
+def test_nemotron_expert_layer_compiles_to_two_products_an_expert(
+        chip, kernels_for_the_chip):
     """`ops.moe.held_experts_ffn` as Nemotron-H calls it — relu2 experts of
     two matrices, a selection bias, a shared expert — at 8,192 tokens, top-6
     of 128: the compact program over C = 6,144 sorted rows and the full one
@@ -616,10 +645,35 @@ def test_nemotron_expert_layer_compiles_to_two_products_an_expert(chip):
         assert grouped_products(compact) == sorted(products(6144)[want])
         assert not re.search(r"\[49152,(?:2688|1856)\]", compact)
     assert len(grouped_products(text)) == 16
-    made = set(re.findall(
-        r"^\s*(?:ROOT )?%([\w-]+?)[.\d]* = [^\n]*tpu_custom_call", text,
-        re.M))
-    assert made == set(obs_names.EXTERNAL_KERNELS)
+    assert_kernels_are_the_tables(text)
+
+
+def test_nemotron_expert_block_lowers_each_distinct_kernel_once(
+        chip, kernels_for_the_chip):
+    """Each distinct (kernel, shapes) of the block's value and gradient is
+    traced and lowered once, however many call sites it has: the text
+    handed to the compiler, before XLA inlines anything, holds 12 Mosaic
+    kernels (forward, rows' and weights' cotangent, at two matrices' shapes,
+    in each of the two programs) for the 16 products the compiled step runs
+    (the backward recomputes the forward).  A `pallas_call` lowered at
+    every call site cost seconds of set-up a step trace (PERF.md 6)."""
+    from fpga_ai_nic_tpu.ops import moe
+    cfg, lyr, x = _nemotron_block(chip, "E")
+    params = {k: v for k, v in lyr.items() if k != "norm"}
+
+    def grads(params, x):
+        return jax.value_and_grad(lambda p, y: moe.held_experts_ffn(
+            p, y, num_experts=128, top_k=6, held=cfg.held, scale=2.5,
+            bias=jax.lax.stop_gradient(p["expert_bias"])
+        ).astype(jnp.float32).sum(), argnums=(0, 1))(params, x)
+
+    lowered = jax.jit(grads).lower(params, x)
+    signatures = re.findall(
+        r"func\.func private @_t?gmm\w*\(([^)]*)\)", lowered.as_text())
+    assert lowered.as_text().count("tpu_custom_call") == len(signatures) \
+        == len(set(re.sub(r"loc\([^)]*\)", "", s) for s in signatures)) \
+        == 12
+    assert len(grouped_products(lowered.compile().as_text())) == 16
 
 
 # -- the whole step (about a minute each: not tier-1) ------------------------
@@ -692,6 +746,7 @@ def test_expert_cells_step_holds_both_dispatch_programs(monkeypatch, chip,
     from benchmark import loader, run
     monkeypatch.setattr(bfp_pallas, "_is_tpu", lambda: True)
     monkeypatch.setattr(ring_pallas, "_is_tpu", lambda: True)
+    monkeypatch.setattr(grouped_pallas, "_is_tpu", lambda: True)
     cell = loader.load_cell(loader.load_spec(), cell_name)
     config, job, family = cell["config"], cell["job"], cell["family"]
     tr, _, _ = run.build(jax, cell, 1, chip.devices)
