@@ -32,10 +32,17 @@ Contract:
     intervals are merged on.
   - Thread-safe: the elastic watchdog worker, XLA callback threads and
     the trainer thread all emit into one stream.
+  - **Compiles as child spans**: while a ``Profiler.bucket`` is open on a
+    thread, jax's own compile events on that thread land in the bucket's
+    stream as its children: ``jax.trace``, ``jax.lower`` and
+    ``jax.compile`` spans, the last with the persistent compile cache's
+    outcome as ``cache`` (``forward_compile_events``; names in
+    ``obs.names.SPANS``).
 """
 
 from __future__ import annotations
 
+import contextvars
 import json
 import threading
 import time
@@ -188,6 +195,94 @@ class EventStream:
             for ev in events:
                 f.write(json.dumps(ev) + "\n")
         return path
+
+
+# -- jax's compile events as children of the innermost open bucket ----------
+
+# jax 0.9's event -> the child span it becomes
+_COMPILE_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jax.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax.lower",
+    # wraps compile_or_get_cached: the compile on a miss, the cache read
+    # and load on a hit
+    "/jax/core/compile/backend_compile_duration": "jax.compile",
+}
+# jax's cache-outcome event -> the `cache` attribute of the jax.compile
+# span it fires inside
+_CACHE_OUTCOMES = {
+    "/jax/compilation_cache/cache_hits": "hit",
+    "/jax/compilation_cache/cache_misses": "miss",
+}
+
+
+class _OpenBucket:
+    """What the forwarder needs of the innermost open bucket: its stream,
+    its name, and the cache outcome of the compile now running in it."""
+
+    __slots__ = ("stream", "name", "cache")
+
+    def __init__(self, stream: "EventStream", name: str) -> None:
+        self.stream, self.name, self.cache = stream, name, "none"
+
+
+# set by utils.observability.Profiler.bucket; jax compiles synchronously on
+# the calling thread, so the innermost bucket of that context is the parent
+_OPEN_BUCKET: contextvars.ContextVar[Optional[_OpenBucket]] = \
+    contextvars.ContextVar("obs_open_bucket", default=None)
+_forwarding = False
+_forwarding_lock = threading.Lock()
+
+
+def _on_compile_duration(event: str, duration_secs: float,
+                         **kwargs: Any) -> None:
+    top = _OPEN_BUCKET.get()
+    if top is None:
+        return
+    name = _COMPILE_SPANS.get(event)
+    if name is None:
+        return
+    # jax times on its own clock: end now on the stream's, start = end - dur
+    t1 = time.perf_counter_ns()
+    dur = int(duration_secs * 1e9)
+    attrs = {"fun_name": kwargs.get("fun_name"), "parent": top.name}
+    if name == "jax.compile":
+        attrs["cache"], top.cache = top.cache, "none"
+    top.stream.emit(SPAN, name, t_ns=t1 - dur, dur_ns=dur, attrs=attrs)
+
+
+def _on_cache_event(event: str, **kwargs: Any) -> None:
+    top = _OPEN_BUCKET.get()
+    if top is None:
+        return
+    top.cache = _CACHE_OUTCOMES.get(event, top.cache)
+
+
+def forward_compile_events() -> None:
+    """Register the process's one forwarder of jax's compile events (once;
+    later calls return at once).  Outside any open bucket the forwarder
+    does one ``ContextVar.get`` and nothing else."""
+    global _forwarding
+    if _forwarding:
+        return
+    with _forwarding_lock:
+        if _forwarding:
+            return
+        import jax.monitoring
+        jax.monitoring.register_event_duration_secs_listener(
+            _on_compile_duration)
+        jax.monitoring.register_event_listener(_on_cache_event)
+        _forwarding = True
+
+
+def open_bucket(stream: "EventStream", name: str) -> contextvars.Token:
+    """Make ``name`` the parent of the compile events of this context until
+    ``close_bucket(token)``."""
+    forward_compile_events()
+    return _OPEN_BUCKET.set(_OpenBucket(stream, name))
+
+
+def close_bucket(token: contextvars.Token) -> None:
+    _OPEN_BUCKET.reset(token)
 
 
 def read_jsonl(path: str) -> Tuple[Dict[str, Any], List[Dict[str, Any]]]:

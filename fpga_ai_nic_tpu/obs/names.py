@@ -1,5 +1,6 @@
 """The one table of names the program's Pallas kernels and step phases carry
-into the compiled step and the device trace.
+into the compiled step and the device trace, and of the host spans of
+set-up in the event stream.
 
 Every ``pl.pallas_call`` in the package is written
 ``pl.pallas_call(kern, ..., **kernel("ring.rs_update", opt="sgd"))``:
@@ -78,6 +79,21 @@ SCOPES: Dict[str, str] = {
     "ainic.moe.route": "sigmoid scores over every expert, top-k, gates",
     "ainic.moe.experts": "dropless grouped product over the experts held",
     "ainic.moe.shared": "the shared expert, on every token",
+}
+
+
+# host spans of the event stream (obs.events), opened by Profiler.bucket or
+# forwarded from jax's compile events into the innermost open bucket; each
+# child carries `fun_name` and `parent`, and jax.compile also `cache`
+SPANS: Dict[str, str] = {
+    "trainer.init_state": "DPTrainer.init_state: the master shards' layout, "
+                          "its compile and dispatch",
+    "trainer.step": "DPTrainer.step, host side; `traced`: the call traced "
+                    "the step anew",
+    "jax.trace": "jax traces a function to a jaxpr (nested traces nest)",
+    "jax.lower": "jaxpr -> MLIR module",
+    "jax.compile": "compile_or_get_cached: the compile on a miss, the cache "
+                   "read and load on a hit; `cache` hit | miss | none",
 }
 
 

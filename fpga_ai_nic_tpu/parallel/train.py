@@ -41,6 +41,7 @@ from ..obs.names import scope
 from ..ops import fused_update
 from ..runtime import chaos
 from ..utils.config import TrainConfig
+from ..utils.observability import Profiler
 
 
 class TrainState(NamedTuple):
@@ -66,6 +67,9 @@ class DPTrainer:
     def __init__(self, loss_fn: Callable, mesh: Mesh, cfg: TrainConfig,
                  axis_name: str = "dp"):
         self.loss_fn = loss_fn
+        # host spans trainer.init_state / trainer.step, with jax's trace,
+        # lowering and compile as their children (obs.names.SPANS)
+        self.profiler = Profiler()
         self.mesh = mesh
         self.cfg = cfg
         self.ax = axis_name
@@ -165,22 +169,24 @@ class DPTrainer:
         """Split replicated params into ZeRO-1 master shards (the analogue
         of the first-iteration weight download to FPGA DDR, flags=1 path,
         sw/mlp_mpi_example_f32.cpp:700; hw/weight_update.sv MEM_INIT)."""
-        # _ensure_meta FIRST: it resolves a codec='auto' template into
-        # the concrete config _init must close over
-        self._ensure_meta(params)
-        coll, opt_cfg = self.cfg.collective, self.cfg.optimizer
+        with self.profiler.bucket("trainer.init_state"):
+            # _ensure_meta FIRST: it resolves a codec='auto' template into
+            # the concrete config _init must close over
+            self._ensure_meta(params)
+            coll, opt_cfg = self.cfg.collective, self.cfg.optimizer
 
-        def _init(params):
-            w_own, opt_state, meta = fused_update.init_master_shard(
-                params, self.ax, coll, opt_cfg)
-            return w_own, opt_state
+            def _init(params):
+                w_own, opt_state, meta = fused_update.init_master_shard(
+                    params, self.ax, coll, opt_cfg)
+                return w_own, opt_state
 
-        w_own, opt_state = jax.jit(jax.shard_map(
-            _init, mesh=self.mesh, in_specs=P(),
-            out_specs=P(self.ax), check_vma=False))(params)
-        return TrainState(params=params, w_own=w_own, opt_state=opt_state,
-                          step=jnp.zeros((), jnp.int32),
-                          codec_state=self._init_codec_state())
+            w_own, opt_state = jax.jit(jax.shard_map(
+                _init, mesh=self.mesh, in_specs=P(),
+                out_specs=P(self.ax), check_vma=False))(params)
+            return TrainState(params=params, w_own=w_own,
+                              opt_state=opt_state,
+                              step=jnp.zeros((), jnp.int32),
+                              codec_state=self._init_codec_state())
 
     def _init_codec_state(self):
         """Zeroed per-device error-feedback residuals ([n * L_pad] global,
@@ -406,7 +412,12 @@ class DPTrainer:
         return jax.jit(_step, donate_argnums=(0,))
 
     def step(self, state: TrainState, batch) -> Tuple[TrainState, jax.Array]:
-        return self.step_fn(state, batch)
+        # the span is host-side only: nothing of it enters the jitted _step
+        traces = self.step_traces
+        with self.profiler.bucket("trainer.step") as attrs:
+            out = self.step_fn(state, batch)
+            attrs["traced"] = self.step_traces > traces
+        return out
 
     # -- telemetry ----------------------------------------------------------
 

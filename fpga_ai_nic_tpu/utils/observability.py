@@ -34,7 +34,9 @@ from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
-from ..obs.events import EventStream
+from jax.profiler import TraceAnnotation
+
+from ..obs.events import EventStream, close_bucket, open_bucket
 
 
 def _lock_field():
@@ -269,17 +271,25 @@ class Profiler:
 
     @contextmanager
     def bucket(self, name: str):
-        t0 = time.perf_counter()
+        """Time the body as span ``name``.  Yields the span's attribute dict,
+        which the body may fill.  While open, the bucket is the parent of
+        jax's compile events on this thread (``obs.events.open_bucket``),
+        and, while a profiler trace runs, a ``TraceAnnotation`` in its host
+        plane on the device trace's clock."""
+        attrs: Dict[str, object] = {}
+        token = open_bucket(self.events, name)
         t0_ns = time.perf_counter_ns()
         try:
-            yield
+            with TraceAnnotation(name):
+                yield attrs
         finally:
-            dt = time.perf_counter() - t0
+            dur_ns = time.perf_counter_ns() - t0_ns
+            close_bucket(token)
             with self._lock:
-                self.buckets[name] += dt
+                self.buckets[name] += dur_ns / 1e9
                 self.counts[name] += 1
-            self.events.emit("span", name, t_ns=t0_ns,
-                             dur_ns=time.perf_counter_ns() - t0_ns)
+            self.events.emit("span", name, t_ns=t0_ns, dur_ns=dur_ns,
+                             attrs=attrs or None)
 
     def report(self) -> Dict:
         with self._lock:
