@@ -15,8 +15,8 @@ residual add each, x += f(RMSNorm(x)), its kind a letter of `pattern`
   (no clip), a_t = exp(D_t * A), A = -exp(A_log) a scalar a head;
   S_t = a_t S_{t-1} + D_t x_t (x) B_t, y_t = C_t . S_t + d_skip * x_t
   (`ssd_scan`: the recurrence in chunks of `chunk` positions, in its matrix
-  form); y = RMSNorm_grouped(y * silu(z)), over groups of d_inner / groups
-  channels; the addend is y W_out.
+  form, as the Pallas kernels of `ops.ssd_pallas`); y = RMSNorm_grouped(y *
+  silu(z)), over groups of d_inner / groups channels; the addend is y W_out.
 - `E`: `ops.moe.held_experts_ffn` — sigmoid scores over all
   `n_routed_experts`, the `top_k` largest of score + `expert_bias` selected,
   gates the selected scores normalised and scaled; the experts this chip
@@ -55,7 +55,7 @@ from jax import lax
 
 from ..obs.names import scope
 from ..ops import moe as moe_ops
-from ..ops import ring_attention
+from ..ops import ring_attention, ssd_pallas
 from .decoder import KEEP, head_nll, init_leaves, next_token_loss
 from .lfm2_moe import causal_conv
 from .llama import _rmsnorm
@@ -219,65 +219,20 @@ def ssd_scan(x: jax.Array, step: jax.Array, a: jax.Array, b: jax.Array,
     reading group h // (H / G) -> y [B, S, H, P] float32.
 
     The recurrence in chunks of `chunk` positions, in its matrix form
-    (Mamba-2's state-space duality): inside a chunk (C B^T * L) (D x), L the
-    decay between two positions, from the cumulative sum of D a in
-    float32; a state a chunk, B^T (decay to the chunk's end * D x); the
-    states carried over the chunks; C . S of the carried state, decayed
-    to the position.  Products take the operands' type and accumulate in
-    float32.  A sequence that is no multiple of the chunk is padded with
-    steps of zero, which move no state."""
+    (Mamba-2's state-space duality), as the Pallas kernels of
+    `ops.ssd_pallas`: inside a chunk (C B^T * L) (D x), L the decay between
+    two positions, from the cumulative sum of D a in float32, built in fast
+    memory; the state carried over the chunks in float32; C . S of the
+    carried state, decayed to the position.  Products take the operands'
+    type and accumulate in float32.  A sequence that is no multiple of the
+    chunk is padded with steps of zero, which move no state."""
     with scope("ainic.ssm.scan"):
-        B_, S, H, P = x.shape
-        G, N = b.shape[2:]
+        S = x.shape[1]
         pad = -S % chunk
         if pad:
             x, step, b, c = (jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (
                 t.ndim - 2)) for t in (x, step, b, c))
-        nc, f32, R = (S + pad) // chunk, jnp.float32, H // G
-        # inside a chunk the positions come LAST: [B, nc, H, P, Q]
-        xd = (x.astype(f32) * step[..., None]).reshape(B_, nc, chunk, H, P)
-        xd = xd.transpose(0, 1, 3, 4, 2)
-        b, c = (t.reshape(B_, nc, chunk, G, N) for t in (b, c))
-        # cs[l]: the log of the decay from the chunk's start through l
-        cs = jnp.cumsum((step * a).reshape(B_, nc, chunk, H), axis=2)
-        cs = cs.transpose(0, 1, 3, 2)                       # [B, nc, H, Q]
-        # position s reaches l >= s decayed by cs[l] - cs[s]
-        reach = jnp.tril(jnp.ones((chunk, chunk), bool))
-        decay = jnp.where(reach, jnp.exp(jnp.where(
-            reach, cs[..., :, None] - cs[..., None, :], 0.0)), 0.0)
-        cb = jnp.einsum("zclgn,zcsgn->zcgls", c, b,
-                        preferred_element_type=f32)
-        mix = (jnp.repeat(cb, R, axis=2) * decay).astype(x.dtype)
-        y = jnp.einsum("zchps,zchls->zchpl", xd.astype(x.dtype), mix,
-                       preferred_element_type=f32)
-        # a state a chunk, as if it started from nothing; a group's R heads
-        # side by side, so that one product reads the group's B once
-        to_end = (xd * jnp.exp(cs[..., -1:] - cs)[..., None, :]).astype(
-            x.dtype)
-        states = jnp.einsum("zcgqs,zcsgn->zcgqn",
-                            to_end.reshape(B_, nc, G, R * P, chunk), b,
-                            preferred_element_type=f32)
-        states = states.reshape(B_, nc, H, P, N)
-
-        # carried over the chunks: what a chunk starts from
-        def carry(state, chunk_in):
-            own, total = chunk_in
-            return jnp.exp(total)[..., None, None] * state + own, state
-
-        # (under shard_map a carry enters with the type it leaves with)
-        empty = ring_attention._varying(jnp.zeros((B_, H, P, N), f32),
-                                        jax.typeof(states).vma)
-        _, starts = lax.scan(
-            carry, empty,
-            (states.transpose(1, 0, 2, 3, 4), cs[..., -1].transpose(1, 0, 2)))
-        starts = starts.transpose(1, 0, 2, 3, 4).astype(x.dtype)
-        carried = jnp.einsum("zcgqn,zclgn->zcgql",
-                             starts.reshape(B_, nc, G, R * P, N), c,
-                             preferred_element_type=f32)
-        y = y + carried.reshape(B_, nc, H, P, chunk) * jnp.exp(
-            cs)[..., None, :]
-        return y.transpose(0, 1, 4, 2, 3).reshape(
-            B_, nc * chunk, H, P)[:, :S]
+        return ssd_pallas.ssd(x, step, a, b, c, chunk)[:, :S]
 
 
 def mamba_mixer(lyr: Dict, h: jax.Array, cfg: NemotronHConfig) -> jax.Array:
@@ -299,12 +254,18 @@ def mamba_mixer(lyr: Dict, h: jax.Array, cfg: NemotronHConfig) -> jax.Array:
                      c.reshape(B, S, G, N), cfg.chunk)
         y = y + lyr["d_skip"][:, None] * x.astype(f32)
         y = y.reshape(B, S, cfg.d_inner) * jax.nn.silu(z.astype(f32))
-        # the gated norm: one RMSNorm a group of d_inner / G channels
-        y = y.reshape(B, S, G, -1)
-        y = y * lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True)
-                          + cfg.norm_eps)
-        y = y.reshape(B, S, cfg.d_inner).astype(h.dtype) * lyr["gate_norm"]
-        return y @ lyr["w_out"]
+        # the gated norm: one RMSNorm a group of d_inner / G channels.  The
+        # groups' sums of squares, and the factors back over the channels,
+        # are products with the groups' one-hot [d_inner, G], exact at
+        # HIGHEST: y keeps the scan's layout, where a reshape to
+        # [B, S, G, d_inner / G] costs XLA a copy of it a pass
+        width = cfg.d_inner // G
+        groups = jax.nn.one_hot(jnp.arange(cfg.d_inner) // width, G,
+                                dtype=f32)
+        ms = jnp.dot(y * y, groups, precision=lax.Precision.HIGHEST) / width
+        y = y * jnp.dot(lax.rsqrt(ms + cfg.norm_eps), groups.T,
+                        precision=lax.Precision.HIGHEST)
+        return (y.astype(h.dtype) * lyr["gate_norm"]) @ lyr["w_out"]
 
 
 def attention(lyr: Dict, h: jax.Array, cfg: NemotronHConfig) -> jax.Array:

@@ -55,6 +55,15 @@ KERNELS: Dict[str, Tuple[str, str]] = {
     "moe.tgmm": (
         "moe", "grouped product's weight cotangent, one matrix an expert "
                "(ops/grouped_pallas.py)"),
+    "ssm_scan.fwd": (
+        "ssm_scan", "Mamba-2's chunked scan, a chunk's decay block in fast "
+                    "memory, the state carried (ops/ssd_pallas.py)"),
+    "ssm_scan.states": (
+        "ssm_scan", "the state each chunk starts from, the forward's carry "
+                    "again for the backward (ops/ssd_pallas.py)"),
+    "ssm_scan.bwd": (
+        "ssm_scan", "its backward, the chunks in reverse, the state's "
+                    "cotangent carried (ops/ssd_pallas.py)"),
 }
 
 # the phases of DPTrainer's step, as jax.named_scope: metadata on the

@@ -170,11 +170,11 @@ def _vma(*arrays) -> frozenset:
 
 
 def _launch(call, interpret, *args):
-    """call(*args).  Pallas's interpreter evaluates the index maps apart from
-    jax's record of the manual mesh axes a value varies over, and refuses
-    there a scalar prefetch that varies over one (inside shard_map), so the
-    interpreter is handed every rank's operands gathered, runs each rank's
-    in turn and each rank keeps its own result."""
+    """call(*args), an array or a tuple.  Pallas's interpreter evaluates a
+    kernel apart from jax's record of the manual mesh axes a value varies
+    over and refuses there (inside shard_map) a scalar prefetch or a block
+    that meets a varying value, so it is handed every rank's operands
+    gathered, runs each rank's in turn and each rank keeps its own result."""
     axes = tuple(sorted(_vma(*args)))
     if not interpret or not axes:
         return call(*args)
@@ -187,8 +187,8 @@ def _launch(call, interpret, *args):
                         axes)
 
     every = [whole(a) for a in args]
-    return jnp.stack([call(*(a[r] for a in every))
-                      for r in range(ranks)])[me]
+    return jax.tree_util.tree_map(lambda *o: jnp.stack(o)[me], *[
+        call(*(a[r] for a in every)) for r in range(ranks)])
 
 
 def _held_rows(y: jax.Array, bounds: jax.Array) -> jax.Array:

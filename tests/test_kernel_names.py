@@ -26,7 +26,7 @@ from fpga_ai_nic_tpu import optim
 from fpga_ai_nic_tpu.compress import int8
 from fpga_ai_nic_tpu.obs import names
 from fpga_ai_nic_tpu.ops import (bfp_pallas, flash_pallas, grouped_pallas,
-                                 paged_attend_pallas, ring_pallas)
+                                 paged_attend_pallas, ring_pallas, ssd_pallas)
 from fpga_ai_nic_tpu.utils.config import (
     BFPConfig, CollectiveConfig, MeshConfig, MLPConfig, OptimizerConfig,
     OptimizerSpec, TrainConfig)
@@ -43,9 +43,9 @@ def test_name_survives_sanitising(name):
     """`name=` may hold no dot: the table's name goes in with `_`, stays
     an identifier, and the metadata keeps the name as the table has it."""
     layer, what = names.KERNELS[name]
-    assert re.fullmatch(r"[a-z]+\.[a-z0-9_]+", name)
+    assert re.fullmatch(r"[a-z]+(?:_[a-z]+)*\.[a-z0-9_]+", name)
     assert layer == name.split(".")[0] in ("ring", "codec", "attention",
-                                           "moe")
+                                           "moe", "ssm_scan")
     assert what and "\n" not in what
     kw = names.kernel(name)
     assert kw["name"] == name.replace(".", "_") and kw["name"].isidentifier()
@@ -113,7 +113,7 @@ def test_every_pallas_call_in_the_package_takes_its_name_from_the_table():
     calls = _pallas_calls()
     unnamed = [f"{f}:{line}" for f, line, splat in calls if splat is None]
     assert not unnamed, f"pl.pallas_call without **kernel(...): {unnamed}"
-    assert len(calls) == 14
+    assert len(calls) == 17
     used = [n for _, _, splat in calls for n in _names_of(splat)]
     assert sorted(used) == sorted(names.KERNELS)      # each once, none spare
 
@@ -181,6 +181,15 @@ def _grouped_grad(x, w):
     return pull(y)
 
 
+def _scan_grad(x, step, a, b, c):
+    """The chunked scan forward and its gradient: `ssm_scan.fwd`, the
+    chunks' states again (`ssm_scan.states`) and `ssm_scan.bwd`."""
+    y, pull = jax.vjp(lambda *t: ssd_pallas.ssd(*t, 8), x, step, a, b, c)
+    return pull(y)
+
+
+_SCAN = [_f32(1, 16, 4, 8), _f32(1, 16, 4), _f32(4), _f32(1, 16, 2, 8),
+         _f32(1, 16, 2, 8)]
 _QKV = [jax.ShapeDtypeStruct((1, 2, 128, 64), jnp.bfloat16)] * 3
 _GROUPED = [_f32(48, 128), _f32(3, 128, 256)]
 SITES = {
@@ -210,6 +219,9 @@ SITES = {
     "attention.flash_dkv": lambda: (_flash_grad, _QKV),
     "moe.gmm": lambda: (_grouped_grad, _GROUPED),
     "moe.tgmm": lambda: (_grouped_grad, _GROUPED),
+    "ssm_scan.fwd": lambda: (_scan_grad, _SCAN),
+    "ssm_scan.states": lambda: (_scan_grad, _SCAN),
+    "ssm_scan.bwd": lambda: (_scan_grad, _SCAN),
     "attention.paged": lambda: (
         lambda q, pk, pv, pt, pos: paged_attend_pallas.paged_gather_attend(
             q, pk, pv, pt, pos, page_size=16, interpret=True),
@@ -442,6 +454,9 @@ EVENTS = [
             "bf16[384,512,64]{2,1,0} %q", {"ainic_kernel":
                                            "attention.flash_fwd"}),
      "attention", "attention.flash_fwd"),
+    (_event("ssm_scan_bwd.1", "(bf16[1,8192,4096]{2,1,0}, f32[1,8,8192,8])",
+            "f32[1,64,128,4096]{3,2,1,0} %states",
+            {"ainic_kernel": "ssm_scan.bwd"}), "ssm_scan", "ssm_scan.bwd"),
     # unnamed (the parent of PR 25): 10-kernels.json's fallback decides
     (_event("_rs_stream_call.1", f"({F32}, {F32})", f"{F32} %x"),
      "ring", None),
@@ -473,7 +488,8 @@ def test_event_names_classify_with_both_rule_files(event, cls, kernel):
     assert kernel_events.kernel_name(event) == kernel
 
 
-NAMED_RULE_FILES = ["05-named-kernels.json", "06-moe-kernels.json"]
+NAMED_RULE_FILES = ["05-named-kernels.json", "06-moe-kernels.json",
+                    "065-ssm-kernels.json"]
 
 
 def _rule_file(name):
@@ -486,11 +502,11 @@ def test_named_rules_sort_first_and_give_no_new_class():
     files = sorted(f for f in os.listdir(d) if f.endswith(".json"))
     # named kernels first, the fallback last; a class told by what an XLA
     # operation touches (07-head.json, PR 26) sorts between the two
-    assert files[:2] == NAMED_RULE_FILES
+    assert files[:3] == NAMED_RULE_FILES
     assert files[-1] == "10-kernels.json"
     named = [r for f in NAMED_RULE_FILES for r in _rule_file(f)]
     assert [r["class"] for r in named] == ["ring", "codec", "attention",
-                                          "moe"]
+                                          "moe", "ssm_scan"]
     layers = {layer for layer, _ in names.KERNELS.values()}
     assert {r["class"] for r in named} == layers
 
@@ -527,6 +543,46 @@ def test_moe_rule_reads_the_layer_out_of_the_name(event):
 def test_moe_rule_meets_no_other_layer_s_event(event):
     mine = [dict(r, regex=re.compile(r["regex"]))
             for r in _rule_file("06-moe-kernels.json")]
+    assert trace_reduce.classify(event, mine) == trace_reduce.DEFAULT_CLASS
+
+
+# -- the chunked scan's kernels: 065-ssm-kernels.json ------------------------
+
+SSM_EVENTS = [
+    _event("ssm_scan_fwd.1", "f32[1,8192,4096]{2,1,0:T(8,128)}",
+           "bf16[1,8192,4096]{2,1,0} %x, f32[1,8,8192,8]{3,2,1,0} %d, "
+           "f32[1,8,8192,8]{3,2,1,0} %cs, f32[1,8,8,8192]{3,2,1,0} %csr, "
+           "bf16[1,8192,1024]{2,1,0} %b, bf16[1,8192,1024]{2,1,0} %c",
+           {"ainic_kernel": "ssm_scan.fwd"}),
+    _event("ssm_scan_states.2", "f32[1,64,128,4096]{3,2,1,0:T(8,128)}",
+           "bf16[1,8192,4096]{2,1,0} %x, bf16[1,8192,1024]{2,1,0} %b",
+           {"ainic_kernel": "ssm_scan.states"}),
+    _event("ssm_scan_bwd.3", "(bf16[1,8192,4096]{2,1,0}, f32[1,8,8,8192])",
+           "f32[1,64,128,4096]{3,2,1,0} %states, f32[1,8192,4096] %dy",
+           {"ainic_kernel": "ssm_scan.bwd"}),
+]
+
+
+@pytest.mark.parametrize("event", SSM_EVENTS, ids=["fwd", "states", "bwd"])
+def test_ssm_rule_reads_the_layer_out_of_the_name(event):
+    """The Nemotron cell's rule file skips every tpu_custom_call, so 065 is
+    what classes these; its pattern meets an `ssm_scan.*` name and nothing
+    else's, and the readers by name find the kernel."""
+    mine = [dict(r, regex=re.compile(r["regex"]))
+            for r in _rule_file("065-ssm-kernels.json")]
+    assert trace_reduce.classify(event, mine) == "ssm_scan"
+    assert trace_reduce.classify(event, trace_reduce.load_rules()) \
+        == "ssm_scan"
+    assert kernel_events.kernel_name(event).startswith("ssm_scan.")
+
+
+@pytest.mark.parametrize(
+    "event", [e for e, cls, _ in EVENTS if cls != "ssm_scan"] + MOE_EVENTS,
+    ids=[f"{i}" for i in range(len(EVENTS) - 1)]
+    + ["gmm", "gmm-t", "tgmm"])
+def test_ssm_rule_meets_no_other_layer_s_event(event):
+    mine = [dict(r, regex=re.compile(r["regex"]))
+            for r in _rule_file("065-ssm-kernels.json")]
     assert trace_reduce.classify(event, mine) == trace_reduce.DEFAULT_CLASS
 
 

@@ -25,7 +25,7 @@ from fpga_ai_nic_tpu import optim
 from fpga_ai_nic_tpu.compress import int8
 from fpga_ai_nic_tpu.obs import names as obs_names
 from fpga_ai_nic_tpu.ops import (bfp_pallas, flash_pallas, grouped_pallas,
-                                 paged_attend_pallas, ring_pallas)
+                                 paged_attend_pallas, ring_pallas, ssd_pallas)
 from fpga_ai_nic_tpu.utils.config import (BFPConfig, OptimizerConfig,
                                           OptimizerSpec)
 from fpga_ai_nic_tpu.verify import opstream
@@ -235,6 +235,19 @@ def _ag(chip, streaming):
 
 
 N_CODEC = 64 * TILE
+
+
+def _scan_operands(c):
+    """The scan kernels' operands at the Nemotron cell's widths (8 heads of
+    64 a group, state 128, chunks of 128), two groups over 256 positions:
+    x, D and cs a row a head, B, C, the chunks' states, dy."""
+    bf, f = jnp.bfloat16, jnp.float32
+    return (sds((1, 256, 16, 64), bf, c.one), sds((1, 2, 8, 256), f, c.one),
+            sds((1, 2, 8, 256), f, c.one), sds((1, 256, 2, 128), bf, c.one),
+            sds((1, 256, 2, 128), bf, c.one),
+            sds((1, 2, 128, 1024), f, c.one), sds((1, 256, 16, 64), f, c.one))
+
+
 NAMED_SITES = {
     "ring.rs": lambda c: _rs(c, False),
     "ring.rs_stream": lambda c: _rs(c, True),
@@ -265,6 +278,15 @@ NAMED_SITES = {
         lambda x, dy, s: grouped_pallas._tgmm(x, dy, s, False),
         sds((512, 256), jnp.bfloat16, c.one),
         sds((512, 384), jnp.bfloat16, c.one), sds((4,), jnp.int32, c.one)),
+    "ssm_scan.fwd": lambda c: (
+        lambda *args: ssd_pallas._forward(*args, 128, False),
+        *_scan_operands(c)[:5]),
+    "ssm_scan.states": lambda c: (
+        lambda *args: ssd_pallas._states(*args, 128, False),
+        *_scan_operands(c)[:4]),
+    "ssm_scan.bwd": lambda c: (
+        lambda *args: ssd_pallas._backward(*args, 128, False),
+        *_scan_operands(c)),
     "attention.paged": lambda c: (
         lambda q, pk, pv, pt, pos: paged_attend_pallas.paged_gather_attend(
             q, pk, pv, pt, pos, page_size=128, interpret=False),
@@ -394,24 +416,28 @@ def grouped_products(text):
         r"^\s*(?:ROOT )?%moe_t?gmm[.\d]* = (\w+\[[\d,]+\])", text, re.M))
 
 
-def assert_kernels_are_the_tables(text):
-    """Every Mosaic kernel of the compiled text is one of the expert
-    layer's named kernels (the benchmark's 06-moe-kernels.json reads the
-    class from its name), and none is the compiler's own grouped kernel."""
+def assert_kernels_are_the_tables(text, layer="moe"):
+    """Every Mosaic kernel of the compiled text is one of `layer`'s named
+    kernels, and each of them is there (the benchmark's 06-moe-kernels.json
+    and 065-ssm-kernels.json read the class from the name); none is the
+    compiler's own grouped kernel."""
+    mine = {name for name, (of, _) in obs_names.KERNELS.items()
+            if of == layer}
     made = set(re.findall(
         r"^\s*(?:ROOT )?%([\w-]+?)[.\d]* = [^\n]*tpu_custom_call", text,
         re.M))
-    assert made == {"moe_gmm", "moe_tgmm"}
-    assert {meta["ainic_kernel"] for _, meta in named_calls(text)} == {
-        "moe.gmm", "moe.tgmm"}
+    assert made == {name.replace(".", "_") for name in mine}
+    assert {meta["ainic_kernel"] for _, meta in named_calls(text)} == mine
     assert "ragged-dot" not in text
 
 
 @pytest.fixture
 def kernels_for_the_chip(monkeypatch):
-    """`grouped_pallas` asks `_is_tpu` whether to interpret; a compile for
-    the described chip wants the kernels themselves."""
+    """`grouped_pallas` and `ssd_pallas` ask `_is_tpu` whether to
+    interpret; a compile for the described chip wants the kernels
+    themselves."""
     monkeypatch.setattr(grouped_pallas, "_is_tpu", lambda: True)
+    monkeypatch.setattr(ssd_pallas, "_is_tpu", lambda: True)
 
 
 def conditional_branches(text):
@@ -579,24 +605,25 @@ def _nemotron_block(chip, kind):
     return cfg, lyr, sds((1, 8192, cfg.dim), jnp.bfloat16, chip.one)
 
 
-def test_nemotron_mixer_compiles_clear_of_the_other_cells_rules(chip):
+def test_nemotron_mixer_compiles_clear_of_the_other_cells_rules(
+        chip, kernels_for_the_chip):
     """The Mamba-2 mixer (W_in 2688 x 10304, 64 heads of 64 over 8 groups,
     state 128, chunks of 128) at one 8,192-token sequence, forward and
-    backward: XLA's own fusions, no Mosaic kernel; the decay arrays are
-    float32 [64 chunks, 64 heads, 128, 128]; and NO array of it has the
-    shape 075-lfm2-moe.json reads as attention ([.., 8 or 32, n, 64 or
-    512]) — inside a chunk the positions come last, so every array of the
-    scan ends in 128 — nor 07's or 075's vocabulary-wide ones."""
+    backward: the chunked scan is the table's `ssm_scan.*` kernels
+    (ops/ssd_pallas.py), no decay array [64 chunks, 64 heads, 128, 128] is
+    left outside them, and NO array of the mixer has the shape
+    075-lfm2-moe.json reads as attention ([.., 8 or 32, n, 64 or 512]), nor
+    07's or 075's vocabulary-wide ones."""
     from fpga_ai_nic_tpu.models import nemotron_h
     cfg, lyr, x = _nemotron_block(chip, "M")
 
     def grads(lyr, x):
-        return jax.grad(lambda l, y: nemotron_h.mamba_mixer(
+        return jax.value_and_grad(lambda l, y: nemotron_h.mamba_mixer(
             l, y, cfg).astype(jnp.float32).sum(), argnums=(0, 1))(lyr, x)
 
     text = compiled_text(grads, lyr, x)
-    assert "tpu_custom_call" not in text
-    assert re.search(r"f32\[64,64,128,128\]", text)
+    assert_kernels_are_the_tables(text, "ssm_scan")
+    assert not re.search(r"f32\[64,64,128,128\]", text)
     assert re.search(r"bf16\[(?:1,)?2688,10304\]", text)
     with open(os.path.join(os.path.dirname(__file__), os.pardir, "benchmark",
                            "op_classes", "075-lfm2-moe.json")) as f:
@@ -608,6 +635,60 @@ def test_nemotron_mixer_compiles_clear_of_the_other_cells_rules(chip):
         shape_part = regex[regex.rindex("[^\\n]*") + len("[^\\n]*"):]
         hit = [s for s in shapes if re.search(shape_part, s)]
         assert not hit, (regex, hit)
+
+
+def _scan_lowerings(chip, runs):
+    """Mixers in `runs` runs of one block each, as `nemotron_h.hidden` runs
+    the cell's four (a checkpointed scan body a run), value and gradient:
+    (the scan functions in the text handed to the compiler, by name with
+    their signatures; the scan kernels the compiled program runs)."""
+    from fpga_ai_nic_tpu.models import nemotron_h
+    cfg, lyr, x = _nemotron_block(chip, "M")
+    stack = {k: sds((1,) + v.shape, v.dtype, chip.one)
+             for k, v in lyr.items()}
+
+    def grads(stacks, x):
+        def loss(stacks, y):
+            for stack in stacks:
+                y, _ = jax.lax.scan(jax.checkpoint(
+                    lambda y, l: nemotron_h._block(l, y, cfg, "M"),
+                    policy=nemotron_h.KEEP), y, stack)
+            return y.astype(jnp.float32).sum()
+        return jax.value_and_grad(loss, argnums=(0, 1))(stacks, x)
+
+    lowered = jax.jit(grads).lower([stack] * runs, x)
+    text = lowered.as_text()
+    funcs = re.findall(
+        r"func\.func private @(_(?:forward|states|backward))\w*\(([^)]*)\)",
+        text)
+    assert text.count("tpu_custom_call") == len(funcs)
+    made = [meta["ainic_kernel"]
+            for _, meta in named_calls(lowered.compile().as_text())]
+    return [(name, re.sub(r"loc\([^)]*\)", "", sig))
+            for name, sig in funcs], sorted(made)
+
+
+def test_nemotron_mixer_runs_lower_each_distinct_scan_kernel_once(
+        chip, kernels_for_the_chip):
+    """However many mixers a step has, the text handed to the compiler
+    holds the same scan kernels — the forward, the states and the backward
+    at the one shape the mixers share — while the compiled program runs
+    them at every site: four mixers run eight forwards (the recompute under
+    the checkpoint), four states and four backwards.  The forward is
+    lowered twice, once for each jaxpr jax keeps of it: the checkpoint's
+    dead-code pass hands the forward pass a copy (PERF.md 7).  A
+    `pallas_call` lowered at every call site cost seconds of set-up a step
+    trace (PERF.md 6)."""
+    one, made_one = _scan_lowerings(chip, 1)
+    four, made_four = _scan_lowerings(chip, 4)
+    assert sorted(name for name, _ in four) == sorted(
+        name for name, _ in one) == ["_backward", "_forward", "_forward",
+                                     "_states"]
+    assert len(set(four)) == 3
+    assert made_one == ["ssm_scan.bwd", "ssm_scan.fwd", "ssm_scan.fwd",
+                        "ssm_scan.states"]
+    assert made_four == (["ssm_scan.bwd"] * 4 + ["ssm_scan.fwd"] * 8
+                         + ["ssm_scan.states"] * 4)
 
 
 def test_nemotron_expert_layer_compiles_to_two_products_an_expert(
